@@ -34,7 +34,7 @@ sys.path.insert(0, REPO)
 
 RESULTS = os.path.join(REPO, "benchmarks", "results", "attn_memory.json")
 
-# the LM-family shapes kernels.json benches (b, h, L, d)
+# the LM-family shapes kernel_bench.py benches (b, h, L, d)
 SHAPES = [(4, 8, 2048, 128), (2, 8, 4096, 128), (1, 8, 8192, 128)]
 
 
@@ -95,8 +95,8 @@ def flash_analytic(b, h, l, d, block_q=128, block_k=128):
 
 
 def main() -> None:
-    from lua_mapreduce_tpu.utils.jax_env import force_cpu_if_unavailable
-    force_cpu_if_unavailable()
+    from lua_mapreduce_tpu.utils.jax_env import place_compile_cache
+    place_compile_cache()
     import jax
 
     backend = jax.default_backend()

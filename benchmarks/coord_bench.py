@@ -175,7 +175,8 @@ def _spawn_workers(coord: str, n: int, v1: bool = False):
         "w.execute()\n"
         "print(json.dumps({'rounds': st.round_counts(),\n"
         "                  'jobs': w.jobs_executed}), flush=True)\n")
-    env = dict(os.environ, PYTHONPATH=REPO)
+    # host-path workers: never reach for a chip the parent may hold
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     return [subprocess.Popen([sys.executable, "-c", code], env=env,
                              stdout=subprocess.PIPE, text=True)
             for _ in range(n)]

@@ -121,8 +121,8 @@ def run(native_steps: int = 300, mr_steps: int = 60,
 
 
 def main() -> None:
-    from lua_mapreduce_tpu.utils.jax_env import force_cpu_if_unavailable
-    force_cpu_if_unavailable()
+    from lua_mapreduce_tpu.utils.jax_env import place_compile_cache
+    place_compile_cache()
 
     out = run()
     print(json.dumps(out, indent=1))

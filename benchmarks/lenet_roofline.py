@@ -132,13 +132,10 @@ def profile(batch=1024, dtype_name="bfloat16", target_s=0.35) -> dict:
 
 
 def main() -> int:
-    from lua_mapreduce_tpu.utils.jax_env import force_cpu_if_unavailable
-    force_cpu_if_unavailable()
-    import jax
-
-    if jax.default_backend() != "tpu":
-        print(json.dumps({"skipped": "not on TPU"}))
-        return 1
+    from lua_mapreduce_tpu.utils.jax_env import (place_compile_cache,
+                                                 require_tpu)
+    place_compile_cache()
+    require_tpu("lenet_roofline.py")
 
     results = profile()
     results["note"] = (

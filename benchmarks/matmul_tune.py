@@ -70,13 +70,10 @@ def main():
     ap.add_argument("--sizes", default="4096,8192")
     args = ap.parse_args()
 
-    from lua_mapreduce_tpu.utils.jax_env import force_cpu_if_unavailable
-    force_cpu_if_unavailable()
-    import jax
-
-    if jax.default_backend() != "tpu":
-        print(json.dumps({"skipped": "not on TPU"}))
-        return
+    from lua_mapreduce_tpu.utils.jax_env import (place_compile_cache,
+                                                 require_tpu)
+    place_compile_cache()
+    require_tpu("matmul_tune.py")
 
     sizes = [int(s) for s in args.sizes.split(",")]
     # candidate schedules: (bm, bn, bk); VMEM budget ~16 MB on v5e with

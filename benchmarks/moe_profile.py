@@ -145,13 +145,10 @@ def profile(T=T, E=E, D=D, FF=FF, cap=None, target_s=0.35) -> dict:
 
 
 def main() -> int:
-    from lua_mapreduce_tpu.utils.jax_env import force_cpu_if_unavailable
-    force_cpu_if_unavailable()
-    import jax
-
-    if jax.default_backend() != "tpu":
-        print(json.dumps({"skipped": "not on TPU"}))
-        return 1
+    from lua_mapreduce_tpu.utils.jax_env import (place_compile_cache,
+                                                 require_tpu)
+    place_compile_cache()
+    require_tpu("moe_profile.py")
 
     results = profile()
     results["note"] = (

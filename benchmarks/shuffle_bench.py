@@ -44,7 +44,8 @@ def _spawn_workers(coord: str, n: int):
         f"w = Worker(FileJobStore({coord!r})).configure(\n"
         "    max_iter=100000, max_sleep=0.05, max_tasks=100000)\n"
         "w.execute()\n")
-    env = dict(os.environ, PYTHONPATH=REPO)
+    # host-path workers: never reach for a chip the parent may hold
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     return [subprocess.Popen([sys.executable, "-c", code], env=env)
             for _ in range(n)]
 

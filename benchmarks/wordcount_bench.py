@@ -83,7 +83,8 @@ def run(n_workers: int = 4, corpus_dir: str = "/tmp/wc_corpus") -> dict:
         f"w = Worker(FileJobStore({coord!r})).configure(\n"
         "    max_iter=100000, max_sleep=0.05, max_tasks=100000)\n"
         "w.execute()\n")
-    env = dict(os.environ, PYTHONPATH=REPO)
+    # host-path workers: never reach for a chip the parent may hold
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     t0 = time.perf_counter()
     procs = [subprocess.Popen([sys.executable, "-c", worker_code], env=env)
              for _ in range(n_workers)]

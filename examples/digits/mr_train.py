@@ -38,8 +38,8 @@ _data = None
 
 def init(args):
     global _cfg, _data
-    # host-path processes must not die if the (single-tenant) TPU backend
-    # is owned by another pool member
+    # host-path pool members must not die when a sibling holds the chip
+    # (a second process gets RuntimeError from JAX; utils/jax_env.py)
     from lua_mapreduce_tpu.utils.jax_env import ensure_backend
     ensure_backend()
     _cfg = {

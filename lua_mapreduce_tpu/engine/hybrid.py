@@ -255,9 +255,8 @@ class HybridMapEngine:
     def _build_shard_map(self, keys, prepped):
         import jax
         import jax.numpy as jnp
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        from lua_mapreduce_tpu.utils.jax_compat import shard_map
 
         spec, axis = self.spec, self.axis
         mesh = self._ensure_mesh()

@@ -273,6 +273,19 @@ def record_lowering(decision: EngineDecision) -> None:
         tracer.add(f"lowering.{stage}", now, now, ns="hybrid", **sattrs)
 
 
+def record_tier(mode: str, collective_error: str) -> None:
+    """Emit the ``lowering.tier`` span: the in-graph engine compiled on
+    the ``mode`` tier after the collective (shard_map) tier refused the
+    task with ``collective_error``. The run is correct either way, but
+    the jit tier uses one device."""
+    tracer = active_tracer()
+    if tracer is None:
+        return
+    now = tracer.clock()
+    tracer.add("lowering.tier", now, now, ns="ingraph", stage="tier",
+               engine=mode, compiled="true", reason=collective_error)
+
+
 def record_fallback(reason: str) -> None:
     """Emit the ``ingraph.fallback`` span marking a RUNTIME degrade to
     the store plane (oracle accepted, lowering raised)."""
@@ -519,55 +532,69 @@ def _sum_fold(spec: TaskSpec, key, value_template, n_values: int) -> bool:
         return False
     import jax
     import numpy as np
-    try:
-        leaves, td = jax.tree.flatten(value_template)
-        shapes = [(tuple(x.shape), x.dtype) for x in leaves]
-        probes = [
-            jax.tree.unflatten(td, [np.zeros(s, d) for s, d in shapes])
-            for _ in range(n_values)]
-        jaxpr, out_shape = jax.make_jaxpr(
-            lambda *vs: spec.reducefn(key, list(vs)),
-            return_shape=True)(*probes)
-        if jax.tree.structure(out_shape) != td:
-            return False
-        core = jaxpr.jaxpr
-        n_leaves = len(shapes)
-        if len(core.invars) != n_values * n_leaves:
-            return False
-        Literal = jax.core.Literal
-        contrib: Dict[Any, Dict[int, int]] = {
-            v: {i: 1} for i, v in enumerate(core.invars)}
-        for eqn in core.eqns:
-            name = eqn.primitive.name
-            if name == "add":
-                c: Dict[int, int] = {}
-                for x in eqn.invars:
-                    if isinstance(x, Literal):
-                        return False
-                    for src, mult in contrib.get(x, {}).items():
-                        c[src] = c.get(src, 0) + mult
-                contrib[eqn.outvars[0]] = c
-            elif name == "convert_element_type":
-                x = eqn.invars[0]
+    from jax.extend.core import Literal
+    leaves, td = jax.tree.flatten(value_template)
+    shapes = [(tuple(x.shape), x.dtype) for x in leaves]
+    probes = [
+        jax.tree.unflatten(td, [np.zeros(s, d) for s, d in shapes])
+        for _ in range(n_values)]
+    traced = _trace_reducer(lambda *vs: spec.reducefn(key, list(vs)),
+                            probes)
+    if traced is None:
+        return False
+    core, out_shape = traced
+    if jax.tree.structure(out_shape) != td:
+        return False
+    n_leaves = len(shapes)
+    if len(core.invars) != n_values * n_leaves:
+        return False
+    contrib: Dict[Any, Dict[int, int]] = {
+        v: {i: 1} for i, v in enumerate(core.invars)}
+    for eqn in core.eqns:
+        name = eqn.primitive.name
+        if name == "add":
+            c: Dict[int, int] = {}
+            for x in eqn.invars:
                 if isinstance(x, Literal):
                     return False
-                contrib[eqn.outvars[0]] = contrib.get(x, {})
-            else:
+                for src, mult in contrib.get(x, {}).items():
+                    c[src] = c.get(src, 0) + mult
+            contrib[eqn.outvars[0]] = c
+        elif name == "convert_element_type":
+            x = eqn.invars[0]
+            if isinstance(x, Literal):
                 return False
-        if len(core.outvars) != n_leaves:
+            contrib[eqn.outvars[0]] = contrib.get(x, {})
+        else:
             return False
-        for li, ov in enumerate(core.outvars):
-            if isinstance(ov, Literal):
-                return False
-            if ov.aval.shape != shapes[li][0] \
-                    or ov.aval.dtype != shapes[li][1]:
-                return False
-            want = {i * n_leaves + li: 1 for i in range(n_values)}
-            if contrib.get(ov, {}) != want:
-                return False
-        return True
-    except Exception:                       # noqa: BLE001 — probe only
+    if len(core.outvars) != n_leaves:
         return False
+    for li, ov in enumerate(core.outvars):
+        if isinstance(ov, Literal):
+            return False
+        if ov.aval.shape != shapes[li][0] \
+                or ov.aval.dtype != shapes[li][1]:
+            return False
+        want = {i * n_leaves + li: 1 for i in range(n_values)}
+        if contrib.get(ov, {}) != want:
+            return False
+    return True
+
+
+def _trace_reducer(fn, probes):
+    """``(jaxpr, out_shape)`` of the user's reducer on ``probes``, or
+    None when the reducer will not trace because it needs concrete
+    values (``float(v)``, ``if v > 0``: JAX's tracer-conversion errors)
+    — such a reducer is not a sum. Nothing else is caught: an
+    ``AttributeError``/``ImportError``/plain ``TypeError`` is JAX API
+    drift or a bug, and swallowing it once turned every psum fold into
+    an all_gather without a word."""
+    import jax
+    try:
+        jaxpr, out_shape = jax.make_jaxpr(fn, return_shape=True)(*probes)
+    except jax.errors.JAXTypeError:
+        return None
+    return jaxpr.jaxpr, out_shape
 
 
 def _singleton_passthrough(spec: TaskSpec, key, value_template) -> bool:
@@ -579,28 +606,26 @@ def _singleton_passthrough(spec: TaskSpec, key, value_template) -> bool:
     only when that call provably adds nothing else."""
     import jax
     import numpy as np
-    try:
-        leaves, td = jax.tree.flatten(value_template)
-        shapes = [(tuple(x.shape), x.dtype) for x in leaves]
-        probe = jax.tree.unflatten(td, [np.zeros(s, d) for s, d in shapes])
-        jaxpr, out_shape = jax.make_jaxpr(
-            lambda v: spec.reducefn(key, [v]), return_shape=True)(probe)
-        if jax.tree.structure(out_shape) != td:
-            return False
-        core = jaxpr.jaxpr
-        Literal = jax.core.Literal
-        alias = {v: i for i, v in enumerate(core.invars)}
-        for eqn in core.eqns:
-            if eqn.primitive.name != "convert_element_type":
-                return False
-            x = eqn.invars[0]
-            if isinstance(x, Literal) or x not in alias:
-                return False
-            alias[eqn.outvars[0]] = alias[x]
-        return [alias.get(ov) for ov in core.outvars] \
-            == list(range(len(shapes)))
-    except Exception:                       # noqa: BLE001 — probe only
+    from jax.extend.core import Literal
+    leaves, td = jax.tree.flatten(value_template)
+    shapes = [(tuple(x.shape), x.dtype) for x in leaves]
+    probe = jax.tree.unflatten(td, [np.zeros(s, d) for s, d in shapes])
+    traced = _trace_reducer(lambda v: spec.reducefn(key, [v]), [probe])
+    if traced is None:
         return False
+    core, out_shape = traced
+    if jax.tree.structure(out_shape) != td:
+        return False
+    alias = {v: i for i, v in enumerate(core.invars)}
+    for eqn in core.eqns:
+        if eqn.primitive.name != "convert_element_type":
+            return False
+        x = eqn.invars[0]
+        if isinstance(x, Literal) or x not in alias:
+            return False
+        alias[eqn.outvars[0]] = alias[x]
+    return [alias.get(ov) for ov in core.outvars] \
+        == list(range(len(shapes)))
 
 
 # --------------------------------------------------------------------------
@@ -636,6 +661,10 @@ class InGraphEngine:
         self._mesh = mesh
         self.traces = 0
         self.mode: Optional[str] = None     # "shard_map" | "jit"
+        # why the collective tier was refused, when it was tried and
+        # the jit tier ran instead ("TypeError: ..."); the runner puts
+        # it in the log and in a ``lowering.tier`` span
+        self.collective_error: Optional[str] = None
         self._program: Optional[Callable] = None
         self._plan: Optional[_Plan] = None
         self._sig: Optional[tuple] = None
@@ -690,7 +719,7 @@ class InGraphEngine:
     # -- build --------------------------------------------------------------
 
     def _build_and_run(self, keys, prepped) -> tuple:
-        first_err: Optional[Exception] = None
+        self.collective_error = None
         uniform = len({st for _, st in prepped}) == 1
         numeric_keys = all(isinstance(k, (int, float))
                            and type(k) is not bool for k in keys)
@@ -701,7 +730,7 @@ class InGraphEngine:
                     mode="shard_map",
                     sig=self._mode_sig(keys, prepped, "shard_map"))
             except Exception as e:          # noqa: BLE001 — tier fallback
-                first_err = e
+                self.collective_error = f"{type(e).__name__}: {e}"
                 self.traces = 0             # aborted trace doesn't count
         try:
             return self._finish_build(
@@ -710,8 +739,9 @@ class InGraphEngine:
         except LoweringError:
             raise
         except Exception as e:              # noqa: BLE001
-            hint = (f"; collective tier also failed: {first_err}"
-                    if first_err is not None else "")
+            hint = (f"; collective tier also failed: "
+                    f"{self.collective_error}"
+                    if self.collective_error is not None else "")
             raise LoweringUnsupported(
                 f"in-graph lowering failed at trace time: "
                 f"{type(e).__name__}: {e}{hint}") from e
@@ -757,11 +787,10 @@ class InGraphEngine:
     def _build_shard_map(self, keys, prepped):
         import jax
         import jax.numpy as jnp
-        from jax import lax
+        from jax import lax, shard_map
         from jax.sharding import PartitionSpec as P
 
         from lua_mapreduce_tpu.parallel.tpu_engine import _CROSS
-        from lua_mapreduce_tpu.utils.jax_compat import shard_map
 
         spec, axis = self.spec, self.axis
         mesh = self._ensure_mesh()
@@ -934,6 +963,7 @@ class IngraphRunner:
             if decision.chosen == "ingraph" else None
         self._log = log or (lambda msg: print(f"[ingraph] {msg}",
                                               file=sys.stderr))
+        self._tier_reported = False
         record_lowering(decision)
         if decision.requested != "store" and decision.chosen == "store":
             self._log(f"store plane selected: {decision.reason}")
@@ -976,6 +1006,16 @@ class IngraphRunner:
             self.engine = None
             return False
         COUNTERS.bump("ingraph_iterations")
+        if self.engine.collective_error is not None \
+                and not self._tier_reported:
+            # the jit tier ran because the collective tier refused the
+            # task: on a multi-device mesh that is the difference
+            # between every device working and one, so say it once
+            self._tier_reported = True
+            record_tier(self.engine.mode, self.engine.collective_error)
+            self._log(f"iteration {iteration}: {self.engine.mode} tier "
+                      f"(collective tier refused: "
+                      f"{self.engine.collective_error})")
         return True
 
 

@@ -23,9 +23,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-from lua_mapreduce_tpu.utils.jax_compat import shard_map
 
 
 class KMeansResult(NamedTuple):

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from lua_mapreduce_tpu.ops.attention import flash_attention
@@ -44,8 +44,7 @@ from lua_mapreduce_tpu.parallel.ring_attention import (
     _NEG_INF, _ring_shard, _ring_shard_zigzag, _ulysses_shard,
     _zigzag_check, _zigzag_perm, attention_reference)
 from lua_mapreduce_tpu.train.accum import accum_value_and_grad
-from lua_mapreduce_tpu.utils.jax_compat import (shard_map, spec_axes,
-                                                stamp_replicated)
+from lua_mapreduce_tpu.utils.jax_compat import spec_axes, stamp_replicated
 
 Params = Dict[str, jnp.ndarray]
 
@@ -520,6 +519,11 @@ def prefill(params: Params, prompt, *,
     return caches, logits[:, -1].astype(jnp.float32)
 
 
+@functools.partial(
+    jax.jit,
+    static_argnames=("n_new", "cfg", "temperature", "top_k",
+                     "use_prefill", "mesh", "attn", "dp_axis", "sp_axis",
+                     "kv_q8"))
 def greedy_decode(params: Params, prompt, n_new: int, *,
                   cfg: TransformerConfig = TransformerConfig(),
                   temperature: float = 0.0,
@@ -531,6 +535,10 @@ def greedy_decode(params: Params, prompt, n_new: int, *,
     """KV-cached decoding: (B, P) int32 prompt → (B, P+n_new).
 
     The inference half of the LM family (training: make_train_step).
+    The whole call is ONE jitted program, cached on the prompt shape,
+    the param structure and every keyword but ``key`` — called eagerly,
+    the position scan's body was a fresh closure per call and so was
+    recompiled on every request.
     One ``lax.scan`` over positions with per-layer (B, L, H_kv, Dh)
     caches in the carry (H_kv < H under GQA — the cache shrinks by the
     group factor) — static shapes throughout, so the whole decode is one
@@ -896,10 +904,29 @@ def param_specs_moe(ep_axis: str = "dp") -> Dict[str, object]:
 
 def shard_params_moe(params: Params, mesh, *, ep_axis: str = "dp"
                      ) -> Params:
-    """device_put params with expert stacks sharded over ``ep_axis``."""
+    """device_put params with expert stacks sharded over ``ep_axis``
+    (every other leaf, so every leaf of a dense model, replicated)."""
     specs = param_specs_moe(ep_axis)
     return {k: jax.device_put(v, NamedSharding(mesh, _spec_for(k, specs)))
             for k, v in params.items()}
+
+
+def init_opt_state(optimizer, params: Params, mesh):
+    """``optimizer.init(params)`` laid out the way :func:`make_train_step`'s
+    step returns it; ``params`` must already live on ``mesh``
+    (:func:`shard_params_moe`). The moments inherit their parameter's
+    layout, but what ``init`` makes from nothing (Adam's step count) is
+    left uncommitted; the first step returns it committed to the mesh,
+    and that one changed input type makes the SECOND step trace and
+    compile the whole program again."""
+    replicated = NamedSharding(mesh, P())
+
+    def place(x):
+        s = x.sharding
+        on_mesh = isinstance(s, NamedSharding) and s.mesh == mesh
+        return x if on_mesh else jax.device_put(x, replicated)
+
+    return jax.tree.map(place, optimizer.init(params))
 
 
 def make_train_step(cfg: TransformerConfig, mesh, optimizer, *,
@@ -920,7 +947,9 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer, *,
     With ``cfg.moe_experts`` > 0 the block FFNs are switch-MoE with
     experts sharded over the dp axis (the standard ep ≡ dp grouping:
     expert buckets ride all_to_all between data-parallel peers); params
-    must then come from :func:`shard_params_moe`.
+    must then come from :func:`shard_params_moe`. Params and optimizer
+    state laid out on the mesh beforehand (:func:`shard_params_moe`,
+    :func:`init_opt_state`) keep the second step from recompiling.
 
     ``zigzag_layout=True`` (``attn="zigzag"`` only) declares tokens and
     targets ALREADY in zigzag order — feed batches through

@@ -7,12 +7,11 @@ package is the TPU-native replacement: Pallas kernels tiled for the MXU
 (128×128 systolic array) and VPU, with XLA reference implementations used
 for (a) correctness tests and (b) non-TPU backends.
 
-Backend policy (``default_backend``): per-op, measured, not dogmatic.
-On TPU each op's ``auto`` resolves to whichever implementation the
-committed kernel bench (benchmarks/results/kernels.json) shows faster on
-real hardware — a hand-written kernel is a means, not an end, and for
-some ops XLA's lowering is the better TPU program. Off-TPU everything
-resolves to "xla" (Pallas-TPU kernels only lower on TPU). Every op takes
+Backend policy (``default_backend``): per-op, not dogmatic. On TPU
+each op's ``auto`` resolves through ``_TPU_AUTO_POLICY`` — a
+hand-written kernel is a means, not an end, and for some ops XLA's
+lowering is the better TPU program. Off-TPU everything resolves to
+"xla" (Pallas-TPU kernels only lower on TPU). Every op takes
 ``backend=`` with values "auto" | "pallas" | "xla" | "pallas_interpret"
 (interpreter mode, for CPU tests of the kernel path).
 """
@@ -21,31 +20,13 @@ from __future__ import annotations
 
 import jax
 
-# Measured on a TPU v5e (benchmarks/results/kernels.json, round-4
-# windows 2026-07-31): XLA's conv lowering beats the im2col+Pallas path
-# (46.1 vs 8.1 TF/s on the ResNet 56×56 block) STRUCTURALLY — the
-# im2col patch round trip alone costs 1.75× XLA's whole runtime
-# (DESIGN.md §8b), so conv2d is "xla" permanently for this shape class.
-# Matmul: the sweep-tuned wide tiles (matmul_tune.json baked into
-# _auto_blocks: (512-1024, 1024, 512)) measured 151.6 TF/s at 8192³ —
-# 2.8× the round-2 256² schedule, 0.90× XLA's 169.2 — still fractionally
-# under the ≥0.9× flip rule (0.896), so the policy holds at XLA: the
-# kernel exists for fusion sites XLA can't express, not to re-win dense
-# GEMM. The Pallas pooling kernel beats XLA's reduce_window ~2.7×.
-# Flash is Pallas on BOTH grounds, measured on-chip with the
-# sweep-tuned (512, 512) blocks (flash_tune.json, two sweep rounds):
-#   speed — fwd 3.14× XLA at L=2048 and 9.69× at L=4096, fused
-#   backward 3.99× (flash_*/flash_grad_* entries);
-#   memory — the XLA composition's compiled buffer assignment holds
-#   L²-sized temps across fwd+bwd (attn_memory.json, tpu section): 2.00
-#   GiB of grad temps at (b=2, h=8, L=4096, d=128) vs the fused pair's
-#   0.178 GiB of O(L) residents (11.3×; 4.06 GiB / 22.9× by L=8192),
-#   the gap doubling per context doubling (the CPU buffer-assignment
-#   analysis, DESIGN §9, shows the same growth at ~2× the absolute
-#   temps) — while the Pallas pair (forward + FlashAttention-2
-#   backward re-materializing p from the saved logsumexp) never
-#   materializes O(L²).
-# Softmax is a wash; XLA wins on fusion-with-neighbors grounds.
+# Routing on TPU. Speed: not measured on the current installation;
+# ROADMAP A3-A5. What is on record for it: every "pallas" entry is
+# compiled by the chip's compiler and compared with its "xla" twin by
+# chip_smoke.py's kernel stage. The structural reasons behind the
+# routing: conv2d's im2col patch round trip costs more than XLA's whole
+# conv (DESIGN.md §8b); flash attention never materializes the O(L²)
+# score matrix the XLA composition holds across fwd+bwd (DESIGN §9).
 _TPU_AUTO_POLICY = {
     "matmul": "xla",
     "conv2d": "xla",
@@ -59,17 +40,14 @@ _TPU_AUTO_POLICY = {
     # forfeiting the halved weight traffic the op exists for
     "q8_matmul": "pallas",
     # flash-decode (ops/decode.py): one query position vs the KV
-    # cache, chunk-streamed with dynamic dead-chunk DMA elision —
-    # built for the DESIGN §13 decode gap; first on-chip number
-    # pending the next window (decode_* bench entries route through
-    # greedy_decode and therefore through this policy)
+    # cache, chunk-streamed with dynamic dead-chunk DMA elision
     "decode_attention": "pallas",
 }
 
 
 def default_backend(op: str | None = None) -> str:
-    """Resolved backend for ``op`` on the current platform: the measured
-    per-op winner on TPU (see ``_TPU_AUTO_POLICY``), 'xla' elsewhere."""
+    """Resolved backend for ``op`` on the current platform: the
+    ``_TPU_AUTO_POLICY`` route on TPU, 'xla' elsewhere."""
     if jax.default_backend() != "tpu":
         return "xla"
     return _TPU_AUTO_POLICY.get(op, "pallas")
@@ -78,19 +56,15 @@ def default_backend(op: str | None = None) -> str:
 def out_struct(shape, dtype, *like) -> jax.ShapeDtypeStruct:
     """``pallas_call`` out_shape that survives shard_map's vma typing.
 
-    JAX ≥0.9 checks varying-mesh-axes (vma) types inside ``shard_map``
-    and rejects a plain ``ShapeDtypeStruct`` out_shape; the output of a
+    JAX checks varying-mesh-axes (vma) types inside ``shard_map`` and
+    rejects a plain ``ShapeDtypeStruct`` out_shape; the output of a
     kernel varies over exactly the union of axes its operands vary over,
     so that union is propagated from ``like``. Outside shard_map every
     operand's vma is empty and this degrades to the plain struct.
     """
-    try:
-        vma = (frozenset().union(*(jax.typeof(a).vma for a in like))
-               if like else frozenset())
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    except (AttributeError, TypeError):
-        # older JAX: no jax.typeof/.vma/vma kwarg — and no vma checking
-        return jax.ShapeDtypeStruct(shape, dtype)
+    vma = (frozenset().union(*(jax.typeof(a).vma for a in like))
+           if like else frozenset())
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def resolve_backend(backend: str, op: str | None = None) -> str:

@@ -34,7 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from lua_mapreduce_tpu.utils.jax_compat import tpu_compiler_params
 
 from lua_mapreduce_tpu.ops import out_struct, resolve_backend
 
@@ -380,7 +379,7 @@ def _flash_pallas(q, k, v, causal, block_q=None, block_k=None,
         # (bh, qi) carry no cross-iteration state (scratch re-inits at
         # ki == 0); only the kv axis accumulates — telling Mosaic lets
         # it parallelize/pipeline across the first two grid axes
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qb, kb, vb)
@@ -571,7 +570,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, block_q=None,
         out_specs=spec_q,
         out_shape=out_struct(qb.shape, q.dtype, qb, kb, vb, dob),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qb, kb, vb, dob, lse_r, delta_r)
@@ -603,7 +602,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, block_q=None,
                    out_struct(vb.shape, v.dtype, qb, kb, vb, dob)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(qb, kb, vb, dob, lse_r, delta_r)

@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from lua_mapreduce_tpu.utils.jax_compat import tpu_compiler_params
 
 from lua_mapreduce_tpu.ops import out_struct, resolve_backend
 from lua_mapreduce_tpu.ops.attention import _LANES, _tile_mask
@@ -244,7 +243,7 @@ def _decode_pallas(q, k, v, t, roll: bool = False, block_s: int = 512,
                           q8=q8),
         grid_spec=grid_spec,
         out_shape=out_struct((b * hkv, g, d), jnp.float32, qb, kb, vb),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)

@@ -11,8 +11,8 @@ accumulator is live for exactly one (i, j) tile at a time.
 Block sizes default to a size-adaptive schedule (see ``_auto_blocks``):
 the kernel's HBM traffic is ``2·m·n·k·itemsize·(1/bm + 1/bn)`` bytes, so
 fixed 256-tiles cap large bf16 matmuls at a ~64 TF/s bandwidth roofline
-on a v5e (measured: 20.5 ms at 8192³ ≡ the roofline's 21 ms prediction,
-benchmarks/results/kernels.json) while 512-tiles double the arithmetic
+on a v5e (measured 2026-07: 20.5 ms at 8192³ ≡ the roofline's 21 ms
+prediction) while 512-tiles double the arithmetic
 intensity into compute-bound territory. Full analysis and the measured
 evidence trail: docs/DESIGN.md §matmul; the on-chip sweep that validates
 or overrides these defaults is benchmarks/matmul_tune.py.
@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from lua_mapreduce_tpu.utils.jax_compat import tpu_compiler_params
 
 from lua_mapreduce_tpu.ops import out_struct, resolve_backend
 
@@ -66,7 +65,7 @@ def _auto_blocks(m: int, n: int, k: int) -> tuple:
     results/matmul_tune.json, v5e 2026-07-31) measured the winners:
     (1024, 1024, 512) at 4096³ (152.7 TF/s) and (512, 1024, 512) at
     8192³ (171.4 TF/s in the sweep; 151.6 = 0.896× XLA through the
-    standard bench that governs the auto policy, kernels.json — the
+    kernel bench of the same date — the
     shallower bm wins there on VMEM/pipeline pressure: the f32 acc at
     bm=1024 is 4 MB). VMEM at
     (512, 1024, 512) bf16: double-buffered A+B 3 MB + f32 acc 2 MB +
@@ -127,7 +126,7 @@ def _matmul_pallas(a, b, block_m: int | None = None,
         out_shape=out_struct((ap.shape[0], bp.shape[1]), out_dtype,
                              ap, bp),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * n * k,

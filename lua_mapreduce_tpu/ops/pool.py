@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from lua_mapreduce_tpu.utils.jax_compat import tpu_compiler_params
 
 from lua_mapreduce_tpu.ops import out_struct, resolve_backend
 from lua_mapreduce_tpu.ops.conv import _norm_stride
@@ -67,7 +66,7 @@ def _pool_pallas(x, window, stride, mode, interpret=False):
                                memory_space=pltpu.VMEM),
         out_shape=out_struct((n, ho, wo, c), x.dtype, x),
         # each image is independent — let Mosaic parallelize the batch
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x)

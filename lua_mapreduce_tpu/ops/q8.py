@@ -1,8 +1,8 @@
 """Weight-only int8 matmul — the serving-side quantization kernel.
 
 Decode is weight-bandwidth-bound: every generated token streams the
-full parameter set from HBM while the MXU idles (kernels.json's decode
-rows measure exactly this). Weight-only int8 halves that traffic — the
+full parameter set from HBM while the MXU idles. Weight-only int8
+halves that traffic — the
 kernel reads int8 weight tiles from HBM, converts to bf16 in VMEM for
 the MXU dot, and applies the per-output-channel scale ONCE on the f32
 accumulator (out[:, j] = (x @ q)[:, j] · s[j], exact because the scale
@@ -32,7 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from lua_mapreduce_tpu.utils.jax_compat import tpu_compiler_params
 
 from lua_mapreduce_tpu.ops import out_struct, resolve_backend
 
@@ -115,7 +114,7 @@ def _q8_matmul_pallas(x, q, s, block_m=256, block_n=512, block_k=512,
         out_shape=out_struct((xb.shape[0], qb.shape[1]), x.dtype,
                              xb, qb, sb),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xb, qb, sb)

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from lua_mapreduce_tpu.utils.jax_compat import tpu_compiler_params
 
 from lua_mapreduce_tpu.ops import out_struct, resolve_backend
 
@@ -66,7 +65,7 @@ def _rowwise_pallas(x, kernel, block_rows=256, interpret=False):
                                memory_space=pltpu.VMEM),
         out_shape=out_struct(x2.shape, x.dtype, x2),
         # each row block is independent — let Mosaic parallelize
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(x2)

@@ -41,11 +41,10 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from lua_mapreduce_tpu.ops.attention import flash_attention
-from lua_mapreduce_tpu.utils.jax_compat import shard_map
 
 _NEG_INF = -1e30      # finite mask fill: -inf breaks the m-subtraction
 

@@ -39,11 +39,10 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from lua_mapreduce_tpu.parallel.array_task import ArrayTaskSpec
-from lua_mapreduce_tpu.utils.jax_compat import shard_map
 
 _CROSS = {
     "sum": lax.psum,

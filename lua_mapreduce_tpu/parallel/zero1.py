@@ -23,9 +23,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-from lua_mapreduce_tpu.utils.jax_compat import shard_map
 
 
 def _chunk_len(n: int, n_dp: int) -> int:

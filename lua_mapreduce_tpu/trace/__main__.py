@@ -87,9 +87,10 @@ def render_text(col, top: int) -> str:
                        f"{d.get('reason', '')}")
         elif d["span"].startswith("lowering."):
             # per-stage hybrid verdict (DESIGN §28)
+            why = f" — {d['reason']}" if d.get("reason") else ""
             out.append(f"lowering: stage {d.get('stage')} -> "
                        f"{d.get('engine')} "
-                       f"(compiled={d.get('compiled')})")
+                       f"(compiled={d.get('compiled')}){why}")
         elif d["span"] == "hybrid.fallback":
             out.append(f"lowering: HYBRID FALLBACK it{d['it']} "
                        f"stage={d.get('stage')} — {d.get('reason', '')}")

@@ -27,13 +27,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from lua_mapreduce_tpu.parallel import zero1 as _z1
 from lua_mapreduce_tpu.train import checkpoint as ckpt
 from lua_mapreduce_tpu.train.accum import accum_value_and_grad
-from lua_mapreduce_tpu.utils.jax_compat import shard_map, stamp_replicated
+from lua_mapreduce_tpu.utils.jax_compat import stamp_replicated
 
 
 @dataclasses.dataclass

@@ -1,42 +1,9 @@
-"""JAX API drift shims (library-wide, lazily resolved).
-
-``jax.shard_map`` went public (with the ``check_vma`` kwarg) in newer
-JAX; installed older releases carry it as
-``jax.experimental.shard_map.shard_map`` with the same semantics under
-the ``check_rep`` kwarg. Every library call site routes through
-:func:`shard_map` here so the whole package — not just individual tests
-with local try/except shims — runs on both API generations.
-"""
+"""Helpers for ``jax.shard_map``'s varying-mesh-axes (vma) checker,
+which jax 0.9.0 (the one installation there is) keeps on by default."""
 
 from __future__ import annotations
 
-
-def shard_map(f, *args, **kwargs):
-    import jax
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-    return fn(f, *args, **kwargs)
-
-
-def vma_shard_map(f, *args, **kwargs):
-    """:func:`shard_map` for programs that close over ``pallas_call``.
-
-    Newer JAX's ``check_vma`` machinery carries replication rules for
-    ``pallas_call``, so kernels trace under the checker; the legacy
-    ``check_rep`` checker has no such rule and raises
-    ``NotImplementedError`` on any kernel-bearing body. On the legacy
-    API the check is therefore disabled (its documented workaround)
-    instead of crashing; on the public API full vma checking stays on.
-    """
-    import jax
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-        kwargs.setdefault("check_rep", False)
-    return fn(f, *args, **kwargs)
+import jax
 
 
 def spec_axes(spec) -> set:
@@ -55,21 +22,18 @@ def spec_axes(spec) -> set:
 
 def stamp_replicated(tree, axes):
     """Make mathematically-replicated shard_map outputs *statically*
-    replicated for the rep/vma checker (the ``shard_step`` out_specs
-    drift).
+    replicated for the vma checker.
 
-    Newer JAX rejects ``out_specs=P()`` for gradients of replicated
-    params at trace time: the transpose machinery still auto-psums the
-    replicated-input cotangents (the values ARE identical across
-    ``axes``), but the static checker cannot infer that through
-    ``value_and_grad``. ``lax.pmean`` over each axis is a numerical
-    identity on an already-replicated value and carries the replication
-    fact the checker needs — so the check stays ON (the loud failure
-    mode the call sites prefer) on every API generation, instead of
-    being disabled with ``check_vma=False`` (which on older JAX also
-    disables the auto-psum itself: silently un-summed grads).
+    JAX rejects ``out_specs=P()`` for gradients of replicated params at
+    trace time: the transpose machinery auto-psums the replicated-input
+    cotangents (the values ARE identical across ``axes``), but the
+    static checker cannot infer that through ``value_and_grad``.
+    ``lax.pmean`` over each axis is a numerical identity on an
+    already-replicated value and carries the replication fact the
+    checker needs — so the check stays ON (the loud failure mode the
+    call sites prefer) instead of being disabled with
+    ``check_vma=False``.
     """
-    import jax
     from jax import lax
     axes = tuple(a for a in axes if a)
     if not axes:
@@ -81,18 +45,3 @@ def stamp_replicated(tree, axes):
         return x
 
     return jax.tree.map(stamp, tree)
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` — renamed from ``TPUCompilerParams``.
-
-    Newer pallas dropped the ``TPU`` prefix (the module path already
-    says it); older releases only export the prefixed class. Same
-    constructor kwargs either way, so every kernel call site routes
-    through here instead of hard-coding one generation's name.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)

@@ -8,9 +8,7 @@ sees. :func:`device_trace` wraps any region in a jax.profiler trace
 whose output TensorBoard (or xprof) renders; train_lm's ``--profile``
 flag wires it around the train loop, and the distributed worker/server
 CLIs (cli/execute_worker.py, cli/execute_server.py) expose the same
-``--profile DIR`` around their execute/loop — always AFTER the
-jax_env.force_cpu_if_unavailable bootstrap, since entering the trace
-initializes the backend (the ordering note on device_trace below).
+``--profile DIR`` around their execute/loop.
 :func:`maybe_annotate` bridges lmr-trace span names (DESIGN §22) into
 the device profile so host and TPU timelines correlate.
 """
@@ -25,12 +23,8 @@ import os
 def device_trace(log_dir: str):
     """Trace everything inside the ``with`` to ``log_dir`` (created if
     missing). Traces include host Python annotations and, on TPU, the
-    device timeline; view with TensorBoard's profile plugin.
-
-    NOTE: entering the trace initializes the JAX backend — callers that
-    need the CPU fallback (utils/jax_env.force_cpu_if_unavailable) must
-    run it BEFORE this context, which is why train_lm starts its trace
-    inside run() after the bootstrap, never around it."""
+    device timeline; view with TensorBoard's profile plugin. Entering
+    the trace initializes the JAX backend."""
     import jax
 
     os.makedirs(log_dir, exist_ok=True)

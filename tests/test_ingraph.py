@@ -208,9 +208,17 @@ def _run_kmeans(engine, tag, **args):
     return ex, mr_kmeans.read_state("mem")
 
 
-def test_kmeans_allclose_and_compile_once():
+def test_kmeans_allclose_and_compile_once(capsys):
     ex_s, st_s = _run_kmeans("store", "s")
     ex_i, st_i = _run_kmeans("ingraph", "i")
+    # int(shard) on a traced key: the collective tier refuses the mapfn
+    # and the jit tier runs — on one device, so the reason is logged
+    # once and kept, not dropped
+    assert ex_i._ingraph.engine.mode == "jit"
+    assert "int()" in ex_i._ingraph.engine.collective_error \
+        or "Concretization" in ex_i._ingraph.engine.collective_error
+    err = capsys.readouterr().err
+    assert err.count("collective tier refused") == 1
     assert st_i["iter"] == st_s["iter"] == 4
     np.testing.assert_allclose(st_i["centroids"], st_s["centroids"],
                                rtol=1e-4, atol=1e-4)
@@ -264,9 +272,59 @@ def test_digits_sgd_allclose_collective_tier():
     # (shard_map over the mesh's dp axis) must carry this workload
     assert ex_i._ingraph.engine.mode == "shard_map"
     assert ex_i._ingraph.engine.traces == 1
+    # reduce = gradient sum: every fold must lower to psum (they fell
+    # back to all_gather, silently, when the sum proof could not run)
+    assert set(ex_i._ingraph.engine._plan.folds.values()) == {"psum"}
+    assert ex_i._ingraph.engine.collective_error is None
     for k in p_s:
         np.testing.assert_allclose(p_i[k], p_s[k], rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(val_i, val_s, rtol=1e-4)
+
+
+def _sgd_reducer_probe():
+    from examples.digits import mr_sgd
+    mod = "examples.digits.mr_sgd"
+    spec = TaskSpec(taskfn=mod, mapfn=mod, partitionfn=mod, reducefn=mod,
+                    finalfn=mod, init_args={"max_steps": 1},
+                    storage="mem:igsgd-probe")
+    template = {"g": np.zeros((16, 8), np.float32),
+                "count": np.zeros((), np.int32)}
+    return spec, mr_sgd.W1, template
+
+
+def test_sum_fold_proves_the_gradient_sum():
+    from lua_mapreduce_tpu.engine.ingraph import (_singleton_passthrough,
+                                                  _sum_fold)
+    spec, key, template = _sgd_reducer_probe()
+    assert _sum_fold(spec, key, template, 4) is True
+    assert _singleton_passthrough(spec, key, template) is True
+
+
+def test_sum_fold_says_no_only_to_a_reducer_that_is_not_a_sum(monkeypatch):
+    """A reducer that needs concrete values is "not a sum" (False); an
+    AttributeError out of JAX is API drift and must surface — swallowed,
+    it once turned every psum fold into an all_gather without a word."""
+    import types
+
+    import jax
+
+    from lua_mapreduce_tpu.engine import ingraph
+    spec, key, template = _sgd_reducer_probe()
+
+    def concretizing(k, values):
+        return {"g": values[0]["g"] * float(values[1]["count"]),
+                "count": values[0]["count"]}
+    needs_values = types.SimpleNamespace(
+        associative=True, commutative=True, reducefn=concretizing)
+    assert ingraph._sum_fold(needs_values, key, template, 2) is False
+
+    def drifted(*a, **kw):
+        raise AttributeError("module 'jax' has no attribute 'make_jaxpr'")
+    monkeypatch.setattr(jax, "make_jaxpr", drifted)
+    with pytest.raises(AttributeError, match="make_jaxpr"):
+        ingraph._sum_fold(spec, key, template, 2)
+    with pytest.raises(AttributeError, match="make_jaxpr"):
+        ingraph._singleton_passthrough(spec, key, template)
 
 
 # ---------------------------------------------------------------------------

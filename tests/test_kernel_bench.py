@@ -1,10 +1,20 @@
 """Smoke tests for the benchmark harness functions that are cheap on
-CPU: the bench code itself must stay runnable between hardware windows
-(the kernels.json drift of round 2 came from the script only ever being
-exercised on the wedge-prone chip)."""
+CPU: the bench code itself must stay runnable between chip runs."""
 
 import numpy as np
 import pytest
+
+
+@pytest.fixture(autouse=True)
+def cpu_in_the_peak_tables(monkeypatch):
+    """The bench functions report against utils/roofline's tables,
+    which hold TPUs only (an unknown device is an error there). Give
+    this box's CPU a stand-in row so the functions can be smoke-run
+    here; what they print under it is not a measurement."""
+    from lua_mapreduce_tpu.utils import roofline
+
+    monkeypatch.setitem(roofline.PEAK_BF16_FLOPS, "cpu", 1e12)
+    monkeypatch.setitem(roofline.PEAK_HBM_BYTES, "cpu", 1e9)
 
 
 def test_bench_conv_train_lenet_smoke():
@@ -96,8 +106,7 @@ def test_bench_pair_speedup_from_unrounded_seconds(monkeypatch):
     def make():
         return (lambda x: x, lambda x: x, (x,), None)
 
-    # force a known HBM bandwidth so the roofline check is exercised
-    monkeypatch.setenv("LMR_PEAK_HBM_BYTES", "1e9")
+    # the fixture's 1 GB/s HBM row exercises the roofline check
     out = kb._bench_pair(make)
     assert out["speedup_pallas_vs_xla"] == 1.0
     # 64 bytes in 20 ns = 3.2 GB/s > 1.1 * 1 GB/s → flagged on both

@@ -360,7 +360,7 @@ def test_lm_sigkill_mid_training_resumes(tmp_path):
         # of submit N is proven by submit N+1 (one write in flight at
         # most). Kill after the SECOND line → checkpoint #1 is on disk.
         # A watchdog kills a hung/drifted child so readline can't block
-        # the suite forever (the wedged-tunnel hang test_cli documents).
+        # the suite forever.
         import threading
         watchdog = threading.Timer(240, p.kill)
         watchdog.daemon = True

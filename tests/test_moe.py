@@ -6,12 +6,12 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from lua_mapreduce_tpu.parallel import moe
 from lua_mapreduce_tpu.parallel.mesh import make_mesh
-from lua_mapreduce_tpu.utils.jax_compat import (shard_map, spec_axes,
-                                                stamp_replicated)
+from lua_mapreduce_tpu.utils.jax_compat import spec_axes, stamp_replicated
 
 D, FF, E, CAP = 16, 32, 8, 4
 

@@ -56,7 +56,6 @@ def test_global_batch_array_roundtrip():
 
 _WORKER = r'''
 import os, sys
-from lua_mapreduce_tpu.utils.jax_compat import shard_map
 pid = int(sys.argv[1]); port = sys.argv[2]
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
@@ -70,6 +69,7 @@ assert multihost.initialize_multihost(
 import numpy as np
 import jax.numpy as jnp
 import optax
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from lua_mapreduce_tpu.models.mlp import init_mlp, nll_loss
@@ -236,6 +236,7 @@ assert multihost.initialize_multihost(
     process_id=pid)
 import numpy as np
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 assert jax.process_count() == 4 and len(jax.devices()) == 8

@@ -144,8 +144,7 @@ def test_avgpool_matches_xla():
 def test_default_backend_mapping():
     on_tpu = jax.default_backend() == "tpu"
     # off-TPU everything is XLA (Pallas-TPU kernels don't lower); on TPU
-    # "auto" resolves per op to the measured winner from
-    # benchmarks/results/kernels.json (ops/__init__._TPU_AUTO_POLICY)
+    # "auto" resolves per op through ops/__init__._TPU_AUTO_POLICY
     assert ops.default_backend() == ("pallas" if on_tpu else "xla")
     for op, tpu_winner in ops._TPU_AUTO_POLICY.items():
         want = tpu_winner if on_tpu else "xla"

@@ -2,7 +2,7 @@
 
 The reference publishes wall-clock tables only (README.md:43-113); the
 build's north star is an MFU figure (BASELINE.md), so the accounting
-itself needs tests: peak resolution order, the MFU formula, and that
+itself needs tests: the peak tables, the MFU formula, and that
 ``run_steps`` (the measured hot loop) computes the same training
 trajectory as discrete ``step`` calls.
 """
@@ -20,27 +20,31 @@ from lua_mapreduce_tpu.train.harness import (  # noqa: E402
 from lua_mapreduce_tpu.utils import roofline  # noqa: E402
 
 
-def test_peak_env_override(monkeypatch):
-    monkeypatch.setenv("LMR_PEAK_FLOPS", "1e15")
-    assert roofline.peak_flops_per_s() == 1e15
-
-
 def test_peak_known_generation_table():
     # table entries are per-chip bf16 figures; spot-check the bench chip
     assert roofline.PEAK_BF16_FLOPS["TPU v5 lite"] == 197e12
 
 
-def test_peak_unknown_kind_probes(monkeypatch):
-    monkeypatch.delenv("LMR_PEAK_FLOPS", raising=False)
-    # CPU device_kind is not in the table → measured-probe fallback
-    peak = roofline.peak_flops_per_s(jax.devices()[0])
-    assert peak > 0
-    # cached: second call returns the identical object fast
-    assert roofline.peak_flops_per_s(jax.devices()[0]) == peak
+def test_peak_unknown_kind_raises():
+    """A device that is not in the tables is an error, not a probed
+    default: a utilization against a guessed peak is not a metric."""
+    cpu = jax.devices()[0]
+    assert cpu.device_kind not in roofline.PEAK_BF16_FLOPS
+    with pytest.raises(ValueError, match="no peak bf16 FLOP/s on record"):
+        roofline.peak_flops_per_s(cpu)
+    with pytest.raises(ValueError, match="no peak HBM bandwidth on record"):
+        roofline.peak_hbm_bytes_per_s(cpu)
+    with pytest.raises(ValueError):
+        roofline.mfu(1e12, 1.0, device=cpu)
+
+
+def test_peak_tables_cover_the_same_devices():
+    assert set(roofline.PEAK_BF16_FLOPS) == set(roofline.PEAK_HBM_BYTES)
 
 
 def test_mfu_formula(monkeypatch):
-    monkeypatch.setenv("LMR_PEAK_FLOPS", "2e12")
+    monkeypatch.setitem(roofline.PEAK_BF16_FLOPS,
+                        jax.devices()[0].device_kind, 2e12)
     # 1e12 FLOPs in 1s on 1 chip of peak 2e12 → 50%
     assert roofline.mfu(1e12, 1.0, n_chips=1) == pytest.approx(0.5)
     assert roofline.mfu(1e12, 1.0, n_chips=2) == pytest.approx(0.25)

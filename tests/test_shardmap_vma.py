@@ -30,18 +30,14 @@ Two kinds of regression here:
 import functools
 
 import jax
-import jax.export   # noqa: F401  (not an autoloaded submodule on older JAX)
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import AbstractMesh, Mesh, PartitionSpec as P
 
 from lua_mapreduce_tpu import ops
 
-# vma_shard_map: public-API shard_map with full vma checking where the
-# checker understands pallas_call; on legacy experimental shard_map the
-# rep check is disabled (no pallas_call rule there) instead of crashing
-from lua_mapreduce_tpu.utils.jax_compat import vma_shard_map as shard_map
 
 
 def _abstract_mesh():

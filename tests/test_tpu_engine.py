@@ -10,12 +10,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 
 from lua_mapreduce_tpu.engine.contract import TaskSpec
 from lua_mapreduce_tpu.engine.local import LocalExecutor
 from lua_mapreduce_tpu.parallel import (ArrayTaskSpec, TpuExecutor, host_mesh)
 from lua_mapreduce_tpu.parallel import collectives
-from lua_mapreduce_tpu.utils.jax_compat import shard_map
 
 VOCAB = 64
 NUM_P = 16      # partitions; mesh dp=8 → 2 partitions per device

@@ -7,12 +7,12 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from lua_mapreduce_tpu.models import transformer as tfm
 from lua_mapreduce_tpu.parallel import zero1 as z1
 from lua_mapreduce_tpu.parallel.mesh import make_mesh
-from lua_mapreduce_tpu.utils.jax_compat import shard_map
 
 N_DP = 4
 
