@@ -1,0 +1,9 @@
+"""The share of the traced window in which no operation ran on the
+device (mean over the chips used)."""
+
+
+def read(context):
+    reduced = context.get("trace") or {}
+    if not reduced.get("window_s"):
+        return None
+    return 100.0 * (1.0 - reduced["busy_s"] / reduced["window_s"])
