@@ -45,6 +45,7 @@ from lua_mapreduce_tpu.parallel.ring_attention import (
     _zigzag_check, _zigzag_perm, attention_reference)
 from lua_mapreduce_tpu.train.accum import accum_value_and_grad
 from lua_mapreduce_tpu.utils.jax_compat import spec_axes, stamp_replicated
+from lua_mapreduce_tpu.utils.profiling import annotate, scope
 
 Params = Dict[str, jnp.ndarray]
 
@@ -372,21 +373,23 @@ def _block(params: Params, i: int, x, cfg: TransformerConfig, attn_fn,
     b, l, d = x.shape
     h, hd = cfg.n_heads, d // cfg.n_heads
     hkv = kv_heads(cfg)
-    y = _norm(params, f"{p}_ln1", x, cfg)
-    qkv = _mm(params, f"{p}_qkv_W", y)          # (B, L, (H+2Hkv)·hd) MXU
-    q = qkv[..., :h * hd].reshape(b, l, h, hd)
-    k = qkv[..., h * hd:(h + hkv) * hd].reshape(b, l, hkv, hd)
-    v = qkv[..., (h + hkv) * hd:].reshape(b, l, hkv, hd)
-    if cfg.rope:
-        q = _rope(q, pos, cfg.rope_base)
-        k = _rope(k, pos, cfg.rope_base)
-    if kv_sink is not None:
-        kv_sink.append((k, v))
-    a = attn_fn(q, k, v).reshape(b, l, d)
-    x = x + _mm(params, f"{p}_out_W", a)
-    y = _norm(params, f"{p}_ln2", x, cfg)
-    out, aux = _ffn(params, p, y, cfg, moe_axis)
-    return x + out, aux
+    with scope("lm.attn"):
+        y = _norm(params, f"{p}_ln1", x, cfg)
+        qkv = _mm(params, f"{p}_qkv_W", y)      # (B, L, (H+2Hkv)·hd) MXU
+        q = qkv[..., :h * hd].reshape(b, l, h, hd)
+        k = qkv[..., h * hd:(h + hkv) * hd].reshape(b, l, hkv, hd)
+        v = qkv[..., (h + hkv) * hd:].reshape(b, l, hkv, hd)
+        if cfg.rope:
+            q = _rope(q, pos, cfg.rope_base)
+            k = _rope(k, pos, cfg.rope_base)
+        if kv_sink is not None:
+            kv_sink.append((k, v))
+        a = attn_fn(q, k, v).reshape(b, l, d)
+        x = x + _mm(params, f"{p}_out_W", a)
+    with scope("lm.ffn"):
+        y = _norm(params, f"{p}_ln2", x, cfg)
+        out, aux = _ffn(params, p, y, cfg, moe_axis)
+        return x + out, aux
 
 
 def _check_seq(global_len: int, cfg: TransformerConfig) -> None:
@@ -404,9 +407,10 @@ def _forward(params: Params, tokens, pos, cfg: TransformerConfig,
     passes its tensor-parallel block) — one forward for every path.
     Returns (logits, summed moe aux loss; 0.0 for dense blocks)."""
     block = block or _block
-    x = params["tok_emb"][tokens]
-    if not cfg.rope:
-        x = x + params["pos_emb"][pos]   # rope positions live in-block
+    with scope("lm.embed"):
+        x = params["tok_emb"][tokens]
+        if not cfg.rope:
+            x = x + params["pos_emb"][pos]   # rope positions live in-block
     aux_total = 0.0
     for i in range(cfg.n_layers):
         if cfg.remat:
@@ -419,8 +423,9 @@ def _forward(params: Params, tokens, pos, cfg: TransformerConfig,
         else:
             x, aux = block(params, i, x, cfg, attn_fn, pos)
         aux_total = aux_total + aux
-    x = _norm(params, "lnf", x, cfg)
-    return _head(params, x), aux_total                  # tied head
+    with scope("lm.head"):
+        x = _norm(params, "lnf", x, cfg)
+        return _head(params, x), aux_total              # tied head
 
 
 def prefill(params: Params, prompt, *,
@@ -640,60 +645,69 @@ def greedy_decode(params: Params, prompt, n_new: int, *,
 
     def step(carry, t):
         caches, cur = carry
-        tok = jnp.where(t < p_len, given[:, t], cur)    # (B,)
-        x = params["tok_emb"][tok]                      # (B, D)
-        if not cfg.rope:
-            x = x + params["pos_emb"][t]
-        x = x[:, None, :]                               # (B, 1, D)
+        with scope("lm.embed"):
+            tok = jnp.where(t < p_len, given[:, t], cur)    # (B,)
+            x = params["tok_emb"][tok]                      # (B, D)
+            if not cfg.rope:
+                x = x + params["pos_emb"][t]
+            x = x[:, None, :]                               # (B, 1, D)
         for i in range(cfg.n_layers):
             pfx = f"L{i}"
-            y = _norm(params, f"{pfx}_ln1", x, cfg)
-            qkv = _mm(params, f"{pfx}_qkv_W", y)
-            q = qkv[..., :h * hd].reshape(b, 1, h, hd)
-            k = qkv[..., h * hd:(h + hkv) * hd].reshape(b, 1, hkv, hd)
-            v = qkv[..., (h + hkv) * hd:].reshape(b, 1, hkv, hd)
-            if cfg.rope:
-                # rotate THIS position; cache stores rotated keys (the
-                # same convention the prefill capture uses)
-                q = _rope(q, t[None], cfg.rope_base)
-                k = _rope(k, t[None], cfg.rope_base)
-            # (B, 1, Hkv, D) → (B, Hkv, 1, D) cache-layout row
-            k = jnp.transpose(k, (0, 2, 1, 3))
-            v = jnp.transpose(v, (0, 2, 1, 3))
-            # head index = (kv head, group member), kv-head major —
-            # the grouping decode_attention's (B, Hkv, G, D) q expects
-            q = q.reshape(b, hkv, g, hd)
-            slot = t % cache_len if roll else t
-            scales = {}
-            if kv_q8:
-                k, ks_row = quantize_kv(k)
-                v, vs_row = quantize_kv(v)
-                cks = lax.dynamic_update_slice(
-                    caches[f"{pfx}_ks"], ks_row, (0, 0, slot))
-                cvs = lax.dynamic_update_slice(
-                    caches[f"{pfx}_vs"], vs_row, (0, 0, slot))
-                caches = {**caches, f"{pfx}_ks": cks, f"{pfx}_vs": cvs}
-                scales = {"k_scale": cks, "v_scale": cvs}
-            ck = lax.dynamic_update_slice(
-                caches[f"{pfx}_k"], k, (0, 0, slot, 0))
-            cv = lax.dynamic_update_slice(
-                caches[f"{pfx}_v"], v, (0, 0, slot, 0))
-            caches = {**caches, f"{pfx}_k": ck, f"{pfx}_v": cv}
-            # fused decode attention (ops/decode.py): flash-decode
-            # kernel on TPU, the identical einsum+mask+softmax
-            # composition elsewhere. Non-roll windows are total-length
-            # (roll covers window < total), so slot<=t IS the mask.
-            a = decode_attention(q, ck, cv, t, roll=roll,
-                                 backend="auto", **scales)
-            a = a.astype(x.dtype).reshape(b, 1, cfg.d_model)
-            x = x + _mm(params, f"{pfx}_out_W", a)
-            y = _norm(params, f"{pfx}_ln2", x, cfg)
-            ff, _ = _ffn(params, pfx, y, step_cfg, None)
-            x = x + ff
-        x = _norm(params, "lnf", x, cfg)
-        logits = _head(params, x)[:, 0]                 # (B, vocab)
-        nxt = select(logits, t)
+            with scope("lm.attn"):
+                caches, x = step_attn(caches, x, t, pfx)
+            with scope("lm.ffn"):
+                y = _norm(params, f"{pfx}_ln2", x, cfg)
+                ff, _ = _ffn(params, pfx, y, step_cfg, None)
+                x = x + ff
+        with scope("lm.head"):
+            x = _norm(params, "lnf", x, cfg)
+            logits = _head(params, x)[:, 0]             # (B, vocab)
+            nxt = select(logits, t)
         return (caches, nxt), nxt
+
+    def step_attn(caches, x, t, pfx):
+        """One layer's attention at position ``t``: project, write this
+        position's cache row, attend the cache, project out."""
+        y = _norm(params, f"{pfx}_ln1", x, cfg)
+        qkv = _mm(params, f"{pfx}_qkv_W", y)
+        q = qkv[..., :h * hd].reshape(b, 1, h, hd)
+        k = qkv[..., h * hd:(h + hkv) * hd].reshape(b, 1, hkv, hd)
+        v = qkv[..., (h + hkv) * hd:].reshape(b, 1, hkv, hd)
+        if cfg.rope:
+            # rotate THIS position; cache stores rotated keys (the
+            # same convention the prefill capture uses)
+            q = _rope(q, t[None], cfg.rope_base)
+            k = _rope(k, t[None], cfg.rope_base)
+        # (B, 1, Hkv, D) → (B, Hkv, 1, D) cache-layout row
+        k = jnp.transpose(k, (0, 2, 1, 3))
+        v = jnp.transpose(v, (0, 2, 1, 3))
+        # head index = (kv head, group member), kv-head major —
+        # the grouping decode_attention's (B, Hkv, G, D) q expects
+        q = q.reshape(b, hkv, g, hd)
+        slot = t % cache_len if roll else t
+        scales = {}
+        if kv_q8:
+            k, ks_row = quantize_kv(k)
+            v, vs_row = quantize_kv(v)
+            cks = lax.dynamic_update_slice(
+                caches[f"{pfx}_ks"], ks_row, (0, 0, slot))
+            cvs = lax.dynamic_update_slice(
+                caches[f"{pfx}_vs"], vs_row, (0, 0, slot))
+            caches = {**caches, f"{pfx}_ks": cks, f"{pfx}_vs": cvs}
+            scales = {"k_scale": cks, "v_scale": cvs}
+        ck = lax.dynamic_update_slice(
+            caches[f"{pfx}_k"], k, (0, 0, slot, 0))
+        cv = lax.dynamic_update_slice(
+            caches[f"{pfx}_v"], v, (0, 0, slot, 0))
+        caches = {**caches, f"{pfx}_k": ck, f"{pfx}_v": cv}
+        # fused decode attention (ops/decode.py): flash-decode
+        # kernel on TPU, the identical einsum+mask+softmax
+        # composition elsewhere. Non-roll windows are total-length
+        # (roll covers window < total), so slot<=t IS the mask.
+        a = decode_attention(q, ck, cv, t, roll=roll,
+                             backend="auto", **scales)
+        a = a.astype(x.dtype).reshape(b, 1, cfg.d_model)
+        return caches, x + _mm(params, f"{pfx}_out_W", a)
 
     def select(logits, t):
         """Next token from (B, vocab) logits at position t — shared by
@@ -708,12 +722,8 @@ def greedy_decode(params: Params, prompt, n_new: int, *,
         return jax.random.categorical(
             jax.random.fold_in(key, t), lg, axis=-1).astype(jnp.int32)
 
-    if use_prefill:
-        if n_new == 0:
-            return prompt.astype(jnp.int32)
-        caches, last_logits = prefill(params, prompt, cfg=cfg,
-                                      total=total, mesh=mesh, attn=attn,
-                                      dp_axis=dp_axis, sp_axis=sp_axis)
+    def decode_layout(caches):
+        """Prefill's caches as the scan carries them."""
         # prefill's public contract is (B, S, H_kv, D); the decode scan
         # holds (B, H_kv, S, D) — one transpose at the boundary, not
         # one per step
@@ -724,30 +734,41 @@ def greedy_decode(params: Params, prompt, n_new: int, *,
             for n, c in caches.items():
                 quant[n], quant[n + "s"] = quantize_kv(c)
             caches = quant
-        if roll:
-            # fold the prompt cache into the rolling layout: slot j
-            # holds the LAST prompt position ≡ j (mod w). Scale entries
-            # (kv_q8) are (B, H_kv, S) — same slot axis, same fold.
-            if p_len >= cache_len:
-                j = jnp.arange(cache_len)
-                src = p_len - 1 - ((p_len - 1 - j) % cache_len)
-                caches = {n: c[:, :, src] for n, c in caches.items()}
-            else:
-                # positions 0..p_len-1 land in slots 0..p_len-1 and the
-                # prefill cache is already zero-padded beyond them —
-                # a plain truncation IS the rolling layout
-                caches = {n: c[:, :, :cache_len]
-                          for n, c in caches.items()}
-        tok1 = select(last_logits, p_len - 1)
+        if not roll:
+            return caches
+        # fold the prompt cache into the rolling layout: slot j
+        # holds the LAST prompt position ≡ j (mod w). Scale entries
+        # (kv_q8) are (B, H_kv, S) — same slot axis, same fold.
+        if p_len >= cache_len:
+            j = jnp.arange(cache_len)
+            src = p_len - 1 - ((p_len - 1 - j) % cache_len)
+            return {n: c[:, :, src] for n, c in caches.items()}
+        # positions 0..p_len-1 land in slots 0..p_len-1 and the
+        # prefill cache is already zero-padded beyond them —
+        # a plain truncation IS the rolling layout
+        return {n: c[:, :, :cache_len] for n, c in caches.items()}
+
+    if use_prefill:
+        if n_new == 0:
+            return prompt.astype(jnp.int32)
+        with scope("lm.prefill"):
+            caches, last_logits = prefill(
+                params, prompt, cfg=cfg, total=total, mesh=mesh, attn=attn,
+                dp_axis=dp_axis, sp_axis=sp_axis)
+            caches = decode_layout(caches)
+        with scope("lm.first_token"):
+            tok1 = select(last_logits, p_len - 1)
         # remaining n_new - 1 positions ride the ordinary step scan
-        (_, _), emitted = lax.scan(step, (caches, tok1),
-                                   jnp.arange(p_len, total - 1))
+        with scope("lm.decode"):
+            (_, _), emitted = lax.scan(step, (caches, tok1),
+                                       jnp.arange(p_len, total - 1))
         gen = jnp.concatenate(
             [tok1[:, None], jnp.transpose(emitted, (1, 0))], axis=1)
         return jnp.concatenate([prompt.astype(jnp.int32), gen], axis=1)
 
-    (_, _), emitted = lax.scan(step, (caches, given[:, 0]),
-                               jnp.arange(total))
+    with scope("lm.decode"):
+        (_, _), emitted = lax.scan(step, (caches, given[:, 0]),
+                                   jnp.arange(total))
     # emitted[t] is the model's prediction AFTER seeing position t;
     # output = prompt ‖ generated continuation
     gen = jnp.transpose(emitted, (1, 0))[:, p_len - 1:total - 1]
@@ -889,8 +910,12 @@ def lm_loss_local(params, tokens, targets, cfg, attn_fn, pos, block=None):
     tile (targets pre-shifted by the caller — with a sharded sequence
     the shift crosses shard edges, so it happens host-side before
     sharding)."""
-    logits, aux = _forward(params, tokens, pos, cfg, attn_fn, block=block)
-    return _mean_nll(logits, targets) + cfg.moe_aux_weight * aux
+    with scope("lm.loss"):
+        logits, aux = _forward(params, tokens, pos, cfg, attn_fn,
+                               block=block)
+        with scope("lm.head"):
+            nll = _mean_nll(logits, targets)
+        return nll + cfg.moe_aux_weight * aux
 
 
 def param_specs_moe(ep_axis: str = "dp") -> Dict[str, object]:
@@ -1053,12 +1078,13 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer, *,
         # sp first: grads must be identical along every non-dp axis
         # before the dp reduce-scatter
         grads = jax.tree.map(lambda g: lax.pmean(g, sp_axis), grads)
-        params, opt_state = _z1.update_chunks(
-            optimizer, params, grads, opt_state, dp_axis, n_dp)
+        with scope("lm.opt"):
+            params, opt_state = _z1.update_chunks(
+                optimizer, params, grads, opt_state, dp_axis, n_dp)
         return params, opt_state, lax.pmean(
             lax.pmean(loss, sp_axis), dp_axis)
 
-    def step(params, opt_state, tokens, targets):
+    def lm_train_step(params, opt_state, tokens, targets):
         # specs derive from the ACTUAL param keys (cannot drift from
         # init_transformer; same pattern as the 3-D step)
         specs = {k: _spec_for(k, suffix) for k in params} \
@@ -1084,11 +1110,12 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer, *,
             in_specs=(specs, P(dp_axis, sp_axis), P(dp_axis, sp_axis)),
             out_specs=(P(), specs))
         loss, grads = mapped(params, tokens, targets)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with scope("lm.opt"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
-    return jax.jit(step, donate_argnums=(0, 1))
+    return jax.jit(lm_train_step, donate_argnums=(0, 1))
 
 
 def shard_batch(mesh, tokens, targets, dp_axis="dp", sp_axis="sp",
@@ -1109,8 +1136,9 @@ def shard_batch(mesh, tokens, targets, dp_axis="dp", sp_axis="sp",
     elif schedule != "contiguous":
         raise ValueError(f"unknown schedule {schedule!r}")
     sharding = NamedSharding(mesh, P(dp_axis, sp_axis))
-    return (jax.device_put(tokens, sharding),
-            jax.device_put(targets, sharding))
+    with annotate("lm.shard_batch"):
+        return (jax.device_put(tokens, sharding),
+                jax.device_put(targets, sharding))
 
 
 # ---------------------------------------------------------------------------
@@ -1192,23 +1220,26 @@ def _block_tp(params: Params, i: int, x, cfg: TransformerConfig, attn_fn,
     global ``pos`` (per-head independent, so head sharding is free);
     swiglu shards gate/up columns and down rows like gelu's ff1/ff2."""
     p = f"L{i}"
-    y = _norm(params, f"{p}_ln1", x, cfg)
-    w_qkv = params[f"{p}_qkv_W"]                # (d, 3, H/mp, hd) local
-    q, k, v = (jnp.einsum("bld,dhk->blhk", y, w_qkv[:, t])
-               for t in range(3))               # (B, L, H/mp, hd)
-    if cfg.rope:
-        q = _rope(q, pos, cfg.rope_base)
-        k = _rope(k, pos, cfg.rope_base)
-    a = attn_fn(q, k, v)                        # this mp slice's heads
-    partial = jnp.einsum("blhk,hkd->bld", a, params[f"{p}_out_W"])
-    x = x + lax.psum(partial, mp_axis)          # Megatron sync point 1
-    y = _norm(params, f"{p}_ln2", x, cfg)
-    if cfg.ffn == "swiglu":
-        h = jax.nn.silu(y @ params[f"{p}_ff1_W"]) * (y @ params[f"{p}_ff3_W"])
-        return x + lax.psum(h @ params[f"{p}_ff2_W"], mp_axis), 0.0
-    y = jax.nn.gelu(y @ params[f"{p}_ff1_W"] + params[f"{p}_ff1_b"])
-    partial = y @ params[f"{p}_ff2_W"]
-    return x + lax.psum(partial, mp_axis) + params[f"{p}_ff2_b"], 0.0
+    with scope("lm.attn"):
+        y = _norm(params, f"{p}_ln1", x, cfg)
+        w_qkv = params[f"{p}_qkv_W"]            # (d, 3, H/mp, hd) local
+        q, k, v = (jnp.einsum("bld,dhk->blhk", y, w_qkv[:, t])
+                   for t in range(3))           # (B, L, H/mp, hd)
+        if cfg.rope:
+            q = _rope(q, pos, cfg.rope_base)
+            k = _rope(k, pos, cfg.rope_base)
+        a = attn_fn(q, k, v)                    # this mp slice's heads
+        partial = jnp.einsum("blhk,hkd->bld", a, params[f"{p}_out_W"])
+        x = x + lax.psum(partial, mp_axis)      # Megatron sync point 1
+    with scope("lm.ffn"):
+        y = _norm(params, f"{p}_ln2", x, cfg)
+        if cfg.ffn == "swiglu":
+            h = (jax.nn.silu(y @ params[f"{p}_ff1_W"])
+                 * (y @ params[f"{p}_ff3_W"]))
+            return x + lax.psum(h @ params[f"{p}_ff2_W"], mp_axis), 0.0
+        y = jax.nn.gelu(y @ params[f"{p}_ff1_W"] + params[f"{p}_ff1_b"])
+        partial = y @ params[f"{p}_ff2_W"]
+        return x + lax.psum(partial, mp_axis) + params[f"{p}_ff2_b"], 0.0
 
 
 def make_train_step_3d(cfg: TransformerConfig, mesh, optimizer, *,
@@ -1264,7 +1295,7 @@ def make_train_step_3d(cfg: TransformerConfig, mesh, optimizer, *,
     def specs_tree(params_like):
         return {k: _spec_for(k, specs) for k in params_like}
 
-    def step(params, opt_state, tokens, targets):
+    def lm_train_step(params, opt_state, tokens, targets):
         # same internal zigzag permutation as the 2-D step
         tokens, targets, _ = _maybe_zigzag(attn, n_sp, tokens, targets,
                                            pre_permuted=zigzag_layout)
@@ -1274,11 +1305,12 @@ def make_train_step_3d(cfg: TransformerConfig, mesh, optimizer, *,
                       P(dp_axis, sp_axis)),
             out_specs=(P(), specs_tree(params)))
         loss, grads = mapped(params, tokens, targets)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with scope("lm.opt"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
-    return jax.jit(step, donate_argnums=(0, 1))
+    return jax.jit(lm_train_step, donate_argnums=(0, 1))
 
 
 # ---------------------------------------------------------------------------
@@ -1376,13 +1408,15 @@ def make_train_step_pp(cfg: TransformerConfig, mesh, optimizer, *,
         tok_m = tokens.reshape(n_micro, mb, l)
         tgt_m = targets.reshape(n_micro, mb, l)
 
+        @scope("lm.loss")
         def global_loss(p):
             local_layers = {k[len("layers_"):]: v for k, v in p.items()
                             if k.startswith("layers_")}
             pos = jnp.arange(l)
-            x_micro = p["tok_emb"][tok_m]
-            if not cfg.rope:
-                x_micro = x_micro + p["pos_emb"][pos]
+            with scope("lm.embed"):
+                x_micro = p["tok_emb"][tok_m]
+                if not cfg.rope:
+                    x_micro = x_micro + p["pos_emb"][pos]
 
             def stage(x):
                 def body(x, w):
@@ -1392,9 +1426,10 @@ def make_train_step_pp(cfg: TransformerConfig, mesh, optimizer, *,
 
             outs = pipeline_apply(stage, x_micro, pp_axis=pp_axis,
                                   n_stages=n_pp)       # (M, mb, l, d)
-            x = _norm(p, "lnf", outs, cfg)
-            logits = x @ p["tok_emb"].T
-            return _mean_nll(logits, tgt_m)
+            with scope("lm.head"):
+                x = _norm(p, "lnf", outs, cfg)
+                logits = x @ p["tok_emb"].T
+                return _mean_nll(logits, tgt_m)
 
         return jax.value_and_grad(global_loss)(params)
 
@@ -1402,14 +1437,15 @@ def make_train_step_pp(cfg: TransformerConfig, mesh, optimizer, *,
         return {k: (P(pp_axis) if k.startswith("layers_") else P())
                 for k in params}
 
-    def step(params, opt_state, tokens, targets):
+    def lm_train_step(params, opt_state, tokens, targets):
         specs = specs_for(params)
         mapped = shard_map(
             shard_step, mesh=mesh, in_specs=(specs, P(), P()),
             out_specs=(P(), specs))
         loss, grads = mapped(params, tokens, targets)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with scope("lm.opt"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
-    return jax.jit(step, donate_argnums=(0, 1))
+    return jax.jit(lm_train_step, donate_argnums=(0, 1))

@@ -382,6 +382,7 @@ def _flash_pallas(q, k, v, causal, block_q=None, block_k=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_pallas",
     )(qb, kb, vb)
 
     out = res[0]
@@ -573,6 +574,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, block_q=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_pallas_dq",
     )(qb, kb, vb, dob, lse_r, delta_r)
 
     # dkv grid: one row per KV head, kv-block outer, and the innermost
@@ -605,6 +607,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, block_q=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_pallas_dkv",
     )(qb, kb, vb, dob, lse_r, delta_r)
 
     def from_bh(x, ln, heads):

@@ -246,6 +246,7 @@ def _decode_pallas(q, k, v, t, roll: bool = False, block_s: int = 512,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="_decode_pallas",
     )(*operands)
     return out.reshape(b, hkv, g, d)
 

@@ -117,6 +117,7 @@ def _q8_matmul_pallas(x, q, s, block_m=256, block_n=512, block_k=512,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="q8_matmul_pallas",
     )(xb, qb, sb)
     return out[:m, :n]
 

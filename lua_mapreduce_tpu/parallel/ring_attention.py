@@ -45,6 +45,7 @@ from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from lua_mapreduce_tpu.ops.attention import flash_attention
+from lua_mapreduce_tpu.utils.profiling import scope
 
 _NEG_INF = -1e30      # finite mask fill: -inf breaks the m-subtraction
 
@@ -142,8 +143,9 @@ def _ring_shard(q, k, v, *, axis: str, n_shards: int, causal: bool,
         kb, vb = k, v
         for i in range(1, hops + 1):
             perm = [(j, (j + 1) % n_shards) for j in range(n_shards)]
-            kb = lax.ppermute(kb, axis, perm)
-            vb = lax.ppermute(vb, axis, perm)
+            with scope("lm.ring"):
+                kb = lax.ppermute(kb, axis, perm)
+                vb = lax.ppermute(vb, axis, perm)
             # wrapped sources (src > my, i.e. my < i) are above the
             # causal diagonal — skipped; the kernel's banded mask
             # handles everything else with the static offset i·L_loc
@@ -174,8 +176,9 @@ def _ring_shard(q, k, v, *, axis: str, n_shards: int, causal: bool,
         # ppermute j→j+1 receives from the anticlockwise neighbor: after
         # i rotations this device holds the KV of shard (my - i) mod P
         perm = [(j, (j + 1) % n_shards) for j in range(n_shards)]
-        kb = lax.ppermute(kb, axis, perm)
-        vb = lax.ppermute(vb, axis, perm)
+        with scope("lm.ring"):
+            kb = lax.ppermute(kb, axis, perm)
+            vb = lax.ppermute(vb, axis, perm)
         o, lse = fold(o, lse, kb, vb, (my - i) % n_shards)
         return (o, lse, kb, vb), None
 
@@ -282,8 +285,9 @@ def _ring_shard_zigzag(q, k, v, *, axis: str, n_shards: int,
     def step(carry, i):
         o, lse, kb, vb = carry
         perm = [(j, (j + 1) % n_shards) for j in range(n_shards)]
-        kb = lax.ppermute(kb, axis, perm)
-        vb = lax.ppermute(vb, axis, perm)
+        with scope("lm.ring"):
+            kb = lax.ppermute(kb, axis, perm)
+            vb = lax.ppermute(vb, axis, perm)
         o, lse = fold(o, lse, kb, vb, (my - i) % n_shards)
         return (o, lse, kb, vb), None
 

@@ -1,0 +1,282 @@
+"""The names the LM program gives its own device work (utils/profiling:
+LM_SCOPES, LM_KERNELS, LM_PROGRAMS, LM_HOST_SPANS): they reach the
+lowered program, the kernels carry their pinned names, and none of it
+changes a number. CPU, tiny widths."""
+
+import contextlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh
+
+from lua_mapreduce_tpu import ops
+from lua_mapreduce_tpu.models import transformer as tfm
+from lua_mapreduce_tpu.parallel import ring_attention
+from lua_mapreduce_tpu.utils import profiling
+
+CFG = tfm.TransformerConfig.llama_style(
+    vocab=64, d_model=32, n_heads=4, n_kv_heads=2, n_layers=2, d_ff=64,
+    max_seq=128, window=16)
+TRAIN_SCOPES = ("lm.loss", "lm.embed", "lm.attn", "lm.ffn", "lm.head",
+                "lm.opt")
+DECODE_SCOPES = ("lm.prefill", "lm.first_token", "lm.decode", "lm.embed",
+                 "lm.attn", "lm.ffn", "lm.head")
+
+
+def train_setup(shape, make=tfm.make_train_step, **kw):
+    dp, sp = shape
+    mesh = Mesh(np.array(jax.devices()[:dp * sp]).reshape(dp, sp),
+                ("dp", "sp"))
+    opt = optax.adam(1e-2)
+    params = tfm.shard_params_moe(
+        tfm.init_transformer(jax.random.PRNGKey(0), CFG), mesh)
+    state = tfm.init_opt_state(opt, params, mesh)
+    rows = np.arange(4 * 33, dtype=np.int32).reshape(4, 33) % CFG.vocab
+    batch = tfm.shard_batch(mesh, rows[:, :-1], rows[:, 1:])
+    return make(CFG, mesh, opt, **kw), params, state, batch
+
+
+def scope_paths(lowered) -> set:
+    """Every operation's scope path in the lowered module's locations."""
+    return set(re.findall(r'loc\("([^"]*)"', lowered.as_text(debug_info=True)))
+
+
+@pytest.fixture(scope="module")
+def lowered_train():
+    out = {}
+    for shape in ((1, 1), (2, 2)):
+        step, params, state, batch = train_setup(shape)
+        out[shape] = step.lower(params, state, *batch)
+    return out
+
+
+@pytest.fixture(scope="module")
+def lowered_decode():
+    params = tfm.init_transformer(jax.random.PRNGKey(0), CFG)
+    prompt = jnp.zeros((2, 8), jnp.int32)
+    return tfm.greedy_decode.lower(params, prompt, 4, cfg=CFG,
+                                   use_prefill=True)
+
+
+@pytest.mark.parametrize("shape", [(1, 1), (2, 2)])
+@pytest.mark.parametrize("name", TRAIN_SCOPES)
+def test_the_train_step_carries_the_scope(lowered_train, shape, name):
+    paths = scope_paths(lowered_train[shape])
+    assert any(name in p.split("/") or f"({name})" in p for p in paths), name
+
+
+@pytest.mark.parametrize("shape", [(1, 1), (2, 2)])
+def test_backward_is_told_from_forward_by_transpose(lowered_train, shape):
+    paths = scope_paths(lowered_train[shape])
+    for block in ("lm.attn", "lm.ffn", "lm.head"):
+        assert any(f"jvp(lm.loss)/{block}" in p and "transpose(" not in p
+                   for p in paths), block
+        assert any(f"transpose(jvp(lm.loss))/{block}" in p
+                   for p in paths), block
+    # the optimizer's work is under neither
+    assert not any("lm.opt" in p and "lm.loss" in p for p in paths)
+
+
+def test_the_ring_exchange_is_scoped_on_the_mesh_only(lowered_train):
+    ring = [p for p in scope_paths(lowered_train[2, 2]) if "lm.ring" in p]
+    assert ring and all(p.endswith("lm.ring/ppermute") for p in ring)
+    assert any("transpose(" in p for p in ring)      # and in the backward
+    assert not any("lm.ring" in p for p in scope_paths(lowered_train[1, 1]))
+
+
+@pytest.mark.parametrize("shape", [(1, 1), (2, 2)])
+def test_the_train_program_is_named(lowered_train, shape):
+    assert "lm_train_step" in profiling.LM_PROGRAMS
+    text = lowered_train[shape].as_text()
+    assert re.search(r"module @jit_lm_train_step\b", text)
+    assert lowered_train[shape].compile().as_text().startswith(
+        "HloModule jit_lm_train_step")
+
+
+@pytest.mark.parametrize("make,kw", [
+    (tfm.make_train_step, {"zero1": True}),
+    (tfm.make_train_step_3d, {}),
+    (tfm.make_train_step_pp, {"n_micro": 2})])
+def test_the_other_builders_name_program_and_optimizer(make, kw):
+    """ZeRO-1, 3-D and pipeline forms share the names (no cell runs them
+    yet): the program, `lm.loss` and `lm.opt`."""
+    cfg = tfm.TransformerConfig.llama_style(
+        vocab=64, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_seq=128)
+    opt = optax.adam(1e-2)
+    params = tfm.init_transformer(jax.random.PRNGKey(0), cfg)
+    tok = jnp.zeros((4, 32), jnp.int32)
+    if make is tfm.make_train_step_pp:
+        mesh = Mesh(np.array(jax.devices()[:2]), ("pp",))
+        params = tfm.shard_params_pp(params, mesh, cfg)
+        state = opt.init(params)
+    elif make is tfm.make_train_step_3d:
+        mesh = Mesh(np.array(jax.devices()[:2]).reshape(1, 1, 2),
+                    ("dp", "sp", "mp"))
+        params = tfm.shard_params_3d(params, mesh, cfg)
+        state = opt.init(params)
+    else:
+        from lua_mapreduce_tpu.parallel import zero1
+        mesh = Mesh(np.array(jax.devices()[:2]).reshape(2, 1), ("dp", "sp"))
+        state = zero1.init_state(opt, params, mesh)
+    lowered = make(cfg, mesh, opt, **kw).lower(params, state, tok, tok)
+    assert "module @jit_lm_train_step" in lowered.as_text()
+    paths = scope_paths(lowered)
+    for name in ("lm.opt", "jvp(lm.loss)", "transpose(jvp(lm.loss))",
+                 "lm.attn", "lm.ffn", "lm.head", "lm.embed"):
+        assert any(name in p for p in paths), name
+
+
+@pytest.mark.parametrize("name", DECODE_SCOPES)
+def test_the_decode_request_carries_the_scope(lowered_decode, name):
+    assert "greedy_decode" in profiling.LM_PROGRAMS
+    assert "module @jit_greedy_decode" in lowered_decode.as_text()
+    assert any(name in p.split("/") for p in scope_paths(lowered_decode))
+
+
+def test_decode_blocks_lie_under_their_phase(lowered_decode):
+    # the scan's body is a function of its own in the lowered text; the
+    # compiled program's `op_name` has the whole path
+    paths = set(re.findall(r'op_name="([^"]*)"',
+                           lowered_decode.compile().as_text()))
+    for block in ("lm.embed", "lm.attn", "lm.ffn", "lm.head"):
+        assert any(f"lm.prefill/{block}/" in p for p in paths), block
+        assert any(p.startswith("jit(greedy_decode)/lm.decode/while/body/")
+                   and f"/{block}/" in p for p in paths), block
+    first = [p for p in paths if "lm.first_token" in p]
+    assert first and not any("lm.prefill" in p or "lm.decode" in p
+                             for p in first)
+
+
+def test_every_scope_of_the_contract_is_used(lowered_train, lowered_decode):
+    found = scope_paths(lowered_train[2, 2]) | scope_paths(lowered_decode)
+    for name in profiling.LM_SCOPES:
+        assert any(name in p for p in found), name
+    assert set(TRAIN_SCOPES + DECODE_SCOPES + ("lm.ring",)) == \
+        set(profiling.LM_SCOPES)
+
+
+def pallas_calls(jaxpr, out=None) -> list:
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else [value]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    pallas_calls(sub, out)
+    return out
+
+
+def kernel_sites():
+    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+
+    def flash(q, k, v):
+        return ops.flash_attention(q, k, v, causal=True,
+                                   backend="pallas_interpret").sum()
+
+    def decode(q, k, v):
+        return ops.decode_attention(q, k, v, jnp.int32(5),
+                                    backend="pallas_interpret")
+
+    def q8(x, w):
+        return ops.q8_matmul(x, *ops.quantize_q8(w),
+                             backend="pallas_interpret")
+
+    cache = jnp.ones((1, 2, 128, 64), jnp.float32)
+    return {
+        "flash_pallas": (flash, (q, q, q)),
+        "flash_bwd_pallas_dq": (jax.grad(flash, (0, 1, 2)), (q, q, q)),
+        "flash_bwd_pallas_dkv": (jax.grad(flash, (0, 1, 2)), (q, q, q)),
+        "_decode_pallas": (decode, (jnp.ones((1, 2, 2, 64)), cache, cache)),
+        "q8_matmul_pallas": (q8, (jnp.ones((8, 128)), jnp.ones((128, 128)))),
+    }
+
+
+@pytest.mark.parametrize("name", profiling.LM_KERNELS)
+def test_the_kernel_carries_its_pinned_name(name):
+    fn, args = kernel_sites()[name]
+    names = pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert name in names
+    assert set(names) <= set(profiling.LM_KERNELS)
+
+
+def test_a_scope_renames_no_kernel():
+    """What `name=` is for: the enclosing scope and the transformation
+    change the path, never the kernel's own name."""
+    fn, args = kernel_sites()["flash_bwd_pallas_dq"]
+    with profiling.scope("lm.attn"):
+        names = pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert sorted(set(names)) == ["flash_bwd_pallas_dkv",
+                                  "flash_bwd_pallas_dq", "flash_pallas"]
+
+
+@pytest.mark.parametrize("shape", [(1, 1), (2, 2)])
+def test_scopes_change_no_number(monkeypatch, shape):
+    """The same step built with every scope a no-op returns bit-equal
+    loss and parameters."""
+    def run():
+        step, params, state, batch = train_setup(shape)
+        for _ in range(2):
+            params, state, loss = step(params, state, *batch)
+        return jax.device_get((loss, params))
+
+    loss, params = run()
+    for module in (tfm, ring_attention):
+        monkeypatch.setattr(module, "scope",
+                            lambda name: contextlib.nullcontext())
+    step, p0, s0, batch = train_setup(shape)
+    assert not any("lm." in p for p in scope_paths(step.lower(p0, s0, *batch)))
+    bare_loss, bare_params = run()
+    assert np.array_equal(loss, bare_loss)
+    for k in params:
+        assert np.array_equal(params[k], bare_params[k]), k
+
+
+def test_scoped_decode_returns_the_same_tokens(monkeypatch):
+    params = tfm.init_transformer(jax.random.PRNGKey(1), CFG)
+    prompt = jnp.arange(16, dtype=jnp.int32).reshape(2, 8) % CFG.vocab
+    scoped = np.asarray(tfm.greedy_decode(params, prompt, 24, cfg=CFG,
+                                          use_prefill=True))
+    stepped = np.asarray(tfm.greedy_decode(params, prompt, 24, cfg=CFG))
+    assert np.array_equal(scoped, stepped)       # rolling window: 32 > 16
+    monkeypatch.setattr(tfm, "scope", lambda name: contextlib.nullcontext())
+    bare = tfm.greedy_decode.__wrapped__(params, prompt, 24, cfg=CFG,
+                                         use_prefill=True)
+    assert np.array_equal(scoped, np.asarray(bare))
+
+
+def host_events(trace_dir) -> list:
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                     "*.xplane.pb"))
+    return [ev.name for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events]
+
+
+def test_shard_batch_writes_its_host_span(tmp_path):
+    """`lm.shard_batch` lands on the profiler's host plane through
+    `device_trace`, which opens the profiler without Python call stacks
+    (as the benchmark does), beside JAX's own row of the program."""
+    step, params, state, batch = train_setup((1, 1))
+    params, state, _ = step(params, state, *batch)           # compiled
+    mesh = batch[0].sharding.mesh
+    rows = np.zeros((4, 32), np.int32)
+    with profiling.device_trace(str(tmp_path)):
+        batch = tfm.shard_batch(mesh, rows, rows)
+        jax.block_until_ready(step(params, state, *batch))
+    names = host_events(str(tmp_path))
+    assert names.count("lm.shard_batch") == 1
+    assert profiling.LM_HOST_SPANS == ("lm.shard_batch",)
+    assert any(n.startswith("PjitFunction(lm_train_step)") for n in names)
+    # python_tracer_level=0: no Python frames in the trace
+    assert not any(n.startswith("$") for n in names)
