@@ -12,9 +12,9 @@ forms that must agree (golden-diff discipline, SURVEY.md §4):
   (all_to_all head reshard). No device ever holds a full sequence —
   context length scales with the sp axis.
 - :func:`make_train_step` — jitted SPMD LM training step over the mesh:
-  per-device loss on its (batch, seq) tile, gradient pmean over BOTH axes
-  fused into the backward pass (the reference's reducefn-sum shape,
-  common.lua:112-137).
+  per-device loss on its (batch, seq) tile, gradient all-reduce over
+  BOTH axes behind the backward pass, in the same program (the
+  reference's reducefn-sum shape, common.lua:112-137).
 
 Params are a flat name→array dict (the grad-shuffle key space, like every
 model in this zoo). Layout: activations (B, L, D); attention heads split
@@ -960,8 +960,11 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer, *,
                     zigzag_layout: bool = False, zero1: bool = False):
     """Jitted SPMD LM train step: ``step(params, opt_state, tokens,
     targets) -> (params, opt_state, loss)`` with tokens/targets sharded
-    P(dp, sp) and the gradient all-reduce (pmean over dp AND sp) fused
-    into the backward pass.
+    P(dp, sp). The gradient all-reduce over dp AND sp is the transpose
+    of the loss's pmean, no call of this function's; XLA runs it in line
+    behind the backward pass, hidden by nothing (all of its 75.4 ms a
+    step exposed on the 2x2; ledger, PR 26). Every gradient leaf is
+    written out in its own type before the optimizer reads it.
 
     ``grad_accum`` > 1 folds that many microbatches (split along each
     device's batch rows) in a lax.scan before the single optimizer
@@ -1036,6 +1039,16 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer, *,
                 stamp=lambda l, g: (
                     stamp_replicated(l, (dp_axis, sp_axis)),
                     stamp_replicated(g, (dp_axis, sp_axis))))
+        # each leaf is written out in its own type before anything
+        # reads it. Left alone on one device, XLA fuses optimizer.update
+        # into the weight-gradient matmuls' output and they run at half
+        # their speed: `subtract_add_fusion (bf16[4096,14336],
+        # f32[4096,14336], ...)` 0.1982 s and `(bf16[14336,4096],
+        # f32[14336,4096], ...)` 0.0821 s over 3 steps, 16.5 and 13.7 ms
+        # an instance for a 7.3 ms matmul (ledger, PR 26; ISSUE 27). On
+        # a mesh the all-reduce already stands between the two and the
+        # compiled program is the same with and without.
+        grads = {k: lax.optimization_barrier(g) for k, g in grads.items()}
         # per-leaf replication stamp (utils/jax_compat.py): each grad
         # is replicated over the data axes its out_spec omits (the
         # transpose machinery psums replicated-param cotangents; MoE

@@ -1,0 +1,144 @@
+"""Compiles for the chip, without the chip: the TPU's compiler is
+installed here and compiles for a described `v5e:2x2`. Nothing runs, so
+nothing here is a time or a result; what it guards is the SHAPE of the
+compiled train step at the train cell's real widths (one layer).
+
+The topology is described inside a fixture and never at import: one
+process at a time may load the TPU's library, and every xdist worker
+imports every test file. Keep every such compile in this one file.
+"""
+
+import collections
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from lua_mapreduce_tpu import ops
+from lua_mapreduce_tpu.models import transformer as tfm
+from lua_mapreduce_tpu.train.precision import with_f32_master
+
+CONFIG = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "perfbench", "configs",
+    "mistral-7b-v0.1.train.json")
+SEQ = 4096
+ROWS = {(1, 1): 3, (2, 2): 8}       # the two train cells' batches
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    with open(CONFIG) as f:
+        c = json.load(f)
+    return tfm.TransformerConfig(
+        vocab=c["vocab_size"], d_model=c["hidden_size"],
+        n_heads=c["num_attention_heads"], n_layers=1,
+        d_ff=c["intermediate_size"], max_seq=c["max_position_embeddings"],
+        n_kv_heads=c["num_key_value_heads"], rope=True,
+        rope_base=float(c["rope_theta"]), norm="rms", ffn="swiglu",
+        window=c["sliding_window"])
+
+
+@pytest.fixture
+def chip_policy(monkeypatch):
+    """What the program would decide on the chip: `ops.default_backend`
+    asks `jax.default_backend()`, which is the CPU here. And no compile
+    cache: an entry written for a described chip cannot be read back."""
+    monkeypatch.setattr(
+        ops, "default_backend",
+        lambda op=None: ops._TPU_AUTO_POLICY.get(op, "pallas"))
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def compiled_step(topo, cfg, shape) -> str:
+    """The train step as the cells build it (bfloat16 weights, float32
+    masters under Adam, state replicated), compiled for `shape` chips."""
+    dp, sp = shape
+    mesh = Mesh(np.array(topo.devices[:dp * sp]).reshape(dp, sp),
+                ("dp", "sp"))
+    placed = lambda tree, spec: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, spec)), tree)
+    opt = with_f32_master(optax.adam(3e-4))
+    params = jax.eval_shape(
+        lambda: {k: v.astype(jnp.bfloat16) for k, v in tfm.init_transformer(
+            jax.random.PRNGKey(0), cfg).items()})
+    state = placed(jax.eval_shape(opt.init, params), P())
+    tokens = placed(jax.ShapeDtypeStruct((ROWS[shape], SEQ), jnp.int32),
+                    P("dp", "sp"))
+    step = tfm.make_train_step(cfg, mesh, opt, attn="ring")
+    return step.lower(placed(params, P()), state, tokens,
+                      tokens).compile().as_text()
+
+
+def fusions(text: str):
+    """(output shapes, kind, body) of every fusion instruction."""
+    bodies = {name: body for name, body in re.findall(
+        r"^%([\w.\-]+) \([^\n]*\{\n(.*?)^\}", text, re.M | re.S)}
+    for out, rest in re.findall(r" = (.*?) fusion\((.*)", text):
+        called = re.search(r"calls=%([\w.\-]+)", rest).group(1)
+        yield out, re.search(r"kind=(\w+)", rest).group(1), bodies[called]
+
+
+def instruction_multiset(text: str) -> collections.Counter:
+    """Every instruction of every computation, names and metadata off."""
+    lines = collections.Counter()
+    for line in text.splitlines():
+        if " = " in line:
+            line = re.sub(r", metadata=\{[^}]*\}", "", line.strip())
+            lines[re.sub(r"%[\w.\-]+", "%", line)] += 1
+    return lines
+
+
+def test_one_chip_weight_gradient_matmuls_are_fusions_of_their_own(
+        topo, cfg, chip_policy):
+    """ISSUE 27: with the masters' Adam update fused into its output the
+    SwiGLU weight-gradient matmul ran at 47% of peak. No fusion may hold
+    a convolution AND write the float32 streams of the update."""
+    d, ff = cfg.d_model, cfg.d_ff
+    found = list(fusions(compiled_step(topo, cfg, (1, 1))))
+    held = lambda body: " convolution(" in body  # noqa: E731
+    for wide in (f"f32[{d},{ff}]", f"f32[{ff},{d}]"):
+        assert not [out for out, _, body in found
+                    if wide in out and held(body)], wide
+        # the update is there, elementwise, once a leaf
+        n = sum(wide in out and kind == "kLoop" for out, kind, _ in found)
+        assert n == (2 if wide.startswith(f"f32[{d},") else 1), (wide, n)
+    # and the matmuls write bfloat16 gradients: ff1 and ff3, then ff2
+    alone = [out for out, _, body in found if held(body)]
+    assert sum(out.startswith(f"bf16[{d},{ff}]") for out in alone) == 2
+    assert sum(out.startswith(f"bf16[{ff},{d}]") for out in alone) == 1
+
+
+def test_2x2_program_is_the_one_without_the_barrier(topo, cfg, chip_policy,
+                                                    monkeypatch):
+    """Behind the all-reduce the barrier changes nothing: the 2x2 step's
+    instructions are those of the same step built without it."""
+    with_barrier = compiled_step(topo, cfg, (2, 2))
+    assert "all-reduce" in with_barrier
+    with monkeypatch.context() as m:
+        m.setattr(jax.lax, "optimization_barrier", lambda x: x)
+        without = compiled_step(topo, cfg, (2, 2))
+    assert instruction_multiset(with_barrier) == instruction_multiset(without)

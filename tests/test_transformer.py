@@ -721,3 +721,121 @@ def test_empty_prompt_rejected(cfg):
     params = tfm.init_transformer(jax.random.PRNGKey(0), cfg)
     with pytest.raises(ValueError, match="at least one token"):
         tfm.greedy_decode(params, jnp.zeros((1, 0), jnp.int32), 4, cfg=cfg)
+
+
+# -- the gradients' barrier (make_train_step's replicated path) -------------
+
+BARRIER_CASES = [((1, 1), 1), ((1, 1), 2), ((2, 2), 1), ((2, 2), 2)]
+
+
+def _barrier_setup(shape, dtype=jnp.float32):
+    """A llama-style step on a (dp, sp) CPU mesh as the benchmark builds
+    it: float32 masters under Adam, state laid out on the mesh."""
+    from jax.sharding import Mesh
+
+    from lua_mapreduce_tpu.train.precision import with_f32_master
+    dp, sp = shape
+    cfg = tfm.TransformerConfig.llama_style(
+        vocab=64, d_model=32, n_heads=4, n_kv_heads=2, n_layers=2, d_ff=64,
+        max_seq=128, window=16)
+    mesh = Mesh(np.array(jax.devices("cpu")[:dp * sp]).reshape(dp, sp),
+                ("dp", "sp"))
+    opt = with_f32_master(optax.adam(1e-2))
+    params = tfm.init_transformer(jax.random.PRNGKey(0), cfg)
+    params = tfm.shard_params_moe(
+        {k: v.astype(dtype) for k, v in params.items()}, mesh)
+    state = tfm.init_opt_state(opt, params, mesh)
+    rows = np.random.RandomState(7).randint(0, cfg.vocab, (4, 33))
+    batch = tfm.shard_batch(mesh, rows[:, :-1].astype(np.int32),
+                            rows[:, 1:].astype(np.int32))
+    return cfg, mesh, opt, params, state, batch
+
+
+def _eqns_named(jaxpr, name):
+    return [(i, e) for i, e in enumerate(jaxpr.eqns)
+            if e.primitive.name == name]
+
+
+@pytest.mark.parametrize("shape,accum", BARRIER_CASES)
+def test_every_gradient_leaf_meets_one_barrier(shape, accum):
+    """One `optimization_barrier` a gradient leaf, inside the shard_map,
+    behind the whole of `lm.loss` (behind the scan where microbatches
+    accumulate) and ahead of `lm.opt`; none anywhere else."""
+    cfg, mesh, opt, params, state, batch = _barrier_setup(shape)
+    step = tfm.make_train_step(cfg, mesh, opt, grad_accum=accum)
+    (_, program), = _eqns_named(
+        jax.make_jaxpr(step)(params, state, *batch).jaxpr, "jit")
+    outer = program.params["jaxpr"].jaxpr
+    (at, mapped), = _eqns_named(outer, "shard_map")
+    assert not _eqns_named(outer, "optimization_barrier")
+    opt_at = [i for i, e in enumerate(outer.eqns)
+              if "lm.opt" in str(e.source_info.name_stack)]
+    assert opt_at and at < min(opt_at)
+
+    body = mapped.params["jaxpr"]
+    barriers = _eqns_named(body, "optimization_barrier")
+    assert len(barriers) == len(params)
+    assert all(len(e.invars) == 1 for _, e in barriers)
+    shapes = sorted((e.invars[0].aval.shape, str(e.invars[0].aval.dtype))
+                    for _, e in barriers)
+    assert shapes == sorted((v.shape, str(v.dtype))
+                            for v in params.values())
+    loss_at = [i for i, e in enumerate(body.eqns)
+               if "lm.loss" in str(e.source_info.name_stack)
+               or e.primitive.name == "scan"]
+    assert max(loss_at) < min(i for i, _ in barriers)
+    assert bool(_eqns_named(body, "scan")) == (accum > 1)
+
+
+@pytest.mark.parametrize("shape,accum", BARRIER_CASES)
+def test_the_barrier_changes_no_bit_of_a_step(shape, accum):
+    """Parameters, optimizer state and loss of one step equal those of a
+    step whose gradients never met a barrier: the optimizer applied by
+    hand to `value_and_grad`'s result. bfloat16 weights, as the cells."""
+    from jax import lax, shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from lua_mapreduce_tpu.train.accum import accum_value_and_grad
+    from lua_mapreduce_tpu.utils.jax_compat import stamp_replicated
+    cfg, mesh, opt, params, state, batch = _barrier_setup(
+        shape, jnp.bfloat16)
+    axes, n_sp = ("dp", "sp"), shape[1]
+    attn = tfm._attn_shard_fn("ring", "sp", n_sp, cfg)
+
+    def shard(p, tok, tgt):
+        pos = tfm._shard_pos("ring", "sp", n_sp, tok.shape[1])
+
+        def loss_of(p, tok, tgt):
+            local = tfm.lm_loss_local(p, tok, tgt, cfg, attn, pos)
+            return lax.pmean(lax.pmean(local, "sp"), "dp")
+
+        if accum == 1:
+            loss, grads = jax.value_and_grad(loss_of)(p, tok, tgt)
+        else:
+            loss, grads = accum_value_and_grad(
+                loss_of, p, (tok, tgt), accum,
+                stamp=lambda l, g: (stamp_replicated(l, axes),
+                                    stamp_replicated(g, axes)))
+        return loss, stamp_replicated(grads, axes)
+
+    @jax.jit
+    def by_hand(p, s, tok, tgt):
+        loss, grads = shard_map(
+            shard, mesh=mesh, in_specs=(P(), P(*axes), P(*axes)),
+            out_specs=(P(), P()))(p, tok, tgt)
+        updates, s = opt.update(grads, s, p)
+        return optax.apply_updates(p, updates), s, loss
+
+    assert "optimization_barrier" not in str(
+        jax.make_jaxpr(by_hand)(params, state, *batch))
+    want = by_hand(params, state, *batch)
+    step = tfm.make_train_step(cfg, mesh, opt, grad_accum=accum)
+    got = step(*jax.tree.map(jnp.copy, (params, state)), *batch)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    # and it was a step: finite, and the working weights moved
+    assert np.isfinite(float(got[2]))
+    assert any(not np.array_equal(np.asarray(v), np.asarray(params[k]))
+               for k, v in got[0].items())
