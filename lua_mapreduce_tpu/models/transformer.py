@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +37,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from lua_mapreduce_tpu.ops.attention import flash_attention
 from lua_mapreduce_tpu.ops.decode import decode_attention, quantize_kv
 from lua_mapreduce_tpu.ops.q8 import q8_matmul, quantize_q8
+from lua_mapreduce_tpu.ops import sparse_mla as _sparse
 from lua_mapreduce_tpu.parallel import moe as _moe
 from lua_mapreduce_tpu.parallel import zero1 as _z1
 from lua_mapreduce_tpu.parallel.pipeline import pipeline_apply
@@ -48,6 +49,38 @@ from lua_mapreduce_tpu.utils.jax_compat import spec_axes, stamp_replicated
 from lua_mapreduce_tpu.utils.profiling import annotate, scope
 
 Params = Dict[str, jnp.ndarray]
+
+# queries of a latent-attention forward over a full sequence, all rows of
+# the batch together, that meet the cache at a time: the indexer's
+# (queries, heads, keys) scores exist for one such block
+_QUERY_BLOCK = 64
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttention:
+    """Multi-head latent attention (DeepSeek-V2/V3) with the sparse
+    attention indexer of V3.2: queries and keys go through low-rank
+    latents, the cache holds one ``kv_rank + rope_dim`` row a token for
+    ALL heads, and a query attends the ``index_top_k`` cached rows that
+    a small indexer (``index_heads`` x ``index_dim``, its own cached
+    key a token) scores highest. ``n_heads`` of the enclosing config
+    are the query heads; ``nope_dim + rope_dim`` is a head's score
+    width and ``v_dim`` its value width."""
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    index_heads: int
+    index_dim: int
+    index_top_k: int
+    # YaRN on the rotary frequencies (factor 1 = plain rope) and its
+    # softmax-scale correction (0.1 * mscale_all_dim * ln(factor) + 1)^2
+    rope_factor: float = 1.0
+    rope_original: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,6 +97,8 @@ class TransformerConfig:
     # factor (the modern long-context serving lever; the flash kernels
     # regroup via index maps, ops/attention.py)
     n_kv_heads: int = 0
+    # a head's width; 0 = d_model // n_heads
+    head_dim: int = 0
     # rotary position embeddings: q/k rotated by their GLOBAL position
     # before attention (relative-position encoding, no pos_emb table —
     # the standard long-context scheme; composes with every sequence-
@@ -73,6 +108,12 @@ class TransformerConfig:
     rope_base: float = 10000.0
     # "ln" (pre-LN with bias) or "rms" (RMSNorm, scale only)
     norm: str = "ln"
+    norm_eps: float = 1e-5
+    # the output head is ``tok_emb.T`` (tied) or a matrix of its own
+    tied_head: bool = True
+    # None = grouped-query attention over (k, v) caches; else latent
+    # attention over a latent cache, sparse by its indexer
+    latent: Optional[LatentAttention] = None
     # "gelu" (2-matmul MLP with biases) or "swiglu" (gate/up/down,
     # no biases — the llama-style FFN)
     ffn: str = "gelu"
@@ -92,6 +133,24 @@ class TransformerConfig:
     # experts each token is routed to: 1 = switch, >1 = Mixtral-style
     # top-k with combine weights renormalized over the selected k
     moe_top_k: int = 1
+    # "switch": softmax gates, capacity-bounded, gelu experts (above).
+    # "grouped": sigmoid scores with a selection bias, a group-limited
+    # choice (``moe_groups`` groups, the best ``moe_topk_groups`` stay),
+    # weights normalised over the selected k and scaled by
+    # ``moe_scale``, SwiGLU experts of width ``moe_d_ff`` (0 = d_ff),
+    # ``moe_shared`` shared experts, and NO dropped token (no capacity).
+    # ``moe_held = (first, count)`` is the range of experts this chip
+    # holds (None = all): the router keeps its ``moe_experts`` outputs,
+    # the layer computes its own experts' part of the result.
+    moe_router: str = "switch"
+    moe_groups: int = 1
+    moe_topk_groups: int = 1
+    moe_scale: float = 1.0
+    moe_d_ff: int = 0
+    moe_shared: int = 0
+    moe_held: Optional[Tuple[int, int]] = None
+    # the first ``moe_first_dense`` layers keep the dense FFN
+    moe_first_dense: int = 0
     # rematerialization: recompute each block in the backward pass
     # instead of saving its activations — trades ~1/3 more FLOPs for
     # O(n_layers) less activation HBM, the standard long-context lever
@@ -127,7 +186,7 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int,
     forward under ``cfg.remat``. MoE FFN FLOPs follow the per-token
     routed expert (same as dense for top-1 switch routing)."""
     d, dff = cfg.d_model, cfg.d_ff
-    hd = d // cfg.n_heads
+    hd = head_dim(cfg)
     qkv_proj = 2.0 * d * (cfg.n_heads + 2 * kv_heads(cfg)) * hd
     if cfg.window and causal:
         # sliding window: mean visible keys per token is
@@ -139,7 +198,7 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int,
     else:
         attn = 4.0 * seq_len * d * (0.5 if causal else 1.0)
     ffn = (6.0 if cfg.ffn == "swiglu" else 4.0) * d * dff
-    per_layer = qkv_proj + 2.0 * d * d + attn + ffn
+    per_layer = qkv_proj + 2.0 * d * cfg.n_heads * hd + attn + ffn
     fwd = cfg.n_layers * per_layer + 2.0 * d * cfg.vocab
     return 3.0 * fwd
 
@@ -153,6 +212,15 @@ def kv_heads(cfg: TransformerConfig) -> int:
     return hkv
 
 
+def head_dim(cfg: TransformerConfig) -> int:
+    return cfg.head_dim or cfg.d_model // cfg.n_heads
+
+
+def moe_layer(cfg: TransformerConfig, i: int) -> bool:
+    """Whether layer ``i`` has the expert FFN (else the dense one)."""
+    return bool(cfg.moe_experts) and i >= cfg.moe_first_dense
+
+
 def _check_arch(cfg: TransformerConfig) -> None:
     """Architecture-knob validation shared by init and every factory."""
     if cfg.norm not in ("ln", "rms"):
@@ -160,18 +228,51 @@ def _check_arch(cfg: TransformerConfig) -> None:
     if cfg.ffn not in ("gelu", "swiglu"):
         raise ValueError(f"unknown ffn {cfg.ffn!r} "
                          f"(want 'gelu'|'swiglu')")
-    if cfg.rope and (cfg.d_model // cfg.n_heads) % 2:
-        raise ValueError("rope needs an even head_dim; got "
-                         f"{cfg.d_model // cfg.n_heads}")
-    if cfg.moe_experts and cfg.ffn != "gelu":
-        raise ValueError("MoE blocks use the switch-gelu expert FFN; "
-                         "ffn='swiglu' applies to dense blocks only")
+    if cfg.rope and head_dim(cfg) % 2:
+        raise ValueError(f"rope needs an even head_dim; got {head_dim(cfg)}")
+    if cfg.moe_router not in ("switch", "grouped"):
+        raise ValueError(f"unknown moe_router {cfg.moe_router!r} "
+                         f"(want 'switch'|'grouped')")
+    if cfg.moe_experts and cfg.moe_router == "switch" and cfg.ffn != "gelu":
+        raise ValueError("the switch router's experts are gelu FFNs; "
+                         "SwiGLU experts come with moe_router='grouped'")
+    if cfg.moe_experts and cfg.moe_router == "grouped":
+        if cfg.ffn != "swiglu":
+            raise ValueError("moe_router='grouped' has SwiGLU experts: "
+                             "set ffn='swiglu'")
+        if (cfg.moe_experts % cfg.moe_groups
+                or cfg.moe_topk_groups > cfg.moe_groups
+                or cfg.moe_top_k > cfg.moe_topk_groups
+                * (cfg.moe_experts // cfg.moe_groups)):
+            raise ValueError("moe_groups must divide moe_experts and the "
+                             "kept groups must hold moe_top_k experts")
+        _moe._check_held(cfg.moe_held or (0, cfg.moe_experts),
+                         cfg.moe_experts)
+    if cfg.latent is not None:
+        if not (cfg.rope and cfg.norm == "rms"):
+            raise ValueError("latent attention is written for rope=True "
+                             "and norm='rms'")
+        if cfg.latent.rope_dim % 2 or cfg.window:
+            raise ValueError("latent attention needs an even rope_dim and "
+                             "takes no window")
     if cfg.window < 0:
         raise ValueError(f"window must be >= 0, got {cfg.window}")
 
 
+def _check_sharded(cfg: TransformerConfig) -> None:
+    """What the sharded forward and the train steps cannot run yet."""
+    if cfg.latent is not None or (cfg.moe_experts
+                                  and cfg.moe_router == "grouped"):
+        raise ValueError(
+            "latent attention and the grouped expert layer are served "
+            "(prefill, decode_from, greedy_decode, transformer_apply); "
+            "the sharded forward and the train steps run grouped-query "
+            "attention and the switch MoE")
+
+
 def _check_moe(cfg: TransformerConfig, n_ep: Optional[int] = None) -> None:
-    if cfg.moe_experts and cfg.moe_capacity <= 0:
+    if (cfg.moe_experts and cfg.moe_capacity <= 0
+            and cfg.moe_router == "switch"):
         raise ValueError(
             "moe_experts > 0 requires an explicit moe_capacity (it is "
             "per routing group; see TransformerConfig)")
@@ -187,16 +288,22 @@ def _check_moe(cfg: TransformerConfig, n_ep: Optional[int] = None) -> None:
 
 def init_transformer(key, cfg: TransformerConfig = TransformerConfig(),
                      dtype=jnp.float32) -> Params:
-    """Flat params: tok/pos embeddings, per layer fused qkv + out proj +
-    2-layer MLP + 2 layernorms, final layernorm; the LM head is tied to
-    the token embedding (standard weight tying)."""
+    """Flat params: tok/pos embeddings, per layer the attention's
+    projections (fused qkv + out, or the latent attention's and its
+    indexer's), the FFN (dense, or router and experts) and 2 norms, the
+    final norm; the LM head is tied to the token embedding unless
+    ``cfg.tied_head`` is off (``head_W``)."""
     _check_moe(cfg)
     _check_arch(cfg)
     d, ff = cfg.d_model, cfg.d_ff
-    hd = d // cfg.n_heads
+    hd = head_dim(cfg)
     qkv_cols = (cfg.n_heads + 2 * kv_heads(cfg)) * hd
     params: Params = {}
     keys = iter(jax.random.split(key, 2 + 5 * cfg.n_layers))
+
+    def dense(shape):
+        return jax.random.normal(next(keys), shape, dtype) / np.sqrt(shape[0])
+
     params["tok_emb"] = 0.02 * jax.random.normal(
         next(keys), (cfg.vocab, d), dtype)
     if not cfg.rope:        # rope needs no position table
@@ -204,27 +311,28 @@ def init_transformer(key, cfg: TransformerConfig = TransformerConfig(),
             next(keys), (cfg.max_seq, d), dtype)
     for i in range(cfg.n_layers):
         p = f"L{i}"
-        params[f"{p}_qkv_W"] = jax.random.normal(
-            next(keys), (d, qkv_cols), dtype) / np.sqrt(d)
-        params[f"{p}_out_W"] = jax.random.normal(
-            next(keys), (d, d), dtype) / np.sqrt(d)
-        if cfg.moe_experts:
+        if cfg.latent is not None:
+            params.update(_init_latent(next(keys), cfg, dtype, p))
+        else:
+            params[f"{p}_qkv_W"] = dense((d, qkv_cols))
+            params[f"{p}_out_W"] = dense((cfg.n_heads * hd, d))
+        if moe_layer(cfg, i) and cfg.moe_router == "grouped":
+            params.update(_moe.init_moe_held(
+                next(keys), d, cfg.moe_d_ff or ff, cfg.moe_experts,
+                cfg.moe_held or (0, cfg.moe_experts), cfg.moe_shared,
+                dtype, prefix=f"{p}_moe"))
+        elif moe_layer(cfg, i):
             params.update(_moe.init_moe(
                 next(keys), d, ff, cfg.moe_experts, dtype,
                 prefix=f"{p}_moe"))
         elif cfg.ffn == "swiglu":
-            params[f"{p}_ff1_W"] = jax.random.normal(     # gate
-                next(keys), (d, ff), dtype) / np.sqrt(d)
-            params[f"{p}_ff3_W"] = jax.random.normal(     # up
-                next(keys), (d, ff), dtype) / np.sqrt(d)
-            params[f"{p}_ff2_W"] = jax.random.normal(     # down
-                next(keys), (ff, d), dtype) / np.sqrt(ff)
+            params[f"{p}_ff1_W"] = dense((d, ff))       # gate
+            params[f"{p}_ff3_W"] = dense((d, ff))       # up
+            params[f"{p}_ff2_W"] = dense((ff, d))       # down
         else:
-            params[f"{p}_ff1_W"] = jax.random.normal(
-                next(keys), (d, ff), dtype) / np.sqrt(d)
+            params[f"{p}_ff1_W"] = dense((d, ff))
             params[f"{p}_ff1_b"] = jnp.zeros((ff,), dtype)
-            params[f"{p}_ff2_W"] = jax.random.normal(
-                next(keys), (ff, d), dtype) / np.sqrt(ff)
+            params[f"{p}_ff2_W"] = dense((ff, d))
             params[f"{p}_ff2_b"] = jnp.zeros((d,), dtype)
         for ln in ("ln1", "ln2"):
             params[f"{p}_{ln}_g"] = jnp.ones((d,), dtype)
@@ -233,17 +341,46 @@ def init_transformer(key, cfg: TransformerConfig = TransformerConfig(),
     params["lnf_g"] = jnp.ones((d,), dtype)
     if cfg.norm == "ln":
         params["lnf_b"] = jnp.zeros((d,), dtype)
+    if not cfg.tied_head:
+        params["head_W"] = jax.random.normal(
+            jax.random.fold_in(key, 1), (d, cfg.vocab), dtype) / np.sqrt(d)
     return params
 
 
+def _init_latent(key, cfg: TransformerConfig, dtype, p: str) -> Params:
+    """One layer's latent-attention and indexer weights."""
+    la, d, h = cfg.latent, cfg.d_model, cfg.n_heads
+    shapes = {
+        "qa_W": (d, la.q_rank),
+        "qb_W": (la.q_rank, h * (la.nope_dim + la.rope_dim)),
+        "kva_W": (d, la.kv_rank + la.rope_dim),
+        "kvb_W": (la.kv_rank, h * (la.nope_dim + la.v_dim)),
+        "out_W": (h * la.v_dim, d),
+        "iq_W": (la.q_rank, la.index_heads * la.index_dim),
+        "ik_W": (d, la.index_dim),
+        "iw_W": (d, la.index_heads),
+    }
+    out = {f"{p}_{n}": jax.random.normal(k, shape, dtype) / np.sqrt(shape[0])
+           for (n, shape), k in zip(shapes.items(),
+                                    jax.random.split(key, len(shapes)))}
+    out[f"{p}_qa_g"] = jnp.ones((la.q_rank,), dtype)
+    out[f"{p}_kv_g"] = jnp.ones((la.kv_rank,), dtype)
+    out[f"{p}_ik_g"] = jnp.ones((la.index_dim,), dtype)
+    out[f"{p}_ik_b"] = jnp.zeros((la.index_dim,), dtype)
+    return out
+
+
 def _head(params: Params, x):
-    """The tied LM head ``x @ tok_emb.T`` — through the int8 kernel when
+    """The LM head: ``x @ head_W`` where the dict has one (an untied
+    head), else the tied ``x @ tok_emb.T`` — through the int8 kernel when
     the serving dict carries ``head::q8`` (quantize_lm). At production
     vocab sizes this is THE decode-bandwidth matmul; the embedding
     GATHER keeps the full-precision tok_emb (it reads only B rows per
     step, negligible traffic)."""
     if "head::q8" in params:
         return _mm(params, "head", x)       # one q8 dispatch path only
+    if "head_W" in params:
+        return x @ params["head_W"]
     return x @ params["tok_emb"].T
 
 
@@ -302,23 +439,50 @@ def _layer_norm(x, g, b, eps=1e-5):
     return (x - mu) * lax.rsqrt(var + eps) * g + b
 
 
-def _norm(params: Params, name: str, x, cfg: TransformerConfig,
-          eps=1e-5):
+def _rms_norm(x, g, eps):
+    ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+    return x * lax.rsqrt(ms + eps) * g
+
+
+def _norm(params: Params, name: str, x, cfg: TransformerConfig):
     """The block norm: pre-LN (scale+bias) or RMSNorm (scale only)."""
     g = params[f"{name}_g"]
     if cfg.norm == "rms":
-        ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-        return x * lax.rsqrt(ms + eps) * g
-    return _layer_norm(x, g, params[f"{name}_b"], eps)
+        return _rms_norm(x, g, cfg.norm_eps)
+    return _layer_norm(x, g, params[f"{name}_b"], cfg.norm_eps)
 
 
-def _rope(x, pos, base: float):
+def _yarn_freqs(la: LatentAttention, base: float) -> np.ndarray:
+    """The rope frequencies of ``la.rope_dim`` under YaRN (Peng et al.
+    2023, as DeepSeek-V3 applies it): frequencies whose wavelength fits
+    the original context ``beta_fast`` times or more are kept, those
+    that fit it ``beta_slow`` times or fewer are divided by the factor,
+    with a linear ramp between."""
+    dim = la.rope_dim
+    freqs = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if la.rope_factor == 1.0:
+        return freqs.astype(np.float32)
+
+    def turns_to_dim(turns):
+        return (dim * np.log(la.rope_original / (turns * 2 * np.pi))
+                / (2 * np.log(base)))
+
+    low = max(np.floor(turns_to_dim(la.beta_fast)), 0)
+    high = min(np.ceil(turns_to_dim(la.beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (freqs / la.rope_factor * ramp
+            + freqs * (1 - ramp)).astype(np.float32)
+
+
+def _rope(x, pos, base: float, freqs=None):
     """Rotary embedding: rotate each (i, i+hd/2) pair of head dims by
-    pos·base^(-2i/hd). x (B, L, H*, hd) — broadcasts over ANY head
+    pos·base^(-2i/hd), or by ``freqs`` where given. x (B, L, H*, hd) —
+    broadcasts over ANY head
     count (q and GQA's smaller k alike); pos (L,) global positions.
     Rotation-half convention; angles in f32, result in x.dtype."""
     half = x.shape[-1] // 2
-    freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if freqs is None:
+        freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]  # (L, half)
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
@@ -328,12 +492,15 @@ def _rope(x, pos, base: float):
                             x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
-def _ffn(params: Params, p: str, y, cfg: TransformerConfig,
-         moe_axis: Optional[str]):
-    """The block's FFN: dense, or switch-MoE when cfg.moe_experts > 0
+def _ffn(params: Params, i: int, y, cfg: TransformerConfig,
+         moe_axis: Optional[str], stats_sink: Optional[list] = None):
+    """Layer ``i``'s FFN: dense, or in an expert layer the switch MoE
     (expert-parallel over ``moe_axis`` inside shard_map, single-device
-    reference routing when ``moe_axis`` is None). Returns (out, aux)."""
-    if not cfg.moe_experts:
+    reference routing when ``moe_axis`` is None) or the dropless grouped
+    MoE over the experts this chip holds (its counters appended to
+    ``stats_sink``). Returns (out, aux)."""
+    p = f"L{i}"
+    if not moe_layer(cfg, i):
         if cfg.ffn == "swiglu":
             gate = jax.nn.silu(_mm(params, f"{p}_ff1_W", y))
             up = _mm(params, f"{p}_ff3_W", y)
@@ -343,8 +510,17 @@ def _ffn(params: Params, p: str, y, cfg: TransformerConfig,
         return _mm(params, f"{p}_ff2_W", h) + params[f"{p}_ff2_b"], 0.0
     b, l, d = y.shape
     t = b * l
-    cap = cfg.moe_capacity
     flat = y.reshape(t, d)
+    if cfg.moe_router == "grouped":
+        out, stats = _moe.moe_ffn_held(
+            params, flat, held=cfg.moe_held or (0, cfg.moe_experts),
+            top_k=cfg.moe_top_k, n_groups=cfg.moe_groups,
+            topk_groups=cfg.moe_topk_groups, scale=cfg.moe_scale,
+            prefix=f"{p}_moe")
+        if stats_sink is not None:
+            stats_sink.append(stats)
+        return out.reshape(b, l, d), 0.0
+    cap = cfg.moe_capacity
     if moe_axis is None:
         out, aux = _moe.moe_ffn_reference(params, flat, capacity=cap,
                                           prefix=f"{p}_moe",
@@ -357,38 +533,137 @@ def _ffn(params: Params, p: str, y, cfg: TransformerConfig,
     return out.reshape(b, l, d), aux
 
 
+def _rope_head(x, pos, cfg: TransformerConfig):
+    """Rope (YaRN frequencies) on the first ``rope_dim`` values of
+    (B, L, H*, D) and none on the rest."""
+    la = cfg.latent
+    turned = _rope(x[..., :la.rope_dim], pos, cfg.rope_base,
+                   _yarn_freqs(la, cfg.rope_base))
+    return jnp.concatenate([turned, x[..., la.rope_dim:]], axis=-1)
+
+
+def _latent_rows(params: Params, p: str, y, pos, cfg: TransformerConfig):
+    """What latent attention caches of the normed block input ``y``
+    (B, L, d) at positions ``pos``: the row ``[c_kv | k_rope]`` (the
+    normed latent, the rotated rope key that all heads share) and the
+    indexer's key (LayerNorm, rope on its first ``rope_dim`` values)."""
+    la = cfg.latent
+    with scope("lm.mla"):
+        kv = _mm(params, f"{p}_kva_W", y)
+        c = _rms_norm(kv[..., :la.kv_rank], params[f"{p}_kv_g"],
+                      cfg.norm_eps)
+        k_r = _rope_head(kv[..., None, la.kv_rank:], pos, cfg)[..., 0, :]
+        ckv = jnp.concatenate([c, k_r], axis=-1)
+    with scope("lm.indexer"):
+        ik = _layer_norm(_mm(params, f"{p}_ik_W", y), params[f"{p}_ik_g"],
+                         params[f"{p}_ik_b"], cfg.norm_eps)
+        ik = _rope_head(ik[..., None, :], pos, cfg)[..., 0, :]
+    return ckv, ik.astype(y.dtype)
+
+
+def _latent_attend(params: Params, p: str, y, pos, ckv, ik,
+                   cfg: TransformerConfig):
+    """Latent attention of the queries ``y`` (B, Q, d; normed block
+    input) at positions ``pos`` over caches ``ckv`` (B, S, kv_rank +
+    rope_dim) and ``ik`` (B, S, index_dim) that hold these positions'
+    own rows already. Absorbed form: ``q_nope`` is taken into the
+    latent's basis through ``kvb_W``'s key half, the selected rows are
+    key and value at once, and the sum comes out through its value half.
+    Returns (out (B, Q, d), idx (B, Q, K) selected positions, -1 where
+    the query sees fewer than K)."""
+    la, h = cfg.latent, cfg.n_heads
+    b, q_len, _ = y.shape
+    freqs = _yarn_freqs(la, cfg.rope_base)
+    m = 0.1 * la.mscale_all_dim * np.log(la.rope_factor) + 1.0
+    scale = float((la.nope_dim + la.rope_dim) ** -0.5 * m * m)
+    w_kv = params[f"{p}_kvb_W"].reshape(la.kv_rank, h,
+                                        la.nope_dim + la.v_dim)
+    with scope("lm.mla"):
+        c_q = _rms_norm(_mm(params, f"{p}_qa_W", y), params[f"{p}_qa_g"],
+                        cfg.norm_eps)
+        q = _mm(params, f"{p}_qb_W", c_q).reshape(
+            b, q_len, h, la.nope_dim + la.rope_dim)
+        q_rope = _rope(q[..., la.nope_dim:], pos, cfg.rope_base, freqs)
+        q_lat = jnp.einsum("bqhn,chn->bqhc", q[..., :la.nope_dim],
+                           w_kv[..., :la.nope_dim])
+        q = jnp.concatenate([q_lat, q_rope], axis=-1)
+    with scope("lm.indexer"):
+        q_i = _mm(params, f"{p}_iq_W", c_q).reshape(
+            b, q_len, la.index_heads, la.index_dim)
+        q_i = _rope_head(q_i, pos, cfg)
+        w = _mm(params, f"{p}_iw_W", y) * float(
+            la.index_heads ** -0.5 * la.index_dim ** -0.5)
+        idx, valid = _sparse.select_top_k(
+            _sparse.index_scores(q_i, w, ik, pos), la.index_top_k)
+    with scope("lm.sparse"):
+        o_lat = _sparse.sparse_latent_attention(
+            q, ckv, idx, valid, scale=scale, v_rank=la.kv_rank)
+    with scope("lm.mla"):
+        o = jnp.einsum("bqhc,chv->bqhv", o_lat.astype(y.dtype),
+                       w_kv[..., la.nope_dim:])
+        out = _mm(params, f"{p}_out_W", o.reshape(b, q_len, h * la.v_dim))
+    return out, jnp.where(valid, idx, -1)
+
+
+def _latent_attention(params: Params, p: str, y, pos, rows,
+                      cfg: TransformerConfig):
+    """:func:`_latent_attend` for every position of a full sequence,
+    ``_QUERY_BLOCK`` queries at a time."""
+    b, l, d = y.shape
+    block = min(max(1, _QUERY_BLOCK // b), l)
+    if l == block:
+        return _latent_attend(params, p, y, pos, *rows, cfg)[0]
+    n = -(-l // block)
+    pad = n * block - l                 # padded queries repeat the last
+    yb = jnp.pad(y, ((0, 0), (0, pad), (0, 0)), mode="edge")
+    pb = jnp.pad(pos, (0, pad), mode="edge")
+    out = lax.map(
+        lambda blk: _latent_attend(params, p, blk[0], blk[1], *rows, cfg)[0],
+        (yb.reshape(b, n, block, d).transpose(1, 0, 2, 3),
+         pb.reshape(n, block)))
+    return out.transpose(1, 0, 2, 3).reshape(b, n * block, d)[:, :l]
+
+
 def _block(params: Params, i: int, x, cfg: TransformerConfig, attn_fn,
            pos, moe_axis: Optional[str] = None,
            kv_sink: Optional[list] = None):
     """One pre-norm decoder block; ``attn_fn(q, k, v) -> out`` supplies
     the (possibly sequence-parallel) attention; ``pos`` are the GLOBAL
     positions of the L rows (rope consumes them; ignored otherwise).
-    Returns (x, moe_aux).
+    Latent attention brings its own (sparse over what it caches) and
+    leaves ``attn_fn`` aside. Returns (x, moe_aux).
 
-    ``kv_sink`` (a list) captures this block's (k, v) projections —
-    the prefill path harvests them as the decode KV cache. With rope
-    the captured k is the ROTATED one (what attention consumes and
-    what the decode cache stores)."""
+    ``kv_sink`` (a list) captures what this block caches, the (k, v)
+    projections or the latent rows and indexer keys — the prefill path
+    harvests them as the decode cache. With rope the captured k is the
+    ROTATED one (what attention consumes and what the decode cache
+    stores)."""
     p = f"L{i}"
     b, l, d = x.shape
-    h, hd = cfg.n_heads, d // cfg.n_heads
+    h, hd = cfg.n_heads, head_dim(cfg)
     hkv = kv_heads(cfg)
     with scope("lm.attn"):
         y = _norm(params, f"{p}_ln1", x, cfg)
-        qkv = _mm(params, f"{p}_qkv_W", y)      # (B, L, (H+2Hkv)·hd) MXU
-        q = qkv[..., :h * hd].reshape(b, l, h, hd)
-        k = qkv[..., h * hd:(h + hkv) * hd].reshape(b, l, hkv, hd)
-        v = qkv[..., (h + hkv) * hd:].reshape(b, l, hkv, hd)
-        if cfg.rope:
-            q = _rope(q, pos, cfg.rope_base)
-            k = _rope(k, pos, cfg.rope_base)
-        if kv_sink is not None:
-            kv_sink.append((k, v))
-        a = attn_fn(q, k, v).reshape(b, l, d)
-        x = x + _mm(params, f"{p}_out_W", a)
+        if cfg.latent is not None:
+            rows = _latent_rows(params, p, y, pos, cfg)
+            if kv_sink is not None:
+                kv_sink.append(rows)
+            x = x + _latent_attention(params, p, y, pos, rows, cfg)
+        else:
+            qkv = _mm(params, f"{p}_qkv_W", y)  # (B, L, (H+2Hkv)·hd) MXU
+            q = qkv[..., :h * hd].reshape(b, l, h, hd)
+            k = qkv[..., h * hd:(h + hkv) * hd].reshape(b, l, hkv, hd)
+            v = qkv[..., (h + hkv) * hd:].reshape(b, l, hkv, hd)
+            if cfg.rope:
+                q = _rope(q, pos, cfg.rope_base)
+                k = _rope(k, pos, cfg.rope_base)
+            if kv_sink is not None:
+                kv_sink.append((k, v))
+            a = attn_fn(q, k, v).reshape(b, l, h * hd)
+            x = x + _mm(params, f"{p}_out_W", a)
     with scope("lm.ffn"):
         y = _norm(params, f"{p}_ln2", x, cfg)
-        out, aux = _ffn(params, p, y, cfg, moe_axis)
+        out, aux = _ffn(params, i, y, cfg, moe_axis)
         return x + out, aux
 
 
@@ -431,7 +706,8 @@ def _forward(params: Params, tokens, pos, cfg: TransformerConfig,
 def prefill(params: Params, prompt, *,
             cfg: TransformerConfig = TransformerConfig(),
             total: Optional[int] = None, mesh=None, attn: str = "ring",
-            dp_axis: str = "dp", sp_axis: str = "sp"):
+            dp_axis: str = "dp", sp_axis: str = "sp",
+            chunk: Optional[int] = None):
     """Parallel prompt ingestion: ONE causal forward over the (B, P)
     prompt yields every layer's (k, v) projections — the decode KV
     cache — plus the last position's logits, instead of the O(P)
@@ -444,8 +720,15 @@ def prefill(params: Params, prompt, *,
     Returns ``(caches, last_logits)``: caches is the
     ``L{i}_{k,v} -> (B, total, H_kv, Dh)`` dict :func:`greedy_decode`
     uses (H_kv = ``kv_heads(cfg)``, which is where GQA's group-factor
-    cache shrink shows up; zero-padded to ``total``, default P),
-    last_logits is (B, vocab). Dense and MoE configs single-device; the
+    cache shrink shows up; zero-padded to ``total``, default P), or for
+    latent attention ``L{i}_ckv -> (B, total, kv_rank + rope_dim)`` and
+    the indexer's ``L{i}_ik -> (B, total, index_dim)``;
+    last_logits is (B, vocab). :func:`decode_caches` turns them into
+    what :func:`decode_from` scans over. With ``chunk`` (latent
+    attention, single-device; it must divide P) the prompt goes through
+    the layers that many positions at a time over the growing cache, so
+    a long prompt's activations exist for one chunk only.
+    Dense and MoE configs single-device; the
     sharded path is dense-only (expert sharding composes with
     training's dp, not with replicated-param prefill)."""
     b, p_len = prompt.shape
@@ -458,8 +741,12 @@ def prefill(params: Params, prompt, *,
     _check_seq(total, cfg)
     cfg_fwd = dataclasses.replace(cfg, remat=False)  # capture ≠ remat
     tokens = prompt.astype(jnp.int32)
-    h, hd = cfg.n_heads, cfg.d_model // cfg.n_heads
 
+    if chunk:
+        if cfg.latent is None or mesh is not None or p_len % chunk:
+            raise ValueError("chunk is for single-device latent attention "
+                             f"and must divide the prompt ({p_len})")
+        return _prefill_chunked(params, tokens, cfg_fwd, total, chunk)
     if mesh is None:
         sink: list = []
         # backend="auto": the fused flash kernel on TPU — prefilling a
@@ -471,8 +758,12 @@ def prefill(params: Params, prompt, *,
                                             backend="auto",
                                             window=cfg.window),
             block=functools.partial(_block, kv_sink=sink))
-        kvs = sink
+        kvs, last = sink, logits[:, -1]
     else:
+        if cfg.latent is not None:
+            raise ValueError("sequence-parallel prefill runs grouped-query "
+                             "attention; latent attention prefills "
+                             "single-device")
         if cfg.moe_experts:
             raise ValueError("sequence-parallel prefill supports dense "
                              "configs; MoE prefills single-device")
@@ -511,17 +802,336 @@ def prefill(params: Params, prompt, *,
             logits = logits[:, inv]
             ks, vs = ks[:, :, inv], vs[:, :, inv]
         kvs = [(ks[i], vs[i]) for i in range(cfg.n_layers)]
+        last = logits[:, -1]
 
     caches = {}
-    for i, (k, v) in enumerate(kvs):
-        pad = total - p_len
-        caches[f"L{i}_k"] = jnp.pad(
-            k, ((0, 0), (0, pad), (0, 0), (0, 0))).astype(
+    for i, leaves in enumerate(kvs):
+        for name, leaf in zip(_cache_leaves(cfg), leaves):
+            pad = ((0, 0), (0, total - p_len)) + ((0, 0),) * (leaf.ndim - 2)
+            caches[f"L{i}_{name}"] = jnp.pad(leaf, pad).astype(
                 params["tok_emb"].dtype)
-        caches[f"L{i}_v"] = jnp.pad(
-            v, ((0, 0), (0, pad), (0, 0), (0, 0))).astype(
-                params["tok_emb"].dtype)
-    return caches, logits[:, -1].astype(jnp.float32)
+    return caches, last.astype(jnp.float32)
+
+
+def _prefill_chunked(params: Params, tokens, cfg: TransformerConfig,
+                     total: int, chunk: int):
+    """:func:`prefill` for latent attention, ``chunk`` positions of
+    every row at a time: a chunk writes its rows into the caches and
+    attends what they hold by then (its own positions and all before)."""
+    b, p_len = tokens.shape
+    caches = _empty_latent_caches(cfg, b, total, params["tok_emb"].dtype)
+
+    def one_chunk(caches, toks_start):
+        toks, start = toks_start
+        pos = start + jnp.arange(chunk)
+        with scope("lm.embed"):
+            x = params["tok_emb"][toks]
+        for i in range(cfg.n_layers):
+            p = f"L{i}"
+            with scope("lm.attn"):
+                y = _norm(params, f"{p}_ln1", x, cfg)
+                written = {
+                    f"{p}_{name}": lax.dynamic_update_slice(
+                        caches[f"{p}_{name}"], row, (0, start, 0))
+                    for name, row in zip(_cache_leaves(cfg),
+                                         _latent_rows(params, p, y, pos, cfg))}
+                caches = {**caches, **written}
+                x = x + _latent_attention(params, p, y, pos,
+                                          tuple(written.values()), cfg)
+            with scope("lm.ffn"):
+                y = _norm(params, f"{p}_ln2", x, cfg)
+                x = x + _ffn(params, i, y, cfg, None)[0]
+        return caches, x[:, -1]
+
+    caches, last = lax.scan(
+        one_chunk, caches,
+        (tokens.reshape(b, p_len // chunk, chunk).transpose(1, 0, 2),
+         jnp.arange(0, p_len, chunk)))
+    with scope("lm.head"):
+        logits = _head(params, _norm(params, "lnf", last[-1][:, None], cfg))
+    return caches, logits[:, 0].astype(jnp.float32)
+
+
+def _empty_latent_caches(cfg: TransformerConfig, b: int, total: int,
+                         dtype) -> Params:
+    la = cfg.latent
+    widths = dict(zip(_cache_leaves(cfg),
+                      (la.kv_rank + la.rope_dim, la.index_dim)))
+    return {f"L{i}_{name}": jnp.zeros((b, total, width), dtype)
+            for i in range(cfg.n_layers) for name, width in widths.items()}
+
+
+def _cache_len(caches: Params, cfg: TransformerConfig) -> int:
+    """Slots of caches in the decode layout."""
+    if cfg.latent is not None:
+        return caches["L0_ckv"].shape[1]
+    return caches["L0_k"].shape[2]
+
+
+def _cache_leaves(cfg: TransformerConfig) -> tuple:
+    """Names of what a layer caches, in the order `_block` captures."""
+    return ("k", "v") if cfg.latent is None else ("ckv", "ik")
+
+
+
+
+def _selector(cfg: TransformerConfig, temperature: float,
+              top_k: Optional[int], key):
+    """``select(logits (B, vocab), t) -> (B,) int32``: the argmax when
+    ``temperature`` is 0, else a categorical sample of
+    logits/temperature from ``fold_in(key, t)``, optionally of the
+    ``top_k`` highest logits only."""
+    if temperature < 0:
+        raise ValueError(f"temperature must be >= 0, got {temperature}")
+    if temperature > 0 and key is None:
+        raise ValueError("sampling (temperature > 0) needs a PRNG key")
+    if top_k is not None and top_k < 1:
+        raise ValueError(f"top_k must be >= 1, got {top_k}")
+
+    def select(logits, t):
+        if temperature == 0.0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        lg = logits.astype(jnp.float32) / temperature
+        if top_k is not None and top_k < cfg.vocab:
+            kth = jnp.sort(lg, axis=-1)[:, -top_k][:, None]
+            lg = jnp.where(lg >= kth, lg, _NEG_INF)
+        return jax.random.categorical(
+            jax.random.fold_in(key, t), lg, axis=-1).astype(jnp.int32)
+
+    return select
+
+
+def _rolls(cfg: TransformerConfig, cache_len: int) -> bool:
+    """Whether caches of ``cache_len`` slots are a ROLLING buffer: they
+    are where they are as long as the window (position p lives in slot
+    p mod window). The one rule for every decode; where the window
+    covers the whole decode, p mod window is p and the two layouts are
+    one."""
+    return bool(cfg.window) and cache_len == cfg.window
+
+
+def _cache_shape(cfg: TransformerConfig, total: int) -> tuple:
+    """(roll, cache_len) of a decode over ``total`` positions. A sliding
+    window makes the cache a rolling buffer of ``window`` slots: the
+    scan carry is O(w) instead of O(total), the serving memory the
+    window exists for. Rolling containment IS the window mask — slot
+    contents are exactly the positions (t-w, t], so the only masking
+    left is "slot not yet filled" during the first w steps."""
+    cache_len = min(cfg.window, total) if cfg.window else total
+    return _rolls(cfg, cache_len), cache_len
+
+
+def decode_caches(caches: Params, *, cfg: TransformerConfig, p_len: int,
+                  total: int, kv_q8: bool = False) -> Params:
+    """:func:`prefill`'s caches (of a ``p_len`` prompt, padded to
+    ``total``) as the decode scan carries them. Grouped-query caches go
+    from prefill's public (B, S, H_kv, D) to (B, H_kv, S, D) — one
+    transpose at the boundary, not one per step —, are quantized under
+    ``kv_q8`` (int8 rows, ``L{i}_{k,v}s`` scales) and folded into the
+    rolling layout where the window is shorter than ``total``. Latent
+    caches are scanned as prefill lays them out."""
+    if cfg.latent is not None:
+        if kv_q8:
+            raise ValueError("kv_q8 quantizes grouped-query caches; the "
+                             "latent cache has no int8 form")
+        return caches
+    roll, cache_len = _cache_shape(cfg, total)
+    caches = {n: jnp.transpose(c, (0, 2, 1, 3)) for n, c in caches.items()}
+    if kv_q8:
+        quant = {}
+        for n, c in caches.items():
+            quant[n], quant[n + "s"] = quantize_kv(c)
+        caches = quant
+    if not roll:
+        return caches
+    # fold the prompt cache into the rolling layout: slot j holds the
+    # LAST prompt position ≡ j (mod w). Scale entries (kv_q8) are
+    # (B, H_kv, S) — same slot axis, same fold.
+    if p_len >= cache_len:
+        j = jnp.arange(cache_len)
+        src = p_len - 1 - ((p_len - 1 - j) % cache_len)
+        return {n: c[:, :, src] for n, c in caches.items()}
+    # positions 0..p_len-1 land in slots 0..p_len-1 and the prefill
+    # cache is already zero-padded beyond them — a plain truncation IS
+    # the rolling layout
+    return {n: c[:, :, :cache_len] for n, c in caches.items()}
+
+
+def _decode_step(params: Params, cfg: TransformerConfig, b: int,
+                 cache_len: int, kv_q8: bool, select, feed, stats: bool):
+    """The scan body of every decode: ``step((caches, cur), t)`` feeds
+    ``feed(t, cur)`` at position ``t``, writes the position's cache
+    rows, attends the cache, and selects the next token. Caches are the
+    layout of :func:`decode_caches`. With ``stats`` a step also yields
+    its counters: the positions latent attention selected in each layer
+    (``selected``, -1 where the query saw fewer), and from each grouped
+    expert layer ``held_assignments``, ``experts_touched`` and the
+    experts every token was routed to (``experts``)."""
+    h, hd = cfg.n_heads, head_dim(cfg)
+    hkv = kv_heads(cfg)
+    g = h // hkv            # query heads per kv head (1 = plain MHA)
+    roll = _rolls(cfg, cache_len)
+    # the switch router's per-step routing group = B tokens; clamp
+    # dispatch capacity to it
+    step_cfg = (dataclasses.replace(cfg, moe_capacity=min(cfg.moe_capacity,
+                                                          b))
+                if cfg.moe_experts and cfg.moe_router == "switch" else cfg)
+
+    def step(carry, t):
+        caches, cur = carry
+        with scope("lm.embed"):
+            tok = feed(t, cur)                              # (B,)
+            x = params["tok_emb"][tok]                      # (B, D)
+            if not cfg.rope:
+                x = x + params["pos_emb"][t]
+            x = x[:, None, :]                               # (B, 1, D)
+        selected, moe_stats = [], []
+        for i in range(cfg.n_layers):
+            pfx = f"L{i}"
+            with scope("lm.attn"):
+                if cfg.latent is not None:
+                    caches, x, idx = step_latent(caches, x, t, pfx)
+                    selected.append(idx[:, 0])
+                else:
+                    caches, x = step_attn(caches, x, t, pfx)
+            with scope("lm.ffn"):
+                y = _norm(params, f"{pfx}_ln2", x, cfg)
+                ff, _ = _ffn(params, i, y, step_cfg, None, moe_stats)
+                x = x + ff
+        with scope("lm.head"):
+            x = _norm(params, "lnf", x, cfg)
+            logits = _head(params, x)[:, 0]             # (B, vocab)
+            nxt = select(logits, t)
+        if not stats:
+            return (caches, nxt), nxt
+        out = {k: jnp.stack([m[k] for m in moe_stats])
+               for k in (moe_stats[0] if moe_stats else ())}
+        if selected:
+            out["selected"] = jnp.stack(selected)
+        return (caches, nxt), (nxt, out)
+
+    def step_latent(caches, x, t, pfx):
+        """One layer's latent attention at position ``t``: write this
+        position's latent row and indexer key, then attend."""
+        y = _norm(params, f"{pfx}_ln1", x, cfg)
+        ckv_row, ik_row = _latent_rows(params, pfx, y, t[None], cfg)
+        ckv = lax.dynamic_update_slice(caches[f"{pfx}_ckv"], ckv_row,
+                                       (0, t, 0))
+        ik = lax.dynamic_update_slice(caches[f"{pfx}_ik"], ik_row, (0, t, 0))
+        caches = {**caches, f"{pfx}_ckv": ckv, f"{pfx}_ik": ik}
+        a, idx = _latent_attend(params, pfx, y, t[None], ckv, ik, cfg)
+        return caches, x + a, idx
+
+    def step_attn(caches, x, t, pfx):
+        """One layer's attention at position ``t``: project, write this
+        position's cache row, attend the cache, project out."""
+        y = _norm(params, f"{pfx}_ln1", x, cfg)
+        qkv = _mm(params, f"{pfx}_qkv_W", y)
+        q = qkv[..., :h * hd].reshape(b, 1, h, hd)
+        k = qkv[..., h * hd:(h + hkv) * hd].reshape(b, 1, hkv, hd)
+        v = qkv[..., (h + hkv) * hd:].reshape(b, 1, hkv, hd)
+        if cfg.rope:
+            # rotate THIS position; cache stores rotated keys (the
+            # same convention the prefill capture uses)
+            q = _rope(q, t[None], cfg.rope_base)
+            k = _rope(k, t[None], cfg.rope_base)
+        # (B, 1, Hkv, D) → (B, Hkv, 1, D) cache-layout row
+        k = jnp.transpose(k, (0, 2, 1, 3))
+        v = jnp.transpose(v, (0, 2, 1, 3))
+        # head index = (kv head, group member), kv-head major —
+        # the grouping decode_attention's (B, Hkv, G, D) q expects
+        q = q.reshape(b, hkv, g, hd)
+        slot = t % cache_len if roll else t
+        scales = {}
+        if kv_q8:
+            k, ks_row = quantize_kv(k)
+            v, vs_row = quantize_kv(v)
+            cks = lax.dynamic_update_slice(
+                caches[f"{pfx}_ks"], ks_row, (0, 0, slot))
+            cvs = lax.dynamic_update_slice(
+                caches[f"{pfx}_vs"], vs_row, (0, 0, slot))
+            caches = {**caches, f"{pfx}_ks": cks, f"{pfx}_vs": cvs}
+            scales = {"k_scale": cks, "v_scale": cvs}
+        ck = lax.dynamic_update_slice(
+            caches[f"{pfx}_k"], k, (0, 0, slot, 0))
+        cv = lax.dynamic_update_slice(
+            caches[f"{pfx}_v"], v, (0, 0, slot, 0))
+        caches = {**caches, f"{pfx}_k": ck, f"{pfx}_v": cv}
+        # fused decode attention (ops/decode.py): flash-decode
+        # kernel on TPU, the identical einsum+mask+softmax
+        # composition elsewhere. A cache that does not roll holds the
+        # whole decode inside the window, so slot<=t IS the mask.
+        a = decode_attention(q, ck, cv, t, roll=roll,
+                             backend="auto", **scales)
+        a = a.astype(x.dtype).reshape(b, 1, h * hd)
+        return caches, x + _mm(params, f"{pfx}_out_W", a)
+
+    return step
+
+
+def _scan_from(params: Params, caches: Params, first_ids, start, n_new: int,
+               *, cfg: TransformerConfig, kv_q8: bool, select,
+               stats: bool = False):
+    """``n_new`` positions from ``start`` over caches in the decode
+    layout: ``first_ids`` (B,) is fed at ``start``, each later position
+    the token selected before it. Returns (tokens (n_new, B), caches,
+    per-step counters or None)."""
+    step = _decode_step(params, cfg, first_ids.shape[0],
+                        _cache_len(caches, cfg), kv_q8, select,
+                        lambda t, cur: cur, stats)
+    with scope("lm.decode"):
+        (caches, _), ys = lax.scan(step, (caches, first_ids.astype(jnp.int32)),
+                                   start + jnp.arange(n_new))
+    if stats:
+        tokens, counters = ys
+        return tokens, caches, counters
+    return ys, caches, None
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("n_new", "cfg", "temperature", "top_k", "kv_q8",
+                     "stats"),
+    donate_argnames=("caches",))
+def decode_from(params: Params, caches: Params, first_ids, start,
+                n_new: int, *, cfg: TransformerConfig = TransformerConfig(),
+                temperature: float = 0.0, top_k: Optional[int] = None,
+                key=None, kv_q8: bool = False, stats: bool = False):
+    """The session entry: decode ``n_new`` positions from position
+    ``start`` over caches that exist already, built by
+    ``decode_caches(prefill(..., total=T)[0], p_len=start, total=T)``
+    with ``T >= start + n_new`` or handed back by an earlier call.
+
+    ``first_ids`` (B,) is the token at position ``start`` (after a
+    prefill, the one selected from its last logits; in a later turn,
+    whatever the session feeds next); positions ``start + 1 ...`` are
+    fed the token selected before them. One jitted program, cached on
+    the shapes and every keyword but ``key``; ``start`` is an operand,
+    so turns at other positions of one cache shape share it.
+
+    Returns ``(tokens (B, n_new), caches)``: the token selected after
+    each scanned position (greedy, or sampled as :func:`greedy_decode`
+    samples), and the caches with those positions written. ``caches``
+    is DONATED: go on with the returned one. A turn writes positions
+    ``>= start`` only, and reads none it has not written, so a cache
+    taken back to ``start`` needs no clearing (a ROLLING cache, where
+    the window is shorter than the whole, reuses its slots: to take
+    that one back, keep a copy). With ``stats`` a third
+    value holds each step's counters, stacked over the steps:
+    ``selected`` (n_new, layers, B, K) the cache positions latent
+    attention read, -1 where fewer than K existed, and from the grouped
+    expert layers ``held_assignments`` / ``experts_touched`` (n_new,
+    expert layers) and ``experts`` (n_new, expert layers, B, top_k),
+    the experts each token was routed to."""
+    _check_arch(cfg)
+    if cfg.moe_experts:
+        _check_moe(cfg)
+    tokens, caches, counters = _scan_from(
+        params, caches, first_ids, jnp.asarray(start, jnp.int32), n_new,
+        cfg=cfg, kv_q8=kv_q8,
+        select=_selector(cfg, temperature, top_k, key), stats=stats)
+    tokens = jnp.transpose(tokens, (1, 0))
+    return (tokens, caches, counters) if stats else (tokens, caches)
 
 
 @functools.partial(
@@ -578,7 +1188,8 @@ def greedy_decode(params: Params, prompt, n_new: int, *,
 
     ``use_prefill=True`` ingests the prompt with :func:`prefill` — one
     parallel causal forward instead of P sequential steps — then scans
-    only the ``n_new`` generation positions. With ``mesh`` the prefill
+    only the ``n_new`` generation positions: it is :func:`prefill`, the
+    first token, and :func:`decode_from`'s scan in one program. With ``mesh`` the prefill
     runs sequence-parallel (``attn`` selects ring/zigzag/ulysses over
     ``dp_axis``/``sp_axis``), so prompts at training-scale context
     lengths decode without ever holding full attention on one device.
@@ -591,12 +1202,7 @@ def greedy_decode(params: Params, prompt, n_new: int, *,
     _check_arch(cfg)
     if cfg.moe_experts:
         _check_moe(cfg)
-    if temperature < 0:
-        raise ValueError(f"temperature must be >= 0, got {temperature}")
-    if temperature > 0 and key is None:
-        raise ValueError("sampling (temperature > 0) needs a PRNG key")
-    if top_k is not None and top_k < 1:
-        raise ValueError(f"top_k must be >= 1, got {top_k}")
+    select = _selector(cfg, temperature, top_k, key)
     b, p_len = prompt.shape
     if p_len < 1:
         raise ValueError("prompt must contain at least one token "
@@ -604,149 +1210,7 @@ def greedy_decode(params: Params, prompt, n_new: int, *,
                          "empty continuation)")
     total = p_len + n_new
     _check_seq(total, cfg)
-    h, hd = cfg.n_heads, cfg.d_model // cfg.n_heads
-    hkv = kv_heads(cfg)
-    g = h // hkv            # query heads per kv head (1 = plain MHA)
-    # per-step routing group = B tokens; clamp dispatch capacity to it
-    step_cfg = (dataclasses.replace(cfg, moe_capacity=min(cfg.moe_capacity,
-                                                          b))
-                if cfg.moe_experts else cfg)
-
-    # GQA: the cache holds H_kv heads — the group-factor cache shrink
-    # is the point of n_kv_heads at decode time. A sliding window
-    # additionally makes the cache a ROLLING buffer of `window` slots
-    # (position p lives in slot p mod window): the scan carry is O(w)
-    # instead of O(total), the serving memory the window exists for.
-    # Rolling containment IS the window mask — slot contents are
-    # exactly the positions (t-w, t], so the only masking left is
-    # "slot not yet filled" during the first w steps.
-    roll = bool(cfg.window) and cfg.window < total
-    cache_len = cfg.window if roll else total
-    # caches ride the scan carry as (B, H_kv, S, D) — per-(batch, head)
-    # rows contiguous, the ops/decode.py layout contract (no per-step
-    # transpose for the fused kernel OR the XLA einsums). ``kv_q8``
-    # stores them int8 with per-row f32 scales (ops/decode.quantize_kv)
-    # — half the dominant decode byte stream; serving accuracy, not
-    # training semantics (the scan quantizes each row as it is written)
-    cache_dtype = jnp.int8 if kv_q8 else params["tok_emb"].dtype
-    caches = {
-        f"L{i}_{kv}": jnp.zeros((b, hkv, cache_len, hd), cache_dtype)
-        for i in range(cfg.n_layers) for kv in ("k", "v")
-    }
-    if kv_q8:
-        caches.update({
-            f"L{i}_{kv}s": jnp.zeros((b, hkv, cache_len), jnp.float32)
-            for i in range(cfg.n_layers) for kv in ("k", "v")
-        })
-    # position t reads its input from `prompt` while t < p_len, else the
-    # previously generated token riding the carry
-    pad = jnp.zeros((b, total - p_len), jnp.int32)
-    given = jnp.concatenate([prompt.astype(jnp.int32), pad], axis=1)
-
-    def step(carry, t):
-        caches, cur = carry
-        with scope("lm.embed"):
-            tok = jnp.where(t < p_len, given[:, t], cur)    # (B,)
-            x = params["tok_emb"][tok]                      # (B, D)
-            if not cfg.rope:
-                x = x + params["pos_emb"][t]
-            x = x[:, None, :]                               # (B, 1, D)
-        for i in range(cfg.n_layers):
-            pfx = f"L{i}"
-            with scope("lm.attn"):
-                caches, x = step_attn(caches, x, t, pfx)
-            with scope("lm.ffn"):
-                y = _norm(params, f"{pfx}_ln2", x, cfg)
-                ff, _ = _ffn(params, pfx, y, step_cfg, None)
-                x = x + ff
-        with scope("lm.head"):
-            x = _norm(params, "lnf", x, cfg)
-            logits = _head(params, x)[:, 0]             # (B, vocab)
-            nxt = select(logits, t)
-        return (caches, nxt), nxt
-
-    def step_attn(caches, x, t, pfx):
-        """One layer's attention at position ``t``: project, write this
-        position's cache row, attend the cache, project out."""
-        y = _norm(params, f"{pfx}_ln1", x, cfg)
-        qkv = _mm(params, f"{pfx}_qkv_W", y)
-        q = qkv[..., :h * hd].reshape(b, 1, h, hd)
-        k = qkv[..., h * hd:(h + hkv) * hd].reshape(b, 1, hkv, hd)
-        v = qkv[..., (h + hkv) * hd:].reshape(b, 1, hkv, hd)
-        if cfg.rope:
-            # rotate THIS position; cache stores rotated keys (the
-            # same convention the prefill capture uses)
-            q = _rope(q, t[None], cfg.rope_base)
-            k = _rope(k, t[None], cfg.rope_base)
-        # (B, 1, Hkv, D) → (B, Hkv, 1, D) cache-layout row
-        k = jnp.transpose(k, (0, 2, 1, 3))
-        v = jnp.transpose(v, (0, 2, 1, 3))
-        # head index = (kv head, group member), kv-head major —
-        # the grouping decode_attention's (B, Hkv, G, D) q expects
-        q = q.reshape(b, hkv, g, hd)
-        slot = t % cache_len if roll else t
-        scales = {}
-        if kv_q8:
-            k, ks_row = quantize_kv(k)
-            v, vs_row = quantize_kv(v)
-            cks = lax.dynamic_update_slice(
-                caches[f"{pfx}_ks"], ks_row, (0, 0, slot))
-            cvs = lax.dynamic_update_slice(
-                caches[f"{pfx}_vs"], vs_row, (0, 0, slot))
-            caches = {**caches, f"{pfx}_ks": cks, f"{pfx}_vs": cvs}
-            scales = {"k_scale": cks, "v_scale": cvs}
-        ck = lax.dynamic_update_slice(
-            caches[f"{pfx}_k"], k, (0, 0, slot, 0))
-        cv = lax.dynamic_update_slice(
-            caches[f"{pfx}_v"], v, (0, 0, slot, 0))
-        caches = {**caches, f"{pfx}_k": ck, f"{pfx}_v": cv}
-        # fused decode attention (ops/decode.py): flash-decode
-        # kernel on TPU, the identical einsum+mask+softmax
-        # composition elsewhere. Non-roll windows are total-length
-        # (roll covers window < total), so slot<=t IS the mask.
-        a = decode_attention(q, ck, cv, t, roll=roll,
-                             backend="auto", **scales)
-        a = a.astype(x.dtype).reshape(b, 1, cfg.d_model)
-        return caches, x + _mm(params, f"{pfx}_out_W", a)
-
-    def select(logits, t):
-        """Next token from (B, vocab) logits at position t — shared by
-        the scan step and the prefill fast path (same fold_in(key, t)
-        stream, so both paths sample identical tokens)."""
-        if temperature == 0.0:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        lg = logits.astype(jnp.float32) / temperature
-        if top_k is not None and top_k < cfg.vocab:
-            kth = jnp.sort(lg, axis=-1)[:, -top_k][:, None]
-            lg = jnp.where(lg >= kth, lg, _NEG_INF)
-        return jax.random.categorical(
-            jax.random.fold_in(key, t), lg, axis=-1).astype(jnp.int32)
-
-    def decode_layout(caches):
-        """Prefill's caches as the scan carries them."""
-        # prefill's public contract is (B, S, H_kv, D); the decode scan
-        # holds (B, H_kv, S, D) — one transpose at the boundary, not
-        # one per step
-        caches = {n: jnp.transpose(c, (0, 2, 1, 3))
-                  for n, c in caches.items()}
-        if kv_q8:
-            quant = {}
-            for n, c in caches.items():
-                quant[n], quant[n + "s"] = quantize_kv(c)
-            caches = quant
-        if not roll:
-            return caches
-        # fold the prompt cache into the rolling layout: slot j
-        # holds the LAST prompt position ≡ j (mod w). Scale entries
-        # (kv_q8) are (B, H_kv, S) — same slot axis, same fold.
-        if p_len >= cache_len:
-            j = jnp.arange(cache_len)
-            src = p_len - 1 - ((p_len - 1 - j) % cache_len)
-            return {n: c[:, :, src] for n, c in caches.items()}
-        # positions 0..p_len-1 land in slots 0..p_len-1 and the
-        # prefill cache is already zero-padded beyond them —
-        # a plain truncation IS the rolling layout
-        return {n: c[:, :, :cache_len] for n, c in caches.items()}
+    _, cache_len = _cache_shape(cfg, total)
 
     if use_prefill:
         if n_new == 0:
@@ -755,17 +1219,45 @@ def greedy_decode(params: Params, prompt, n_new: int, *,
             caches, last_logits = prefill(
                 params, prompt, cfg=cfg, total=total, mesh=mesh, attn=attn,
                 dp_axis=dp_axis, sp_axis=sp_axis)
-            caches = decode_layout(caches)
+            caches = decode_caches(caches, cfg=cfg, p_len=p_len,
+                                   total=total, kv_q8=kv_q8)
         with scope("lm.first_token"):
             tok1 = select(last_logits, p_len - 1)
-        # remaining n_new - 1 positions ride the ordinary step scan
-        with scope("lm.decode"):
-            (_, _), emitted = lax.scan(step, (caches, tok1),
-                                       jnp.arange(p_len, total - 1))
+        # remaining n_new - 1 positions ride the session entry's scan
+        emitted, _, _ = _scan_from(params, caches, tok1, p_len, n_new - 1,
+                                   cfg=cfg, kv_q8=kv_q8, select=select)
         gen = jnp.concatenate(
             [tok1[:, None], jnp.transpose(emitted, (1, 0))], axis=1)
         return jnp.concatenate([prompt.astype(jnp.int32), gen], axis=1)
 
+    # from scratch: empty caches in the decode layout — (B, H_kv, S, D),
+    # per-(batch, head) rows contiguous, the ops/decode.py layout
+    # contract; ``kv_q8`` stores them int8 with per-row f32 scales
+    # (ops/decode.quantize_kv), each row quantized as it is written
+    dtype = params["tok_emb"].dtype
+    if cfg.latent is not None:
+        caches = decode_caches(_empty_latent_caches(cfg, b, total, dtype),
+                               cfg=cfg, p_len=0, total=total,
+                               kv_q8=kv_q8)       # refuses kv_q8
+    else:
+        hkv, hd = kv_heads(cfg), head_dim(cfg)
+        caches = {
+            f"L{i}_{kv}": jnp.zeros((b, hkv, cache_len, hd),
+                                    jnp.int8 if kv_q8 else dtype)
+            for i in range(cfg.n_layers) for kv in ("k", "v")
+        }
+        if kv_q8:
+            caches.update({
+                f"L{i}_{kv}s": jnp.zeros((b, hkv, cache_len), jnp.float32)
+                for i in range(cfg.n_layers) for kv in ("k", "v")
+            })
+    # position t reads its input from `prompt` while t < p_len, else the
+    # previously generated token riding the carry
+    pad = jnp.zeros((b, total - p_len), jnp.int32)
+    given = jnp.concatenate([prompt.astype(jnp.int32), pad], axis=1)
+    step = _decode_step(
+        params, cfg, b, cache_len, kv_q8, select,
+        lambda t, cur: jnp.where(t < p_len, given[:, t], cur), False)
     with scope("lm.decode"):
         (_, _), emitted = lax.scan(step, (caches, given[:, 0]),
                                    jnp.arange(total))
@@ -865,6 +1357,7 @@ def make_sharded_apply(cfg: TransformerConfig, mesh, *,
     ``cfg.moe_experts`` > 0 the expert stacks shard over dp and params
     must come from :func:`shard_params_moe`."""
     _check_arch(cfg)
+    _check_sharded(cfg)
     n_sp = mesh.shape[sp_axis]
     attn_shard = _attn_shard_fn(attn, sp_axis, n_sp, cfg)
     moe_axis = dp_axis if cfg.moe_experts else None
@@ -1002,6 +1495,7 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer, *,
         raise ValueError("zero1 shards optimizer state over dp, "
                          "which MoE already spends on experts")
     _check_arch(cfg)
+    _check_sharded(cfg)
     n_sp = mesh.shape[sp_axis]
     attn_shard = _attn_shard_fn(attn, sp_axis, n_sp, cfg)
     moe_axis = None
@@ -1267,6 +1761,7 @@ def make_train_step_3d(cfg: TransformerConfig, mesh, optimizer, *,
     if zigzag_layout and attn != "zigzag":
         raise ValueError("zigzag_layout=True requires attn='zigzag'")
     _check_arch(cfg)
+    _check_sharded(cfg)
     n_sp = mesh.shape[sp_axis]
     n_mp = mesh.shape[mp_axis]
     if cfg.n_heads % n_mp:
@@ -1404,6 +1899,7 @@ def make_train_step_pp(cfg: TransformerConfig, mesh, optimizer, *,
     by ``n_micro``). Reverse-mode AD transposes the GPipe scan into the
     backward pipeline — no hand-written schedule."""
     _check_arch(cfg)
+    _check_sharded(cfg)
     n_pp = mesh.shape[pp_axis]
     if cfg.n_layers % n_pp:
         raise ValueError(f"n_layers={cfg.n_layers} not divisible by "

@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from lua_mapreduce_tpu.utils.profiling import scope
+
 Params = Dict[str, jnp.ndarray]
 
 
@@ -346,3 +348,164 @@ def moe_ffn_shard(params: Params, x, *, capacity: int, ep_axis: str,
     """
     return _moe_ffn(params, x, capacity, prefix, ep_axis, top_k=top_k,
                     impl=impl)
+
+
+# --------------------------------------------------------------------------
+# the dropless layer: sigmoid scores, group-limited top-k, SwiGLU experts,
+# a shared expert, and a chip that is told which experts it holds
+# --------------------------------------------------------------------------
+
+def init_moe_held(key, d_model: int, d_ff: int, n_experts: int,
+                  held: Tuple[int, int], n_shared: int = 0,
+                  dtype=jnp.float32, prefix: str = "moe") -> Params:
+    """The router over all ``n_experts`` (weights and the selection
+    bias, zero at initialisation: training's load balancing sets it) and
+    the SwiGLU weights of the ``held = (first, count)`` experts,
+    stacked; with ``n_shared`` a shared expert of that many times the
+    width."""
+    _check_held(held, n_experts)
+    ks = jax.random.split(key, 8)
+    count = held[1]
+    s1, s2 = d_model ** -0.5, d_ff ** -0.5
+    out = {
+        f"{prefix}_router_W": s1 * jax.random.normal(
+            ks[0], (d_model, n_experts), dtype),
+        f"{prefix}_router_b": jnp.zeros((n_experts,), jnp.float32),
+        f"{prefix}_wg": s1 * jax.random.normal(
+            ks[2], (count, d_model, d_ff), dtype),
+        f"{prefix}_wu": s1 * jax.random.normal(
+            ks[3], (count, d_model, d_ff), dtype),
+        f"{prefix}_wd": s2 * jax.random.normal(
+            ks[4], (count, d_ff, d_model), dtype),
+    }
+    if n_shared:
+        w = n_shared * d_ff
+        out[f"{prefix}_sg"] = s1 * jax.random.normal(ks[5], (d_model, w),
+                                                     dtype)
+        out[f"{prefix}_su"] = s1 * jax.random.normal(ks[6], (d_model, w),
+                                                     dtype)
+        out[f"{prefix}_sd"] = w ** -0.5 * jax.random.normal(
+            ks[7], (w, d_model), dtype)
+    return out
+
+
+def _check_held(held: Tuple[int, int], n_experts: int) -> None:
+    first, count = held
+    if first < 0 or count < 1 or first + count > n_experts:
+        raise ValueError(f"held={held} is no range of the {n_experts} "
+                         f"experts")
+
+
+def route_grouped(x, router_w, bias, *, top_k: int, n_groups: int,
+                  topk_groups: int, scale: float):
+    """Sigmoid scores with a group-limited choice (DeepSeek-V3's
+    `noaux_tc`): the choice is made on ``score + bias``: a group's
+    score is the sum of its two highest, the ``topk_groups`` best groups
+    stay, and the ``top_k`` highest of what they hold are selected. The
+    weights are the scores themselves (no bias), normalised over the
+    selected and scaled. Returns (expert (T, k) int32, weight (T, k)
+    float32). Nothing is dropped: every token keeps all its k."""
+    sc = jax.nn.sigmoid(x.astype(jnp.float32)
+                        @ router_w.astype(jnp.float32))        # (T, E)
+    t, e = sc.shape
+    choice = sc + bias.astype(jnp.float32)
+    if n_groups > 1:
+        grouped = choice.reshape(t, n_groups, e // n_groups)
+        group_score = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)
+        _, keep = lax.top_k(group_score, topk_groups)          # (T, g)
+        kept = jnp.zeros((t, n_groups), bool).at[
+            jnp.arange(t)[:, None], keep].set(True)
+        choice = jnp.where(jnp.repeat(kept, e // n_groups, axis=1),
+                           choice, -jnp.inf)
+    _, expert = lax.top_k(choice, top_k)
+    picked = jnp.take_along_axis(sc, expert, axis=-1)
+    weight = scale * picked / jnp.sum(picked, axis=-1, keepdims=True)
+    return expert.astype(jnp.int32), weight
+
+
+def _swiglu(x, wg, wu, wd):
+    return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd
+
+
+# tokens one expert takes at a time. A tile no larger than this (a decode
+# step's B tokens) goes through a touched expert whole: gathering the few
+# rows that chose it would cost the step more small operations than the
+# rows it saves. A larger tile (a prefill chunk) is gathered, this many of
+# an expert's own tokens at a time, so that an expert works on the 1/32
+# of the tile that chose it and not on all of it.
+_EXPERT_CHUNK = 512
+
+
+def moe_ffn_held(params: Params, x, *, held: Tuple[int, int], top_k: int,
+                 n_groups: int, topk_groups: int, scale: float,
+                 prefix: str = "moe", shared: bool = True):
+    """This chip's part of the expert layer for the flat (T, d) tile
+    ``x``: the router runs over all experts, and of the result
+    ``sum_i g_i E_i(x)`` the terms of the held experts are computed,
+    plus the shared expert (once on every chip; ``shared=False`` leaves
+    it to another share). No capacity and no dropped token; what the
+    absent experts would add is left out, and no exchange runs.
+
+    An expert that no token of the tile chose is never computed, so its
+    weights are not read: at decode, a step streams the experts it
+    touches. Tokens of a large tile go through their expert
+    ``_EXPERT_CHUNK`` at a time, as many chunks as the routing needs.
+
+    Returns (out (T, d), stats): ``held_assignments``, the routed
+    (token, expert) pairs that fell on held experts,
+    ``experts_touched``, the held experts with at least one token, and
+    ``experts`` (T, top_k), the experts each token was routed to."""
+    first, count = held
+    t, d = x.shape
+    with scope("lm.moe.route"):
+        expert, weight = route_grouped(
+            x, params[f"{prefix}_router_W"], params[f"{prefix}_router_b"],
+            top_k=top_k, n_groups=n_groups, topk_groups=topk_groups,
+            scale=scale)
+        local = expert - first                              # (T, k)
+        # (T, k, count): a one-hot of nothing where the expert is absent
+        onehot = jax.nn.one_hot(local, count, dtype=jnp.float32)
+        # (T, count): the weight with which each held expert enters
+        combine = jnp.sum(onehot * weight[..., None], axis=1)
+        routed = jnp.sum(onehot, axis=1) > 0
+        load = jnp.sum(routed, axis=0)                      # (count,)
+    wg, wu, wd = (params[f"{prefix}_{n}"] for n in ("wg", "wu", "wd"))
+    chunk = min(t, _EXPERT_CHUNK)
+
+    def one_expert(e, acc):
+        def whole(acc):
+            y = _swiglu(x, wg[e], wu[e], wd[e]).astype(jnp.float32)
+            return acc + y * combine[:, e:e + 1]
+
+        def chunked(acc):
+            order = jnp.nonzero(routed[:, e], size=t, fill_value=0)[0]
+            order = jnp.pad(order, (0, chunk))
+
+            def body(state):
+                i, acc = state
+                idx = lax.dynamic_slice(order, (i * chunk,), (chunk,))
+                live = (i * chunk + jnp.arange(chunk)) < load[e]
+                y = _swiglu(x[idx], wg[e], wu[e], wd[e])
+                g = jnp.where(live, combine[idx, e], 0.0)
+                return i + 1, acc.at[idx].add(
+                    y.astype(jnp.float32) * g[:, None])
+
+            return lax.while_loop(lambda s: s[0] * chunk < load[e], body,
+                                  (jnp.int32(0), acc))[1]
+
+        return lax.cond(load[e] > 0, whole if t <= chunk else chunked,
+                        lambda acc: acc, acc)
+
+    with scope("lm.moe.experts"):
+        acc = jnp.zeros((t, d), jnp.float32)
+        for e in range(count):
+            acc = one_expert(e, acc)
+    if shared and f"{prefix}_sg" in params:
+        with scope("lm.moe.shared"):
+            acc = acc + _swiglu(x, params[f"{prefix}_sg"],
+                                params[f"{prefix}_su"],
+                                params[f"{prefix}_sd"]).astype(jnp.float32)
+    stats = {"held_assignments": jnp.sum(load).astype(jnp.int32),
+             "experts_touched": jnp.sum(load > 0).astype(jnp.int32),
+             "experts": expert}
+    return acc.astype(x.dtype), stats

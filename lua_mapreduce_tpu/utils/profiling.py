@@ -28,7 +28,12 @@ import os
 # reads a trace (perfbench/scope_reader.py; PERF.md section 3).
 LM_SCOPES = ("lm.loss", "lm.embed", "lm.attn", "lm.ffn", "lm.head",
              "lm.opt", "lm.ring", "lm.prefill", "lm.first_token",
-             "lm.decode")
+             "lm.decode",
+             # inside lm.attn, latent attention: its projections, the
+             # indexer's scores and top-k, the gather and attention
+             "lm.mla", "lm.indexer", "lm.sparse",
+             # inside lm.ffn, the grouped expert layer
+             "lm.moe.route", "lm.moe.experts", "lm.moe.shared")
 LM_HOST_SPANS = ("lm.shard_batch",)
 # ``name=`` of the pallas_calls (ops/attention.py, ops/decode.py,
 # ops/q8.py): the custom call's HLO result is ``%<name>.<n>`` whatever
@@ -36,7 +41,7 @@ LM_HOST_SPANS = ("lm.shard_batch",)
 LM_KERNELS = ("flash_pallas", "flash_bwd_pallas_dq", "flash_bwd_pallas_dkv",
               "_decode_pallas", "q8_matmul_pallas")
 # the jitted functions, so the trace's programs are ``jit_<name>``
-LM_PROGRAMS = ("lm_train_step", "greedy_decode")
+LM_PROGRAMS = ("lm_train_step", "greedy_decode", "decode_from")
 
 
 @contextlib.contextmanager

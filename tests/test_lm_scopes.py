@@ -25,6 +25,20 @@ TRAIN_SCOPES = ("lm.loss", "lm.embed", "lm.attn", "lm.ffn", "lm.head",
                 "lm.opt")
 DECODE_SCOPES = ("lm.prefill", "lm.first_token", "lm.decode", "lm.embed",
                  "lm.attn", "lm.ffn", "lm.head")
+# latent attention and the grouped expert layer: inside lm.attn / lm.ffn
+LATENT = tfm.LatentAttention(
+    q_rank=24, kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8, index_heads=2,
+    index_dim=8, index_top_k=6, rope_factor=40.0, rope_original=16,
+    mscale_all_dim=1.0)
+LATENT_CFG = tfm.TransformerConfig.llama_style(
+    vocab=64, d_model=32, n_heads=4, n_layers=2, d_ff=48, max_seq=64,
+    norm_eps=1e-6, tied_head=False, latent=LATENT, moe_experts=16,
+    moe_router="grouped", moe_groups=4, moe_topk_groups=2, moe_top_k=3,
+    moe_scale=2.5, moe_d_ff=16, moe_shared=1, moe_held=(4, 8),
+    moe_first_dense=1)
+SESSION_SCOPES = {"lm.mla": "lm.attn", "lm.indexer": "lm.attn",
+                  "lm.sparse": "lm.attn", "lm.moe.route": "lm.ffn",
+                  "lm.moe.experts": "lm.ffn", "lm.moe.shared": "lm.ffn"}
 
 
 def train_setup(shape, make=tfm.make_train_step, **kw):
@@ -60,6 +74,18 @@ def lowered_decode():
     prompt = jnp.zeros((2, 8), jnp.int32)
     return tfm.greedy_decode.lower(params, prompt, 4, cfg=CFG,
                                    use_prefill=True)
+
+
+@pytest.fixture(scope="module")
+def lowered_session():
+    """The session entry over the caches of a prefill, for the model
+    with latent attention and the grouped expert layer."""
+    params = tfm.init_transformer(jax.random.PRNGKey(0), LATENT_CFG)
+    caches, last = tfm.prefill(params, jnp.zeros((2, 8), jnp.int32),
+                               cfg=LATENT_CFG, total=12)
+    caches = tfm.decode_caches(caches, cfg=LATENT_CFG, p_len=8, total=12)
+    return tfm.decode_from.lower(params, caches, jnp.zeros((2,), jnp.int32),
+                                 8, 4, cfg=LATENT_CFG, stats=True)
 
 
 @pytest.mark.parametrize("shape", [(1, 1), (2, 2)])
@@ -151,12 +177,56 @@ def test_decode_blocks_lie_under_their_phase(lowered_decode):
                              for p in first)
 
 
-def test_every_scope_of_the_contract_is_used(lowered_train, lowered_decode):
-    found = scope_paths(lowered_train[2, 2]) | scope_paths(lowered_decode)
+@pytest.mark.parametrize("name", sorted(SESSION_SCOPES))
+def test_the_session_entry_nests_the_layers_scopes(lowered_session, name):
+    """`lm.attn/lm.indexer`, `lm.ffn/lm.moe.experts`, ...: inside the
+    block's scope, so that a reader of blocks still holds them, inside
+    the scan of `lm.decode`."""
+    assert "decode_from" in profiling.LM_PROGRAMS
+    assert "module @jit_decode_from" in lowered_session.as_text()
+    paths = set(re.findall(r'op_name="([^"]*)"',
+                           lowered_session.compile().as_text()))
+    under = [p for p in paths if name in p.split("/")]
+    assert under, name
+    block = SESSION_SCOPES[name]
+    assert any(p.startswith("jit(decode_from)/lm.decode/while/body/")
+               for p in under)
+    for p in under:         # a reducer's own computation has a relative path
+        parts = p.split("/")
+        assert block in parts[:parts.index(name)], p
+        assert not p.startswith("jit(") or parts[1:4] == [
+            "lm.decode", "while", "body"], p
+
+
+def test_the_session_entry_runs_no_prefill(lowered_session):
+    paths = scope_paths(lowered_session)
+    assert not any("lm.prefill" in p or "lm.first_token" in p for p in paths)
+    for block in ("lm.embed", "lm.attn", "lm.ffn", "lm.head"):
+        assert any(block in p.split("/") for p in paths), block
+
+
+def test_every_scope_of_the_contract_is_used(lowered_train, lowered_decode,
+                                             lowered_session):
+    found = (scope_paths(lowered_train[2, 2]) | scope_paths(lowered_decode)
+             | scope_paths(lowered_session))
     for name in profiling.LM_SCOPES:
         assert any(name in p for p in found), name
-    assert set(TRAIN_SCOPES + DECODE_SCOPES + ("lm.ring",)) == \
-        set(profiling.LM_SCOPES)
+    assert set(TRAIN_SCOPES + DECODE_SCOPES + ("lm.ring",)
+               + tuple(SESSION_SCOPES)) == set(profiling.LM_SCOPES)
+
+
+def test_the_new_layers_add_no_kernel():
+    """Latent attention, the indexer and the expert layer are XLA's: the
+    session entry of that model holds no pallas_call, so the pinned
+    kernel names stay the five."""
+    params = tfm.init_transformer(jax.random.PRNGKey(0), LATENT_CFG)
+    caches, _ = tfm.prefill(params, jnp.zeros((2, 8), jnp.int32),
+                            cfg=LATENT_CFG, total=12)
+    jaxpr = jax.make_jaxpr(lambda p, c: tfm.decode_from.__wrapped__(
+        p, c, jnp.zeros((2,), jnp.int32), 8, 4, cfg=LATENT_CFG))(
+            params, caches)
+    assert pallas_calls(jaxpr.jaxpr) == []
+    assert len(profiling.LM_KERNELS) == 5
 
 
 def pallas_calls(jaxpr, out=None) -> list:

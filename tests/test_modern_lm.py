@@ -94,7 +94,7 @@ def test_swiglu_and_rms_formulas(cfg):
         * np.asarray(params["L0_ln1_g"])
     np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5,
                                atol=1e-5)
-    out, aux = tfm._ffn(params, "L0", x, cfg, None)
+    out, aux = tfm._ffn(params, 0, x, cfg, None)
     w1, w3, w2 = (np.asarray(params[f"L0_ff{i}_W"]) for i in (1, 3, 2))
     xx = np.asarray(x)
     g = xx @ w1

@@ -142,3 +142,72 @@ def test_2x2_program_is_the_one_without_the_barrier(topo, cfg, chip_policy,
         m.setattr(jax.lax, "optimization_barrier", lambda x: x)
         without = compiled_step(topo, cfg, (2, 2))
     assert instruction_multiset(with_barrier) == instruction_multiset(without)
+
+
+# --------------------------------------------------------------------------
+# the session entry (PR 28), at the serving cells' real widths, one layer
+# --------------------------------------------------------------------------
+
+def serve_config(name: str) -> dict:
+    path = os.path.join(os.path.dirname(CONFIG), name + ".json")
+    with open(path) as f:
+        return json.load(f)
+
+
+def compiled_turn(topo, program_cfg, batch, context, n_new, stats=False):
+    """`decode_from` over the caches of a prefill, compiled for one chip."""
+    from jax.sharding import SingleDeviceSharding
+    one = SingleDeviceSharding(topo.devices[0])
+    placed = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one), tree)
+    params = jax.eval_shape(
+        lambda: {k: v.astype(jnp.bfloat16) for k, v in tfm.init_transformer(
+            jax.random.PRNGKey(0), program_cfg).items()})
+    total = context + n_new
+    caches = jax.eval_shape(
+        lambda p, ids: tfm.decode_caches(
+            tfm.prefill(p, ids, cfg=program_cfg, total=total)[0],
+            cfg=program_cfg, p_len=context, total=total),
+        params, jax.ShapeDtypeStruct((batch, context), jnp.int32))
+    return tfm.decode_from.lower(
+        placed(params), placed(caches),
+        placed(jax.ShapeDtypeStruct((batch,), jnp.int32)),
+        placed(jax.ShapeDtypeStruct((), jnp.int32)), n_new, cfg=program_cfg,
+        stats=stats).compile()
+
+
+def test_the_dense_session_entry_keeps_kernel_and_caches_in_place(
+        topo, chip_policy):
+    from perfbench.drivers.train import program_config
+    import dataclasses
+    cfg = dataclasses.replace(
+        program_config(serve_config("mistral-7b-v0.1.serve")), n_layers=1)
+    compiled = compiled_turn(topo, cfg, 8, 3968, 128)
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_decode_from")
+    assert "_decode_pallas" in text
+    # the donated caches are written in place: 2 x (8, 8, 4096, 128) bf16
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 2 * 8 * 8 * 4096 * 128 * 2
+    assert memory.temp_size_in_bytes < 64 * 2 ** 20
+
+
+def test_the_latent_session_entry_reads_an_expert_only_where_touched(
+        topo, chip_policy):
+    """One expert layer of DeepSeek-V3.2-Exp's share at its real widths:
+    every held expert sits behind a conditional (no token, no read of
+    its weights), nothing sorts 32k scores, and the latent caches stay
+    in place."""
+    from perfbench.model_dsv32 import program_config as dsv32_program_config
+    import dataclasses
+    cfg = dataclasses.replace(
+        dsv32_program_config(serve_config("deepseek-v3.2-exp.serve-ep16")),
+        n_layers=1, moe_first_dense=0)
+    compiled = compiled_turn(topo, cfg, 8, 32768, 64)
+    text = compiled.as_text()
+    assert len(re.findall(r" conditional\(", text)) == 16
+    assert not re.findall(r"sort\([^)]*\[8,1,32832\]", text)
+    assert not re.findall(r"= [^=]*\[8,1,32832\][^=]* sort\(", text)
+    memory = compiled.memory_analysis()
+    # (the chip's tiling pads the rows a little)
+    assert memory.alias_size_in_bytes >= 8 * 32832 * (576 + 128) * 2
