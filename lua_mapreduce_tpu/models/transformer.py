@@ -45,7 +45,6 @@ from lua_mapreduce_tpu.parallel.ring_attention import (
     _NEG_INF, _ring_shard, _ring_shard_zigzag, _ulysses_shard,
     _zigzag_check, _zigzag_perm, attention_reference)
 from lua_mapreduce_tpu.train.accum import accum_value_and_grad
-from lua_mapreduce_tpu.utils.jax_compat import spec_axes, stamp_replicated
 from lua_mapreduce_tpu.utils.profiling import annotate, scope
 
 Params = Dict[str, jnp.ndarray]
@@ -1454,9 +1453,12 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer, *,
     """Jitted SPMD LM train step: ``step(params, opt_state, tokens,
     targets) -> (params, opt_state, loss)`` with tokens/targets sharded
     P(dp, sp). The gradient all-reduce over dp AND sp is the transpose
-    of the loss's pmean, no call of this function's; XLA runs it in line
-    behind the backward pass, hidden by nothing (all of its 75.4 ms a
-    step exposed on the 2x2; ledger, PR 26). Every gradient leaf is
+    of the loss's pmean, no call of this function's, and the one time a
+    gradient crosses the wire: it types each leaf as unvarying over the
+    axes it summed over, which is what shard_map's vma check (left ON)
+    asks of `out_specs`. XLA runs it in line behind the leaf's
+    weight-gradient matmul, hidden by nothing (21.8 ms a step on the
+    2x2, all exposed; my chip run, PR 29). Every gradient leaf is
     written out in its own type before the optimizer reads it.
 
     ``grad_accum`` > 1 folds that many microbatches (split along each
@@ -1525,14 +1527,8 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer, *,
             loss, grads = jax.value_and_grad(global_loss)(
                 params, tokens, targets)
         else:
-            # MoE composes with accum is rejected above, so every leaf
-            # here is replicated over both data axes and the uniform
-            # scan-carry stamp is an identity
             loss, grads = accum_value_and_grad(
-                global_loss, params, (tokens, targets), grad_accum,
-                stamp=lambda l, g: (
-                    stamp_replicated(l, (dp_axis, sp_axis)),
-                    stamp_replicated(g, (dp_axis, sp_axis))))
+                global_loss, params, (tokens, targets), grad_accum)
         # each leaf is written out in its own type before anything
         # reads it. Left alone on one device, XLA fuses optimizer.update
         # into the weight-gradient matmuls' output and they run at half
@@ -1540,24 +1536,13 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer, *,
         # f32[4096,14336], ...)` 0.1982 s and `(bf16[14336,4096],
         # f32[14336,4096], ...)` 0.0821 s over 3 steps, 16.5 and 13.7 ms
         # an instance for a 7.3 ms matmul (ledger, PR 26; ISSUE 27). On
-        # a mesh the all-reduce already stands between the two and the
-        # compiled program is the same with and without.
-        grads = {k: lax.optimization_barrier(g) for k, g in grads.items()}
-        # per-leaf replication stamp (utils/jax_compat.py): each grad
-        # is replicated over the data axes its out_spec omits (the
-        # transpose machinery psums replicated-param cotangents; MoE
-        # expert grads keep their dp-local slice and stamp over sp
-        # only) — the pmean identity makes that statically inferable
-        # so the rep/vma check stays ON
-
-        def _stamp(k, g):
-            have = spec_axes(_spec_for(k, suffix)) if cfg.moe_experts \
-                else set()
-            return stamp_replicated(
-                g, tuple(a for a in (dp_axis, sp_axis)
-                         if a not in have))
-
-        return loss, {k: _stamp(k, g) for k, g in grads.items()}
+        # a mesh the all-reduce already stands between the two: there the
+        # barrier moves a few small values to another memory space, costs
+        # nothing (PERF.md section 6, PR 29) and changes no instruction.
+        # (An expert leaf was summed over sp alone and stays varying
+        # over dp, as its spec says.)
+        return loss, {k: lax.optimization_barrier(g)
+                      for k, g in grads.items()}
 
     def shard_step_zero1(params, opt_state, tokens, targets):
         """The ZeRO-1 body: loss/grad per rank, dp-mean via

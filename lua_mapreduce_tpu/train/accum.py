@@ -18,22 +18,12 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def accum_value_and_grad(global_loss, params, arrays, accum: int,
-                         stamp=None):
+def accum_value_and_grad(global_loss, params, arrays, accum: int):
     """Mean ``value_and_grad(global_loss)(params, *microbatch)`` over
     ``accum`` equal microbatches of ``arrays`` (split on the leading
     axis). ``global_loss(params, *arrays) -> scalar`` must be a MEAN
     over examples, so equal-size microbatch grads average exactly to
     the whole-tile grad.
-
-    ``stamp``, when given, is a ``(loss, grads) -> (loss, grads)``
-    replication stamp (utils/jax_compat.stamp_replicated at the call
-    site) applied to the scan-carry init AND each microbatch's
-    outputs: under shard_map's rep checker the carry input and output
-    replication types must match exactly, and a fresh f32 constant /
-    an un-stamped value_and_grad result carry weaker types than the
-    pmean'd loss — the stamp is a numerical identity that unifies
-    them with the check left ON.
     """
     rows = arrays[0].shape[0]
     if rows % accum:
@@ -45,21 +35,16 @@ def accum_value_and_grad(global_loss, params, arrays, accum: int,
     def body(carry, mb):
         loss_a, g_a = carry
         l, g = jax.value_and_grad(global_loss)(params, *mb)
-        if stamp is not None:
-            l, g = stamp(l, g)
         g32 = jax.tree.map(lambda acc, x: acc + x.astype(jnp.float32),
                            g_a, g)
         return (loss_a + l.astype(jnp.float32), g32), None
 
-    # zeros_like (not zeros): inside shard_map the leaves carry
-    # varying-axis types that a fresh constant would not, and the scan
-    # carry must type-match the per-microbatch grads
+    # zeros_like (not zeros): inside shard_map a leaf that is sharded
+    # over a mesh axis carries that axis in its type, as its gradient
+    # does, and the scan's carry must type-match what it accumulates
     zeros = jax.tree.map(
         lambda p: jnp.zeros_like(p, dtype=jnp.float32), params)
     init = (jnp.float32(0.0), zeros)
-    if stamp is not None:
-        l0, g0 = stamp(*init)
-        init = (l0.astype(jnp.float32), g0)
     (loss_s, g_s), _ = lax.scan(body, init, micro)
     mean = jax.tree.map(
         lambda g, p: (g / accum).astype(p.dtype), g_s, params)

@@ -33,7 +33,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from lua_mapreduce_tpu.parallel import zero1 as _z1
 from lua_mapreduce_tpu.train import checkpoint as ckpt
 from lua_mapreduce_tpu.train.accum import accum_value_and_grad
-from lua_mapreduce_tpu.utils.jax_compat import stamp_replicated
 
 
 @dataclasses.dataclass
@@ -111,15 +110,16 @@ class DataParallelTrainer:
             return self._build_step_zero1()
         axis, loss_fn, optimizer = self.axis, self.loss_fn, self.optimizer
         accum = self.config.grad_accum
-        mesh_axes = tuple(self.mesh.axis_names)
 
         def step(params, opt_state, x, y):
             def shard_step(params, x, y):
                 # differentiate the *global* (pmean'd) loss: AD inserts the
-                # gradient all-reduce itself — the reference's reducefn sum
-                # (common.lua:112-137) fused into the backward pass. (An
-                # explicit post-grad pmean would double-count under
-                # shard_map's auto-psum of replicated-input cotangents.)
+                # gradient all-reduce itself, the reference's reducefn sum
+                # (common.lua:112-137), and types its result as unvarying
+                # over the axis, so out_specs=P() passes the vma check as
+                # it is. (An explicit post-grad pmean would double-count
+                # under shard_map's auto-psum of replicated-input
+                # cotangents.)
                 def global_loss(p, xm, ym):
                     return lax.pmean(loss_fn(p, xm, ym), axis)
 
@@ -129,23 +129,10 @@ class DataParallelTrainer:
                 else:
                     # microbatch fold: one scan keeps a single
                     # microbatch's activations live at a time (shared
-                    # implementation, train/accum.py); params here are
-                    # replicated over every mesh axis, so the all-axes
-                    # stamp unifying the scan-carry replication types
-                    # is an identity on loss and grads alike
+                    # implementation, train/accum.py)
                     loss, grads = accum_value_and_grad(
-                        global_loss, params, (x, y), accum,
-                        stamp=lambda l, g: (
-                            stamp_replicated(l, mesh_axes),
-                            stamp_replicated(g, mesh_axes)))
-                # the grads ARE dp-replicated (the transpose machinery
-                # psums replicated-param cotangents), but newer JAX's
-                # static checker can't infer it through value_and_grad
-                # — the pmean stamp is a numerical identity that makes
-                # out_specs=P() checkable with the check left ON
-                # (check_vma=False would also disable the auto-psum on
-                # older JAX: silently un-summed grads)
-                return loss, stamp_replicated(grads, (axis,))
+                        global_loss, params, (x, y), accum)
+                return loss, grads
 
             loss, grads = shard_map(
                 shard_step, mesh=self.mesh,

@@ -11,7 +11,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from lua_mapreduce_tpu.parallel import moe
 from lua_mapreduce_tpu.parallel.mesh import make_mesh
-from lua_mapreduce_tpu.utils.jax_compat import spec_axes, stamp_replicated
 
 D, FF, E, CAP = 16, 32, 8, 4
 
@@ -87,44 +86,88 @@ def test_shard_matches_per_tile_reference(mesh, params):
     assert np.isfinite(float(aux))
 
 
-def test_moe_trains_and_uses_multiple_experts(mesh):
-    """A small ep-sharded regression task must reduce loss AND keep the
-    router spread across experts (aux loss regularizer working)."""
-    n_ep = 8
-    params = moe.init_moe(jax.random.PRNGKey(1), D, FF, E)
-    rng = np.random.RandomState(3)
-    x = jnp.asarray(rng.randn(128, D), jnp.float32)
-    y = jnp.asarray(np.sin(2 * np.asarray(x)), jnp.float32)
+def _expert_specs(params):
+    return {k: (P("ep") if k.startswith("moe_w") or
+                k.startswith("moe_b") else P())
+            for k in params}
 
-    specs = {k: (P("ep") if k.startswith("moe_w") or
-                 k.startswith("moe_b") else P())
-             for k in params}
 
+def _grad_fn(mesh, specs, capacity, stamped=False):
+    """Loss and gradients of a small ep-sharded regression. Replicated
+    leaves' gradients (the router's) are psum'd across ep by the
+    transpose machinery and typed as unvarying there; the experts' stay
+    varying over ep, as `specs` says: the vma check passes as it is.
+    `stamped` is the OLD way, kept in this file alone: until PR 29 every
+    replicated leaf's gradient went through one more `pmean` over ep, an
+    identity, to tell the checker so."""
     def body(params, x, y):
-        out, aux = moe.moe_ffn_shard(params, x, capacity=32,
+        out, aux = moe.moe_ffn_shard(params, x, capacity=capacity,
                                      ep_axis="ep")
         mse = jnp.mean((out - y) ** 2)
         return jax.lax.pmean(mse, "ep") + 0.01 * aux
 
     def vag(p, x, y):
         l, g = jax.value_and_grad(lambda p: body(p, x, y))(p)
-        # replicated-leaf grads (router etc.) ARE psum'd across ep by
-        # the transpose machinery; the pmean stamp makes that
-        # statically checkable (utils/jax_compat.py)
-        return l, {k: stamp_replicated(
-            v, tuple(a for a in ("ep",) if a not in spec_axes(specs[k])))
-            for k, v in g.items()}
+        if stamped:
+            g = {k: v if "ep" in tuple(specs[k])
+                 else jax.lax.pmean(v, "ep") for k, v in g.items()}
+        return l, g
 
-    grad_fn = jax.jit(shard_map(
+    return jax.jit(shard_map(
         vag, mesh=mesh, in_specs=(specs, P("ep"), P("ep")),
         out_specs=(P(), specs)))
 
-    opt = optax.adam(1e-2)
+
+def _regression(mesh, specs, params):
+    rng = np.random.RandomState(3)
+    x = jnp.asarray(rng.randn(128, D), jnp.float32)
+    y = jnp.asarray(np.sin(2 * np.asarray(x)), jnp.float32)
     sharded = {k: jax.device_put(v, NamedSharding(mesh, specs[k]))
                for k, v in params.items()}
+    return (x, sharded, jax.device_put(x, NamedSharding(mesh, P("ep"))),
+            jax.device_put(y, NamedSharding(mesh, P("ep"))))
+
+
+@pytest.mark.parametrize("capacity", [4, 32])
+def test_unstamped_gradients_are_the_stamped_ones(mesh, capacity):
+    """With experts over the axis the batch is split on, the loss and the
+    experts' gradients equal, bit for bit, those of the old stamped
+    function (tokens dropped at capacity 4, none at 32). The router's,
+    the one stamped leaf, agrees to the float32 ulps that the stamp
+    itself cost: its mean over eight devices is a running sum of eight
+    equal values (3x, 5x, 6x and 7x need not be float32 numbers) and a
+    division by 8, and so not quite the identity it was held to be."""
+    params = moe.init_moe(jax.random.PRNGKey(1), D, FF, E)
+    specs = _expert_specs(params)
+    _, sharded, xd, yd = _regression(mesh, specs, params)
+    want = _grad_fn(mesh, specs, capacity, stamped=True)(sharded, xd, yd)
+    got = _grad_fn(mesh, specs, capacity)(sharded, xd, yd)
+    assert float(got[0]) == float(want[0])
+    assert sorted(got[1]) == sorted(want[1])
+    for k, w in want[1].items():
+        if "ep" in tuple(specs[k]):
+            np.testing.assert_array_equal(np.asarray(got[1][k]),
+                                          np.asarray(w))
+        else:
+            np.testing.assert_array_max_ulp(np.asarray(got[1][k]),
+                                            np.asarray(w), maxulp=4)
+    assert all(np.asarray(g).any() for g in want[1].values())
+    # sharded leaf by leaf as `specs` says: an expert's gradient stays
+    # on its device, the router's is whole on every one
+    for k, g in got[1].items():
+        assert g.sharding.spec == specs[k], k
+
+
+def test_moe_trains_and_uses_multiple_experts(mesh):
+    """A small ep-sharded regression task must reduce loss AND keep the
+    router spread across experts (aux loss regularizer working)."""
+    params = moe.init_moe(jax.random.PRNGKey(1), D, FF, E)
+    specs = _expert_specs(params)
+    grad_fn = _grad_fn(mesh, specs, 32)
+    x, sharded, xd, yd = _regression(mesh, specs, params)
+
+    opt = optax.adam(1e-2)
     st = opt.init(sharded)
-    xd = jax.device_put(x, NamedSharding(mesh, P("ep")))
-    yd = jax.device_put(y, NamedSharding(mesh, P("ep")))
     first = None
     for _ in range(60):
         loss, g = grad_fn(sharded, xd, yd)

@@ -93,6 +93,21 @@ def compiled_step(topo, cfg, shape) -> str:
                       tokens).compile().as_text()
 
 
+@pytest.fixture(scope="module")
+def as_built(topo, cfg):
+    """`compiled_step` of the package as it stands, compiled once a
+    shape for the whole file (a test that patches the package first
+    calls `compiled_step` itself). Call it under `chip_policy`."""
+    texts = {}
+
+    def text_of(shape) -> str:
+        if shape not in texts:
+            texts[shape] = compiled_step(topo, cfg, shape)
+        return texts[shape]
+
+    return text_of
+
+
 def fusions(text: str):
     """(output shapes, kind, body) of every fusion instruction."""
     bodies = {name: body for name, body in re.findall(
@@ -113,12 +128,12 @@ def instruction_multiset(text: str) -> collections.Counter:
 
 
 def test_one_chip_weight_gradient_matmuls_are_fusions_of_their_own(
-        topo, cfg, chip_policy):
+        cfg, chip_policy, as_built):
     """ISSUE 27: with the masters' Adam update fused into its output the
     SwiGLU weight-gradient matmul ran at 47% of peak. No fusion may hold
     a convolution AND write the float32 streams of the update."""
     d, ff = cfg.d_model, cfg.d_ff
-    found = list(fusions(compiled_step(topo, cfg, (1, 1))))
+    found = list(fusions(as_built((1, 1))))
     held = lambda body: " convolution(" in body  # noqa: E731
     for wide in (f"f32[{d},{ff}]", f"f32[{ff},{d}]"):
         assert not [out for out, _, body in found
@@ -132,16 +147,142 @@ def test_one_chip_weight_gradient_matmuls_are_fusions_of_their_own(
     assert sum(out.startswith(f"bf16[{ff},{d}]") for out in alone) == 1
 
 
+def written(line: str) -> str:
+    """Opcode and arrays written by a line of `instruction_multiset`,
+    layout, tiling and memory space off."""
+    out, opcode = re.match(r"(?:ROOT )?% = (.*?) ([\w\-]+)\(", line).groups()
+    return opcode + " " + re.sub(r"\{[^{}]*\}", "", out)
+
+
+# What PR 27's barrier changes in the one-layer 2x2 step since PR 29, and
+# all it changes. (Until then three all-reduces stood between a gradient
+# and the optimizer and the two programs were equal line for line; now
+# one does.) With the barrier the compiler keeps the [4096] norm scales,
+# their masters and moments, and two [4, 2048, 8, 128] blocks of the
+# attention's backward pass in its second memory space (`S(1)`) around
+# their use, copied in and out; without it, it prefetches one
+# [4096, 14336] and one [4096, 4096] weight there instead.
+# The instructions that read or write one of these are the same on both
+# sides but for that memory space and for the windows and cycles chosen
+# after it: five fusions (four of kind kOutput, and the [4096, 4096]
+# update) are tiled otherwise, and two all-reduces alias other operands.
+# What that costs on the chip: PERF.md section 6, PR 29.
+PLACED_OTHERWISE = {
+    "add f32[4,2048,8,128]": 2, "add f32[4096]": 12,
+    "all-reduce (bf16[4096,14336], bf16[4096], bf16[4096], bf16[4096])": 1,
+    "all-reduce (bf16[4096,4096], bf16[4096,6144])": 1,
+    "bitcast bf16[4,2048,1024]": 2,
+    "convert bf16[4,2048,8,128]": 2, "convert bf16[4096]": 3,
+    "convert f32[4096]": 3,
+    "fusion (bf16[4,2048], bf16[4096], bf16[4,2048,4096])": 1,
+    "fusion (bf16[4096,4096], f32[4096,4096], f32[4096,4096], "
+    "f32[4096,4096])": 1,
+    "fusion (bf16[4096], f32[4096], f32[4096], f32[4096])": 3,
+    "fusion (f32[4,2048], bf16[4,2048,4096])": 1,
+    "fusion bf16[4,2048,8,128]": 2, "fusion bf16[4096,6144]": 1,
+    "fusion f32[4,2048,4096]": 1,
+    "get-tuple-element bf16[4096]": 3, "get-tuple-element f32[4096]": 9,
+    "parameter bf16[4,2048,1024]": 4, "parameter bf16[4096,4096]": 5,
+    "parameter bf16[4096,6144]": 2, "parameter bf16[4096]": 6,
+    "parameter f32[4096]": 9,
+    "tuple (bf16[4096], f32[4096], f32[4096], f32[4096])": 3,
+}
+MOVED_WITH_THE_BARRIER = {
+    "copy-start (bf16[4,2048,8,128], bf16[4,2048,8,128], u32[])": 2,
+    "copy-done bf16[4,2048,8,128]": 2,
+    "copy-start (bf16[4096], bf16[4096], u32[])": 8,
+    "copy-done bf16[4096]": 8,
+    "copy-start (f32[4096], f32[4096], u32[])": 18,
+    "copy-done f32[4096]": 18,
+}
+MOVED_WITHOUT_IT = {
+    "copy-start (bf16[4096,14336], bf16[4096,14336], u32[])": 1,
+    "copy-done bf16[4096,14336]": 1,
+    "slice-start ((bf16[4096,4096]), bf16[1024,4096], s32[])": 4,
+    "slice-done bf16[1024,4096]": 4,
+    "custom-call bf16[4096,4096]": 1,       # ConcatBitcast of the slices
+}
+
+
 def test_2x2_program_is_the_one_without_the_barrier(topo, cfg, chip_policy,
-                                                    monkeypatch):
-    """Behind the all-reduce the barrier changes nothing: the 2x2 step's
-    instructions are those of the same step built without it."""
-    with_barrier = compiled_step(topo, cfg, (2, 2))
+                                                    as_built, monkeypatch):
+    """Behind the all-reduce the barrier changes no instruction: the 2x2
+    step's are those of the same step built without it, line for line,
+    but for the placement pinned above."""
+    with_barrier = as_built((2, 2))
     assert "all-reduce" in with_barrier
     with monkeypatch.context() as m:
         m.setattr(jax.lax, "optimization_barrier", lambda x: x)
         without = compiled_step(topo, cfg, (2, 2))
-    assert instruction_multiset(with_barrier) == instruction_multiset(without)
+    with_barrier, without = map(instruction_multiset, (with_barrier, without))
+    differs = lambda a, b: collections.Counter(  # noqa: E731
+        written(line) for line in (a - b).elements())
+    assert differs(with_barrier, without) == collections.Counter(
+        PLACED_OTHERWISE) + collections.Counter(MOVED_WITH_THE_BARRIER)
+    assert differs(without, with_barrier) == collections.Counter(
+        PLACED_OTHERWISE) + collections.Counter(MOVED_WITHOUT_IT)
+    assert sum(with_barrier.values()) > 2500        # of which 133 and 88
+
+
+def entry_instructions(text: str) -> dict:
+    """name -> (output type, opcode, operand names, the whole line) of
+    the entry computation's instructions."""
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text, re.M | re.S).group(1)
+    found = {}
+    for line in entry.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\((.*)", line)
+        if m:
+            name, out, opcode, rest = m.groups()
+            found[name] = (out, opcode, re.findall(
+                r"%([\w.\-]+)", re.sub(r", \w+=.*", "", rest)), line)
+    return found
+
+
+def array_bytes(out: str) -> int:
+    """Bytes of the arrays in an output type (scalars count nothing)."""
+    width = {"bf16": 2, "f32": 4}
+    return sum(width[kind] * int(np.prod([int(d) for d in dims.split(",")]))
+               for kind, dims in re.findall(r"(\w+)\[([\d,]+)\]", out))
+
+
+def test_2x2_gradients_cross_the_wire_once(cfg, chip_policy, as_built):
+    """ISSUE 29: until then every gradient went through three all-reduces
+    back to back, the sum over the four chips and two `pmean`s of a value
+    that was already the same on every chip. Every all-reduce that
+    carries an array is over all four chips, none reads what another
+    wrote (directly or through anything else), and together they carry
+    the parameters' bytes once."""
+    found = entry_instructions(as_built((2, 2)))
+    carrying = {name for name, (out, opcode, _, _) in found.items()
+                if opcode in ("all-reduce", "all-reduce-start")
+                and array_bytes(out)}
+    assert carrying
+    for name in carrying:
+        assert "replica_groups={{0,1,2,3}}" in found[name][3], name
+
+    def behind(name, seen):
+        for operand in found.get(name, ("", "", ()))[2]:
+            if operand not in seen:
+                seen.add(operand)
+                behind(operand, seen)
+        return seen
+
+    for name in carrying:
+        assert not carrying & behind(name, set()), name
+    params = jax.eval_shape(
+        lambda: tfm.init_transformer(jax.random.PRNGKey(0), cfg))
+    assert sum(array_bytes(found[name][0]) for name in carrying) == sum(
+        2 * int(np.prod(v.shape)) for v in params.values())
+    # and nothing scales a gradient on its way (the old x 0.5 of a mean)
+    assert "broadcast_multiply_fusion" not in "".join(found)
+
+
+def test_one_chip_step_holds_no_all_reduce(chip_policy, as_built):
+    """On a (1, 1) mesh the sums of the loss's mean compile to nothing,
+    and no other collective stands in the step."""
+    text = as_built((1, 1))
+    assert text.startswith("HloModule jit_lm_train_step")
+    assert "all-reduce" not in text
 
 
 # --------------------------------------------------------------------------

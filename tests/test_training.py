@@ -76,6 +76,81 @@ def test_dp_step_equals_single_device_step(mesh, digits):
                                    rtol=2e-5, atol=2e-6)
 
 
+def _stamped_trainer_step(tr, accum):
+    """The trainer's step as it was until PR 29, kept in this file alone:
+    every gradient leaf (and, where microbatches accumulate, the scan's
+    carry and every microbatch's loss and gradients) went through one
+    more `pmean` over each mesh axis, an identity on a value that is
+    already the same on every device, to tell the vma checker so."""
+    from jax import lax, shard_map
+    from jax.sharding import PartitionSpec as P
+    axes = tuple(tr.mesh.axis_names)
+
+    def stamped(tree, over=axes):
+        def stamp(v):
+            for a in over:
+                v = lax.pmean(v, a)
+            return v
+        return jax.tree.map(stamp, tree)
+
+    def shard_step(params, x, y):
+        def global_loss(p, xm, ym):
+            return lax.pmean(tr.loss_fn(p, xm, ym), tr.axis)
+
+        if accum == 1:
+            loss, grads = jax.value_and_grad(global_loss)(params, x, y)
+        else:
+            def body(carry, mb):
+                l, g = stamped(jax.value_and_grad(global_loss)(params, *mb))
+                return (carry[0] + l, jax.tree.map(jnp.add, carry[1], g)), None
+
+            micro = tuple(a.reshape(accum, -1, *a.shape[1:]) for a in (x, y))
+            (loss, grads), _ = lax.scan(body, stamped(
+                (jnp.float32(0.0), jax.tree.map(jnp.zeros_like, params))),
+                micro)
+            loss, grads = jax.tree.map(lambda v: v / accum, (loss, grads))
+        return loss, stamped(grads, (tr.axis,))
+
+    @jax.jit
+    def step(params, opt_state, x, y):
+        loss, grads = shard_map(
+            shard_step, mesh=tr.mesh, in_specs=(P(), P(tr.axis), P(tr.axis)),
+            out_specs=(P(), P()))(params, x, y)
+        updates, opt_state = tr.optimizer.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    return step
+
+
+@pytest.mark.parametrize("devices,accum", [(2, 1), (2, 4), (8, 1), (8, 4)])
+def test_trainer_step_without_the_stamp_is_the_stamped_one(
+        digits, devices, accum):
+    """One step's parameters, optimizer state and loss against those of
+    the step built the old way, to float32 rounding (the loss, which met
+    no stamp without microbatches, to the bit). Not every bit, for two
+    reasons that are the old step's: on eight devices its mean was a
+    running sum of eight equal values (3x, 5x, 6x and 7x need not be
+    float32 numbers) and a division by 8, not quite the identity it was
+    held to be; and on any mesh the CPU's compiler fuses the update
+    another way when no all-reduce stands right before it (2 devices,
+    where the mean is exact: 16 of 32768 updated weights, one ulp)."""
+    mesh = host_mesh(devices)
+    x, y = digits[0][:128], digits[1][:128]
+    tr = DataParallelTrainer(nll_loss, init_mlp(jax.random.PRNGKey(7)),
+                             mesh, TrainConfig(grad_accum=accum))
+    xs, ys = tr._shard_batch(x, y)
+    want = _stamped_trainer_step(tr, accum)(tr.params, tr.opt_state, xs, ys)
+    loss = tr.step(x, y)
+    got = (tr.params, tr.opt_state, loss)
+    assert jax.tree.structure(got[:2]) == jax.tree.structure(want[:2])
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-6, atol=1e-8)
+    if accum == 1:
+        assert loss == float(want[2])
+    assert np.isfinite(loss)
+
+
 def test_fit_learns_and_checkpoints(mesh, digits):
     x_tr, y_tr, x_va, y_va = digits
     params = init_mlp(jax.random.PRNGKey(0))

@@ -728,14 +728,14 @@ def test_empty_prompt_rejected(cfg):
 BARRIER_CASES = [((1, 1), 1), ((1, 1), 2), ((2, 2), 1), ((2, 2), 2)]
 
 
-def _barrier_setup(shape, dtype=jnp.float32):
+def _barrier_setup(shape, dtype=jnp.float32, rows=4, cfg=None):
     """A llama-style step on a (dp, sp) CPU mesh as the benchmark builds
     it: float32 masters under Adam, state laid out on the mesh."""
     from jax.sharding import Mesh
 
     from lua_mapreduce_tpu.train.precision import with_f32_master
     dp, sp = shape
-    cfg = tfm.TransformerConfig.llama_style(
+    cfg = cfg or tfm.TransformerConfig.llama_style(
         vocab=64, d_model=32, n_heads=4, n_kv_heads=2, n_layers=2, d_ff=64,
         max_seq=128, window=16)
     mesh = Mesh(np.array(jax.devices("cpu")[:dp * sp]).reshape(dp, sp),
@@ -745,9 +745,9 @@ def _barrier_setup(shape, dtype=jnp.float32):
     params = tfm.shard_params_moe(
         {k: v.astype(dtype) for k, v in params.items()}, mesh)
     state = tfm.init_opt_state(opt, params, mesh)
-    rows = np.random.RandomState(7).randint(0, cfg.vocab, (4, 33))
-    batch = tfm.shard_batch(mesh, rows[:, :-1].astype(np.int32),
-                            rows[:, 1:].astype(np.int32))
+    ids = np.random.RandomState(7).randint(0, cfg.vocab, (rows, 33))
+    batch = tfm.shard_batch(mesh, ids[:, :-1].astype(np.int32),
+                            ids[:, 1:].astype(np.int32))
     return cfg, mesh, opt, params, state, batch
 
 
@@ -787,55 +787,268 @@ def test_every_gradient_leaf_meets_one_barrier(shape, accum):
     assert bool(_eqns_named(body, "scan")) == (accum > 1)
 
 
-@pytest.mark.parametrize("shape,accum", BARRIER_CASES)
-def test_the_barrier_changes_no_bit_of_a_step(shape, accum):
-    """Parameters, optimizer state and loss of one step equal those of a
-    step whose gradients never met a barrier: the optimizer applied by
-    hand to `value_and_grad`'s result. bfloat16 weights, as the cells."""
+def _stamped(tree, axes):
+    """The OLD way, kept in this file alone: until PR 29 the package ran
+    every gradient leaf through `lax.pmean` over each data axis
+    (`utils/jax_compat.stamp_replicated`), an identity on a value that
+    is already the same on every device, to tell the vma checker so."""
+    from jax import lax
+
+    def stamp(x):
+        for a in axes:
+            x = lax.pmean(x, a)
+        return x
+
+    return jax.tree.map(stamp, tree)
+
+
+def _stamped_accum(loss_of, p, arrays, accum, axes):
+    """`accum_value_and_grad` as it was with that stamp: on the scan's
+    initial carry and on every microbatch's loss and gradients."""
+    from jax import lax
+    micro = tuple(a.reshape(accum, a.shape[0] // accum, *a.shape[1:])
+                  for a in arrays)
+
+    def body(carry, mb):
+        l, g = _stamped(jax.value_and_grad(loss_of)(p, *mb), axes)
+        return (carry[0] + l.astype(jnp.float32), jax.tree.map(
+            lambda acc, x: acc + x.astype(jnp.float32), carry[1], g)), None
+
+    l0, g0 = _stamped((jnp.float32(0.0), jax.tree.map(
+        lambda x: jnp.zeros_like(x, dtype=jnp.float32), p)), axes)
+    (loss_s, g_s), _ = lax.scan(body, (l0.astype(jnp.float32), g0), micro)
+    return loss_s / accum, jax.tree.map(
+        lambda g, x: (g / accum).astype(x.dtype), g_s, p)
+
+
+def _by_hand_step(cfg, mesh, opt, accum, stamped=False):
+    """`make_train_step`'s replicated path without the barrier: the
+    optimizer applied by hand to `value_and_grad`'s result. Returns the
+    gradients too. `stamped` builds it the old way (see `_stamped`)."""
+    import functools
+
     from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
     from lua_mapreduce_tpu.train.accum import accum_value_and_grad
-    from lua_mapreduce_tpu.utils.jax_compat import stamp_replicated
-    cfg, mesh, opt, params, state, batch = _barrier_setup(
-        shape, jnp.bfloat16)
-    axes, n_sp = ("dp", "sp"), shape[1]
+    axes, n_sp = ("dp", "sp"), mesh.shape["sp"]
     attn = tfm._attn_shard_fn("ring", "sp", n_sp, cfg)
+    suffix = tfm.param_specs_moe("dp") if cfg.moe_experts else {}
+    block = functools.partial(
+        tfm._block, moe_axis="dp" if cfg.moe_experts else None)
+
+    def unsharded(k):       # the data axes leaf k is NOT sharded over
+        return tuple(a for a in axes
+                     if a not in tuple(tfm._spec_for(k, suffix)))
 
     def shard(p, tok, tgt):
         pos = tfm._shard_pos("ring", "sp", n_sp, tok.shape[1])
 
         def loss_of(p, tok, tgt):
-            local = tfm.lm_loss_local(p, tok, tgt, cfg, attn, pos)
+            local = tfm.lm_loss_local(p, tok, tgt, cfg, attn, pos,
+                                      block=block)
             return lax.pmean(lax.pmean(local, "sp"), "dp")
 
         if accum == 1:
             loss, grads = jax.value_and_grad(loss_of)(p, tok, tgt)
+        elif stamped:
+            loss, grads = _stamped_accum(loss_of, p, (tok, tgt), accum,
+                                         axes)
         else:
             loss, grads = accum_value_and_grad(
-                loss_of, p, (tok, tgt), accum,
-                stamp=lambda l, g: (stamp_replicated(l, axes),
-                                    stamp_replicated(g, axes)))
-        return loss, stamp_replicated(grads, axes)
+                loss_of, p, (tok, tgt), accum)
+        if stamped:
+            grads = {k: _stamped(g, unsharded(k))
+                     for k, g in grads.items()}
+        return loss, grads
 
     @jax.jit
     def by_hand(p, s, tok, tgt):
+        specs = {k: tfm._spec_for(k, suffix) for k in p}
         loss, grads = shard_map(
-            shard, mesh=mesh, in_specs=(P(), P(*axes), P(*axes)),
-            out_specs=(P(), P()))(p, tok, tgt)
+            shard, mesh=mesh, in_specs=(specs, P(*axes), P(*axes)),
+            out_specs=(P(), specs))(p, tok, tgt)
         updates, s = opt.update(grads, s, p)
-        return optax.apply_updates(p, updates), s, loss
+        return optax.apply_updates(p, updates), s, loss, grads
 
+    return by_hand
+
+
+def _to_the_types(fn, *args):
+    """Run `fn` compiled so that no value keeps more precision than its
+    type states. Left to itself the CPU's compiler may skip a rounding
+    to bfloat16 where nothing stands between the gradient and the
+    float32 update that reads it (a barrier, or the old stamp's
+    all-reduce, is such a thing), and two builds of one step then differ
+    in a last bit that says nothing about either."""
+    return fn.lower(*args).compile(
+        compiler_options={"xla_allow_excess_precision": False})(*args)
+
+
+def _assert_same_bits(got, want, but=lambda path: False):
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        assert g.dtype == w.dtype
+        if not but(jax.tree_util.keystr(path)):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@pytest.mark.parametrize("shape,accum", BARRIER_CASES)
+def test_the_barrier_changes_no_bit_of_a_step(shape, accum):
+    """Parameters, optimizer state and loss of one step equal those of a
+    step whose gradients never met a barrier: the optimizer applied by
+    hand to `value_and_grad`'s result. bfloat16 weights, as the cells.
+
+    One case leaves one leaf out since PR 29: on the (1, 1) mesh at
+    `grad_accum=1` nothing at all stands between the by-hand step's
+    gradients and its update (the old stamp did, and an all-reduce or
+    the scan does in the other cases), and the CPU's compiler adds the
+    tied embedding's two gradients, the head's and the lookup's, into
+    the float32 update without rounding their sum to bfloat16 first. Its
+    master and moments then differ in a last bit; the bfloat16 weight
+    itself does not. Compiled to the types they agree as well."""
+    cfg, mesh, opt, params, state, batch = _barrier_setup(
+        shape, jnp.bfloat16)
+    by_hand = _by_hand_step(cfg, mesh, opt, accum)
     assert "optimization_barrier" not in str(
         jax.make_jaxpr(by_hand)(params, state, *batch))
-    want = by_hand(params, state, *batch)
+    want = by_hand(params, state, *batch)[:3]
     step = tfm.make_train_step(cfg, mesh, opt, grad_accum=accum)
-    got = step(*jax.tree.map(jnp.copy, (params, state)), *batch)
-    assert jax.tree.structure(got) == jax.tree.structure(want)
-    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
-        assert g.dtype == w.dtype
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    fresh = lambda: (  # noqa: E731     (the step donates its first two)
+        *jax.tree.map(jnp.copy, (params, state)), *batch)
+    got = step(*fresh())
+    if (shape, accum) == ((1, 1), 1):
+        _assert_same_bits(
+            got, want,
+            but=lambda path: path.startswith("[1]") and "tok_emb" in path)
+        _assert_same_bits(
+            _to_the_types(step, *fresh()),
+            _to_the_types(by_hand, params, state, *batch)[:3])
+    else:
+        _assert_same_bits(got, want)
     # and it was a step: finite, and the working weights moved
     assert np.isfinite(float(got[2]))
     assert any(not np.array_equal(np.asarray(v), np.asarray(params[k]))
                for k, v in got[0].items())
+
+
+# -- no replication stamp (PR 29): the step is the stamped one --------------
+
+def _moe_cfg():
+    return tfm.TransformerConfig(
+        vocab=64, d_model=32, n_heads=4, n_layers=2, d_ff=64, max_seq=128,
+        moe_experts=4, moe_capacity=64)
+
+
+STAMP_CASES = [
+    pytest.param((2, 2), 1, None, id="2x2"),
+    pytest.param((4, 1), 1, None, id="4x1"),
+    pytest.param((2, 2), 1, _moe_cfg, id="2x2-experts-over-dp"),
+    pytest.param((4, 1), 1, _moe_cfg, id="4x1-experts-over-dp"),
+    pytest.param((2, 2), 2, None, id="2x2-accum2"),
+    pytest.param((4, 1), 2, None, id="4x1-accum2"),
+]
+
+
+def _stamp_setup(shape, accum, make_cfg):
+    cfg, mesh, opt, params, state, batch = _barrier_setup(
+        shape, jnp.bfloat16, rows=8, cfg=make_cfg and make_cfg())
+    old = _by_hand_step(cfg, mesh, opt, accum, stamped=True)
+    step = tfm.make_train_step(cfg, mesh, opt, grad_accum=accum)
+    return cfg, mesh, opt, old, step, (params, state, *batch)
+
+
+def _eqns_within(jaxpr):
+    for e in jaxpr.eqns:
+        yield e
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns_within(sub)
+
+
+def _sums(fn, *args):
+    """Whether `fn`'s one shard_map is checked, and how many sums over
+    mesh axes it holds at any depth: {(axes, of an array): count}. A
+    `pmean` is traced as a `psum_invariant` and a division."""
+    import collections
+    (_, program), = _eqns_named(jax.make_jaxpr(fn)(*args).jaxpr, "jit")
+    (_, mapped), = _eqns_named(program.params["jaxpr"].jaxpr, "shard_map")
+    found = collections.Counter(
+        (tuple(e.params["axes"]), e.invars[0].aval.ndim > 0)
+        for e in _eqns_within(mapped.params["jaxpr"])
+        if e.primitive.name in ("psum", "psum_invariant"))
+    return mapped.params["check_vma"], found
+
+
+@pytest.mark.parametrize("shape,accum,make_cfg", STAMP_CASES)
+def test_the_step_traces_checked_and_with_no_mean_of_a_gradient(
+        shape, accum, make_cfg):
+    """The package's shard_map is traced with the vma check ON and
+    `out_specs` as they were, and it holds the stamped step's sums less
+    the stamps: one `pmean` a data axis that a leaf is not sharded over,
+    three times where microbatches accumulate (the carry's start, every
+    microbatch, the result), and there twice on the loss as well."""
+    cfg, mesh, opt, old, step, args = _stamp_setup(shape, accum, make_cfg)
+    checked, sums = _sums(step, *args)
+    assert checked is True
+    old_checked, old_sums = _sums(old, *args)
+    assert old_checked is True
+    suffix = tfm.param_specs_moe("dp") if cfg.moe_experts else {}
+    over = lambda a: sum(  # noqa: E731
+        a not in tuple(tfm._spec_for(k, suffix)) for k in args[0])
+    # four expert leaves a layer are sharded over dp: no stamp there
+    experts = 4 * cfg.n_layers * bool(cfg.moe_experts)
+    assert over("dp") == len(args[0]) - experts
+    times, on_loss = (3, 2) if accum > 1 else (1, 0)
+    stamps = {((a,), True): times * over(a) for a in ("dp", "sp")}
+    stamps.update({((a,), False): on_loss
+                   for a in ("dp", "sp") if on_loss})
+    assert dict(old_sums - sums) == stamps
+    assert not sums - old_sums
+    if not cfg.moe_experts:
+        # what is left is the transpose of the loss's mean: every
+        # gradient summed once, over both axes at once
+        arrays = {axes for (axes, is_array) in sums if is_array}
+        assert arrays == {("dp", "sp")}
+
+
+@pytest.mark.parametrize("shape,accum,make_cfg", STAMP_CASES)
+def test_the_step_without_the_stamp_is_the_stamped_one(
+        shape, accum, make_cfg):
+    """One step's gradients, parameters, optimizer state and loss against
+    those of the step built the old way, a `pmean` over each data axis on
+    every gradient leaf: bit for bit at `grad_accum=1`.
+
+    With microbatches the accumulated GRADIENTS are compared, to one
+    bfloat16 ulp of the leaf's largest element. The old stamp's
+    all-reduce stood between each microbatch's bfloat16 gradient and the
+    float32 accumulator; without it the CPU's compiler may add a
+    gradient it has not yet rounded (see `_to_the_types`; here the tied
+    embedding's, the sum of the head's and the lookup's), so the float32
+    sums differ by what that rounding would have taken off, and after
+    their own rounding by at most one step at the size of the terms: a
+    small element, where the microbatches cancel, by many of ITS ulps.
+    Adam divides by the root of a tiny second moment and turns that into
+    a visible difference of an updated leaf, which is why the comparison
+    stops at the gradients there. Compiled to the types, every bit of
+    the whole step agrees."""
+    cfg, mesh, opt, old, step, args = _stamp_setup(shape, accum, make_cfg)
+    new = _by_hand_step(cfg, mesh, opt, accum)
+    want = old(*args)
+    got = new(*args)
+    if accum == 1:
+        _assert_same_bits(got, want)
+        _assert_same_bits(step(*jax.tree.map(jnp.copy, args[:2]),
+                               *args[2:]), want[:3])
+    else:
+        for k, w in want[3].items():
+            w = np.asarray(w.astype(jnp.float32))
+            g = np.asarray(got[3][k].astype(jnp.float32))
+            ulp = 2.0 ** (np.floor(np.log2(np.abs(w).max())) - 7)
+            assert np.abs(g - w).max() <= ulp, k
+        _assert_same_bits(_to_the_types(new, *args),
+                          _to_the_types(old, *args))
+    assert any(np.asarray(g).any() for g in want[3].values())
