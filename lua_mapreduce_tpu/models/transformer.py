@@ -34,10 +34,11 @@ import optax
 from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from lua_mapreduce_tpu.models.attention_kinds import (
+    GroupedQuery, Latent, Params, _dense, _mm, _norm, _rope, head_dim,
+    kv_heads)
 from lua_mapreduce_tpu.ops.attention import flash_attention
-from lua_mapreduce_tpu.ops.decode import decode_attention, quantize_kv
-from lua_mapreduce_tpu.ops.q8 import q8_matmul, quantize_q8
-from lua_mapreduce_tpu.ops import sparse_mla as _sparse
+from lua_mapreduce_tpu.ops.q8 import quantize_q8
 from lua_mapreduce_tpu.parallel import moe as _moe
 from lua_mapreduce_tpu.parallel import zero1 as _z1
 from lua_mapreduce_tpu.parallel.pipeline import pipeline_apply
@@ -47,12 +48,6 @@ from lua_mapreduce_tpu.parallel.ring_attention import (
 from lua_mapreduce_tpu.train.accum import accum_value_and_grad
 from lua_mapreduce_tpu.utils.profiling import annotate, scope
 
-Params = Dict[str, jnp.ndarray]
-
-# queries of a latent-attention forward over a full sequence, all rows of
-# the batch together, that meet the cache at a time: the indexer's
-# (queries, heads, keys) scores exist for one such block
-_QUERY_BLOCK = 64
 
 
 @dataclasses.dataclass(frozen=True)
@@ -202,22 +197,16 @@ def flops_per_token(cfg: TransformerConfig, seq_len: int,
     return 3.0 * fwd
 
 
-def kv_heads(cfg: TransformerConfig) -> int:
-    """Effective kv head count (n_kv_heads, defaulting to n_heads)."""
-    hkv = cfg.n_kv_heads or cfg.n_heads
-    if cfg.n_heads % hkv:
-        raise ValueError(f"n_kv_heads={hkv} must divide "
-                         f"n_heads={cfg.n_heads}")
-    return hkv
-
-
-def head_dim(cfg: TransformerConfig) -> int:
-    return cfg.head_dim or cfg.d_model // cfg.n_heads
-
-
 def moe_layer(cfg: TransformerConfig, i: int) -> bool:
     """Whether layer ``i`` has the expert FFN (else the dense one)."""
     return bool(cfg.moe_experts) and i >= cfg.moe_first_dense
+
+
+def attention_kind(cfg: TransformerConfig, i: int):
+    """Layer ``i``'s attention: its weights, what a position caches, the
+    cache's format and the attention over it
+    (models/attention_kinds.py)."""
+    return GroupedQuery(cfg) if cfg.latent is None else Latent(cfg)
 
 
 def _check_arch(cfg: TransformerConfig) -> None:
@@ -295,13 +284,11 @@ def init_transformer(key, cfg: TransformerConfig = TransformerConfig(),
     _check_moe(cfg)
     _check_arch(cfg)
     d, ff = cfg.d_model, cfg.d_ff
-    hd = head_dim(cfg)
-    qkv_cols = (cfg.n_heads + 2 * kv_heads(cfg)) * hd
     params: Params = {}
     keys = iter(jax.random.split(key, 2 + 5 * cfg.n_layers))
 
     def dense(shape):
-        return jax.random.normal(next(keys), shape, dtype) / np.sqrt(shape[0])
+        return _dense(next(keys), shape, dtype)
 
     params["tok_emb"] = 0.02 * jax.random.normal(
         next(keys), (cfg.vocab, d), dtype)
@@ -310,11 +297,7 @@ def init_transformer(key, cfg: TransformerConfig = TransformerConfig(),
             next(keys), (cfg.max_seq, d), dtype)
     for i in range(cfg.n_layers):
         p = f"L{i}"
-        if cfg.latent is not None:
-            params.update(_init_latent(next(keys), cfg, dtype, p))
-        else:
-            params[f"{p}_qkv_W"] = dense((d, qkv_cols))
-            params[f"{p}_out_W"] = dense((cfg.n_heads * hd, d))
+        params.update(attention_kind(cfg, i).init(keys, dtype, p))
         if moe_layer(cfg, i) and cfg.moe_router == "grouped":
             params.update(_moe.init_moe_held(
                 next(keys), d, cfg.moe_d_ff or ff, cfg.moe_experts,
@@ -346,29 +329,6 @@ def init_transformer(key, cfg: TransformerConfig = TransformerConfig(),
     return params
 
 
-def _init_latent(key, cfg: TransformerConfig, dtype, p: str) -> Params:
-    """One layer's latent-attention and indexer weights."""
-    la, d, h = cfg.latent, cfg.d_model, cfg.n_heads
-    shapes = {
-        "qa_W": (d, la.q_rank),
-        "qb_W": (la.q_rank, h * (la.nope_dim + la.rope_dim)),
-        "kva_W": (d, la.kv_rank + la.rope_dim),
-        "kvb_W": (la.kv_rank, h * (la.nope_dim + la.v_dim)),
-        "out_W": (h * la.v_dim, d),
-        "iq_W": (la.q_rank, la.index_heads * la.index_dim),
-        "ik_W": (d, la.index_dim),
-        "iw_W": (d, la.index_heads),
-    }
-    out = {f"{p}_{n}": jax.random.normal(k, shape, dtype) / np.sqrt(shape[0])
-           for (n, shape), k in zip(shapes.items(),
-                                    jax.random.split(key, len(shapes)))}
-    out[f"{p}_qa_g"] = jnp.ones((la.q_rank,), dtype)
-    out[f"{p}_kv_g"] = jnp.ones((la.kv_rank,), dtype)
-    out[f"{p}_ik_g"] = jnp.ones((la.index_dim,), dtype)
-    out[f"{p}_ik_b"] = jnp.zeros((la.index_dim,), dtype)
-    return out
-
-
 def _head(params: Params, x):
     """The LM head: ``x @ head_W`` where the dict has one (an untied
     head), else the tied ``x @ tok_emb.T`` — through the int8 kernel when
@@ -381,20 +341,6 @@ def _head(params: Params, x):
     if "head_W" in params:
         return x @ params["head_W"]
     return x @ params["tok_emb"].T
-
-
-def _mm(params: Params, key: str, y):
-    """``y @ params[key]`` — through the weight-only int8 kernel when
-    the param dict carries a quantized entry (``key::q8`` +
-    ``key::scale``, see :func:`quantize_lm`). The branch is on dict
-    STRUCTURE, so it is resolved at trace time and costs nothing."""
-    qk = key + "::q8"
-    if qk in params:
-        shp = y.shape
-        out = q8_matmul(y.reshape(-1, shp[-1]), params[qk],
-                        params[key + "::scale"])
-        return out.reshape(*shp[:-1], out.shape[-1])
-    return y @ params[key]
 
 
 def quantize_lm(params: Params) -> Params:
@@ -430,65 +376,6 @@ def quantize_lm(params: Params) -> Params:
     out["head::q8"] = qh
     out["head::scale"] = sh.reshape(-1)
     return out
-
-
-def _layer_norm(x, g, b, eps=1e-5):
-    mu = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.var(x, axis=-1, keepdims=True)
-    return (x - mu) * lax.rsqrt(var + eps) * g + b
-
-
-def _rms_norm(x, g, eps):
-    ms = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    return x * lax.rsqrt(ms + eps) * g
-
-
-def _norm(params: Params, name: str, x, cfg: TransformerConfig):
-    """The block norm: pre-LN (scale+bias) or RMSNorm (scale only)."""
-    g = params[f"{name}_g"]
-    if cfg.norm == "rms":
-        return _rms_norm(x, g, cfg.norm_eps)
-    return _layer_norm(x, g, params[f"{name}_b"], cfg.norm_eps)
-
-
-def _yarn_freqs(la: LatentAttention, base: float) -> np.ndarray:
-    """The rope frequencies of ``la.rope_dim`` under YaRN (Peng et al.
-    2023, as DeepSeek-V3 applies it): frequencies whose wavelength fits
-    the original context ``beta_fast`` times or more are kept, those
-    that fit it ``beta_slow`` times or fewer are divided by the factor,
-    with a linear ramp between."""
-    dim = la.rope_dim
-    freqs = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
-    if la.rope_factor == 1.0:
-        return freqs.astype(np.float32)
-
-    def turns_to_dim(turns):
-        return (dim * np.log(la.rope_original / (turns * 2 * np.pi))
-                / (2 * np.log(base)))
-
-    low = max(np.floor(turns_to_dim(la.beta_fast)), 0)
-    high = min(np.ceil(turns_to_dim(la.beta_slow)), dim - 1)
-    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
-    return (freqs / la.rope_factor * ramp
-            + freqs * (1 - ramp)).astype(np.float32)
-
-
-def _rope(x, pos, base: float, freqs=None):
-    """Rotary embedding: rotate each (i, i+hd/2) pair of head dims by
-    pos·base^(-2i/hd), or by ``freqs`` where given. x (B, L, H*, hd) —
-    broadcasts over ANY head
-    count (q and GQA's smaller k alike); pos (L,) global positions.
-    Rotation-half convention; angles in f32, result in x.dtype."""
-    half = x.shape[-1] // 2
-    if freqs is None:
-        freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[:, None] * freqs[None, :]  # (L, half)
-    cos = jnp.cos(ang)[None, :, None, :]
-    sin = jnp.sin(ang)[None, :, None, :]
-    x1, x2 = x[..., :half].astype(jnp.float32), \
-        x[..., half:].astype(jnp.float32)
-    return jnp.concatenate([x1 * cos - x2 * sin,
-                            x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
 def _ffn(params: Params, i: int, y, cfg: TransformerConfig,
@@ -532,138 +419,37 @@ def _ffn(params: Params, i: int, y, cfg: TransformerConfig,
     return out.reshape(b, l, d), aux
 
 
-def _rope_head(x, pos, cfg: TransformerConfig):
-    """Rope (YaRN frequencies) on the first ``rope_dim`` values of
-    (B, L, H*, D) and none on the rest."""
-    la = cfg.latent
-    turned = _rope(x[..., :la.rope_dim], pos, cfg.rope_base,
-                   _yarn_freqs(la, cfg.rope_base))
-    return jnp.concatenate([turned, x[..., la.rope_dim:]], axis=-1)
-
-
-def _latent_rows(params: Params, p: str, y, pos, cfg: TransformerConfig):
-    """What latent attention caches of the normed block input ``y``
-    (B, L, d) at positions ``pos``: the row ``[c_kv | k_rope]`` (the
-    normed latent, the rotated rope key that all heads share) and the
-    indexer's key (LayerNorm, rope on its first ``rope_dim`` values)."""
-    la = cfg.latent
-    with scope("lm.mla"):
-        kv = _mm(params, f"{p}_kva_W", y)
-        c = _rms_norm(kv[..., :la.kv_rank], params[f"{p}_kv_g"],
-                      cfg.norm_eps)
-        k_r = _rope_head(kv[..., None, la.kv_rank:], pos, cfg)[..., 0, :]
-        ckv = jnp.concatenate([c, k_r], axis=-1)
-    with scope("lm.indexer"):
-        ik = _layer_norm(_mm(params, f"{p}_ik_W", y), params[f"{p}_ik_g"],
-                         params[f"{p}_ik_b"], cfg.norm_eps)
-        ik = _rope_head(ik[..., None, :], pos, cfg)[..., 0, :]
-    return ckv, ik.astype(y.dtype)
-
-
-def _latent_attend(params: Params, p: str, y, pos, ckv, ik,
-                   cfg: TransformerConfig):
-    """Latent attention of the queries ``y`` (B, Q, d; normed block
-    input) at positions ``pos`` over caches ``ckv`` (B, S, kv_rank +
-    rope_dim) and ``ik`` (B, S, index_dim) that hold these positions'
-    own rows already. Absorbed form: ``q_nope`` is taken into the
-    latent's basis through ``kvb_W``'s key half, the selected rows are
-    key and value at once, and the sum comes out through its value half.
-    Returns (out (B, Q, d), idx (B, Q, K) selected positions, -1 where
-    the query sees fewer than K)."""
-    la, h = cfg.latent, cfg.n_heads
-    b, q_len, _ = y.shape
-    freqs = _yarn_freqs(la, cfg.rope_base)
-    m = 0.1 * la.mscale_all_dim * np.log(la.rope_factor) + 1.0
-    scale = float((la.nope_dim + la.rope_dim) ** -0.5 * m * m)
-    w_kv = params[f"{p}_kvb_W"].reshape(la.kv_rank, h,
-                                        la.nope_dim + la.v_dim)
-    with scope("lm.mla"):
-        c_q = _rms_norm(_mm(params, f"{p}_qa_W", y), params[f"{p}_qa_g"],
-                        cfg.norm_eps)
-        q = _mm(params, f"{p}_qb_W", c_q).reshape(
-            b, q_len, h, la.nope_dim + la.rope_dim)
-        q_rope = _rope(q[..., la.nope_dim:], pos, cfg.rope_base, freqs)
-        q_lat = jnp.einsum("bqhn,chn->bqhc", q[..., :la.nope_dim],
-                           w_kv[..., :la.nope_dim])
-        q = jnp.concatenate([q_lat, q_rope], axis=-1)
-    with scope("lm.indexer"):
-        q_i = _mm(params, f"{p}_iq_W", c_q).reshape(
-            b, q_len, la.index_heads, la.index_dim)
-        q_i = _rope_head(q_i, pos, cfg)
-        w = _mm(params, f"{p}_iw_W", y) * float(
-            la.index_heads ** -0.5 * la.index_dim ** -0.5)
-        idx, valid = _sparse.select_top_k(
-            _sparse.index_scores(q_i, w, ik, pos), la.index_top_k)
-    with scope("lm.sparse"):
-        o_lat = _sparse.sparse_latent_attention(
-            q, ckv, idx, valid, scale=scale, v_rank=la.kv_rank)
-    with scope("lm.mla"):
-        o = jnp.einsum("bqhc,chv->bqhv", o_lat.astype(y.dtype),
-                       w_kv[..., la.nope_dim:])
-        out = _mm(params, f"{p}_out_W", o.reshape(b, q_len, h * la.v_dim))
-    return out, jnp.where(valid, idx, -1)
-
-
-def _latent_attention(params: Params, p: str, y, pos, rows,
-                      cfg: TransformerConfig):
-    """:func:`_latent_attend` for every position of a full sequence,
-    ``_QUERY_BLOCK`` queries at a time."""
-    b, l, d = y.shape
-    block = min(max(1, _QUERY_BLOCK // b), l)
-    if l == block:
-        return _latent_attend(params, p, y, pos, *rows, cfg)[0]
-    n = -(-l // block)
-    pad = n * block - l                 # padded queries repeat the last
-    yb = jnp.pad(y, ((0, 0), (0, pad), (0, 0)), mode="edge")
-    pb = jnp.pad(pos, (0, pad), mode="edge")
-    out = lax.map(
-        lambda blk: _latent_attend(params, p, blk[0], blk[1], *rows, cfg)[0],
-        (yb.reshape(b, n, block, d).transpose(1, 0, 2, 3),
-         pb.reshape(n, block)))
-    return out.transpose(1, 0, 2, 3).reshape(b, n * block, d)[:, :l]
+def _layer(params: Params, i: int, x, cfg: TransformerConfig, attend,
+           moe_axis: Optional[str] = None,
+           stats_sink: Optional[list] = None):
+    """One pre-norm decoder layer, written once: norm, attention,
+    residual; norm, FFN, residual. ``attend(kind, p, y) -> (out, kept)``
+    is the form of layer ``i``'s attention kind the caller runs on the
+    normed input (a full sequence, a chunk over a growing cache, one
+    position) with what that form hands back; it is called here, once,
+    so a caller's closure sees its loop's current caches. Returns (x,
+    moe aux, kept)."""
+    p = f"L{i}"
+    with scope("lm.attn"):
+        out, kept = attend(attention_kind(cfg, i), p,
+                           _norm(params, f"{p}_ln1", x, cfg))
+        x = x + out
+    with scope("lm.ffn"):
+        y = _norm(params, f"{p}_ln2", x, cfg)
+        out, aux = _ffn(params, i, y, cfg, moe_axis, stats_sink)
+        return x + out, aux, kept
 
 
 def _block(params: Params, i: int, x, cfg: TransformerConfig, attn_fn,
-           pos, moe_axis: Optional[str] = None,
-           kv_sink: Optional[list] = None):
-    """One pre-norm decoder block; ``attn_fn(q, k, v) -> out`` supplies
-    the (possibly sequence-parallel) attention; ``pos`` are the GLOBAL
-    positions of the L rows (rope consumes them; ignored otherwise).
-    Latent attention brings its own (sparse over what it caches) and
-    leaves ``attn_fn`` aside. Returns (x, moe_aux).
-
-    ``kv_sink`` (a list) captures what this block caches, the (k, v)
-    projections or the latent rows and indexer keys — the prefill path
-    harvests them as the decode cache. With rope the captured k is the
-    ROTATED one (what attention consumes and what the decode cache
-    stores)."""
-    p = f"L{i}"
-    b, l, d = x.shape
-    h, hd = cfg.n_heads, head_dim(cfg)
-    hkv = kv_heads(cfg)
-    with scope("lm.attn"):
-        y = _norm(params, f"{p}_ln1", x, cfg)
-        if cfg.latent is not None:
-            rows = _latent_rows(params, p, y, pos, cfg)
-            if kv_sink is not None:
-                kv_sink.append(rows)
-            x = x + _latent_attention(params, p, y, pos, rows, cfg)
-        else:
-            qkv = _mm(params, f"{p}_qkv_W", y)  # (B, L, (H+2Hkv)·hd) MXU
-            q = qkv[..., :h * hd].reshape(b, l, h, hd)
-            k = qkv[..., h * hd:(h + hkv) * hd].reshape(b, l, hkv, hd)
-            v = qkv[..., (h + hkv) * hd:].reshape(b, l, hkv, hd)
-            if cfg.rope:
-                q = _rope(q, pos, cfg.rope_base)
-                k = _rope(k, pos, cfg.rope_base)
-            if kv_sink is not None:
-                kv_sink.append((k, v))
-            a = attn_fn(q, k, v).reshape(b, l, h * hd)
-            x = x + _mm(params, f"{p}_out_W", a)
-    with scope("lm.ffn"):
-        y = _norm(params, f"{p}_ln2", x, cfg)
-        out, aux = _ffn(params, i, y, cfg, moe_axis)
-        return x + out, aux
+           pos, moe_axis: Optional[str] = None):
+    """The layer over a full sequence; ``attn_fn(q, k, v) -> out``
+    supplies the (possibly sequence-parallel) attention where the kind
+    takes one; ``pos`` are the GLOBAL positions of the L rows (rope
+    consumes them; ignored otherwise). Returns (x, moe_aux, what the
+    layer cached: the prefill path hands it out as the decode cache)."""
+    return _layer(params, i, x, cfg,
+                  lambda kind, p, y: kind.full(params, p, y, pos, attn_fn),
+                  moe_axis)
 
 
 def _check_seq(global_len: int, cfg: TransformerConfig) -> None:
@@ -679,27 +465,28 @@ def _forward(params: Params, tokens, pos, cfg: TransformerConfig,
     """Shared body: tokens (B, L) int32, pos (L,) global positions;
     ``block`` swaps the decoder-block implementation (the 3-D form
     passes its tensor-parallel block) — one forward for every path.
-    Returns (logits, summed moe aux loss; 0.0 for dense blocks)."""
+    Returns (logits, summed moe aux loss; 0.0 for dense blocks, what
+    each layer cached, where the block hands that back)."""
     block = block or _block
     with scope("lm.embed"):
         x = params["tok_emb"][tokens]
         if not cfg.rope:
             x = x + params["pos_emb"][pos]   # rope positions live in-block
-    aux_total = 0.0
+    aux_total, cached = 0.0, []
     for i in range(cfg.n_layers):
+        def run_block(p, xx, _i=i):
+            return block(p, _i, xx, cfg, attn_fn, pos)
         if cfg.remat:
             # checkpoint boundary = one decoder block (collectives inside
             # sp/tp blocks are re-executed in the backward — the usual
             # ring-attention remat shape)
-            def run_block(p, xx, _i=i):
-                return block(p, _i, xx, cfg, attn_fn, pos)
-            x, aux = jax.checkpoint(run_block)(params, x)
-        else:
-            x, aux = block(params, i, x, cfg, attn_fn, pos)
+            run_block = jax.checkpoint(run_block)
+        x, aux, *rows = run_block(params, x)
+        cached.extend(rows)
         aux_total = aux_total + aux
     with scope("lm.head"):
         x = _norm(params, "lnf", x, cfg)
-        return _head(params, x), aux_total              # tied head
+        return _head(params, x), aux_total, cached      # tied head
 
 
 def prefill(params: Params, prompt, *,
@@ -742,27 +529,21 @@ def prefill(params: Params, prompt, *,
     tokens = prompt.astype(jnp.int32)
 
     if chunk:
-        if cfg.latent is None or mesh is not None or p_len % chunk:
+        if mesh is not None or p_len % chunk:
             raise ValueError("chunk is for single-device latent attention "
                              f"and must divide the prompt ({p_len})")
         return _prefill_chunked(params, tokens, cfg_fwd, total, chunk)
     if mesh is None:
-        sink: list = []
         # backend="auto": the fused flash kernel on TPU — prefilling a
         # long prompt is exactly the workload whose (P, P) score matrix
         # must not land in HBM; off-TPU this resolves to the XLA oracle
-        logits, _ = _forward(
+        logits, _, kvs = _forward(
             params, tokens, jnp.arange(p_len), cfg_fwd,
             lambda q, k, v: flash_attention(q, k, v, causal=True,
                                             backend="auto",
-                                            window=cfg.window),
-            block=functools.partial(_block, kv_sink=sink))
-        kvs, last = sink, logits[:, -1]
+                                            window=cfg.window))
     else:
-        if cfg.latent is not None:
-            raise ValueError("sequence-parallel prefill runs grouped-query "
-                             "attention; latent attention prefills "
-                             "single-device")
+        _check_sharded(cfg)
         if cfg.moe_experts:
             raise ValueError("sequence-parallel prefill supports dense "
                              "configs; MoE prefills single-device")
@@ -775,13 +556,10 @@ def prefill(params: Params, prompt, *,
         def shard_fwd(params, toks):
             l_loc = toks.shape[1]
             pos = _shard_pos(attn, sp_axis, n_sp, l_loc)
-            sink: list = []
-            logits, _ = _forward(
-                params, toks, pos, cfg_fwd, attn_shard,
-                block=functools.partial(_block, kv_sink=sink))
-            ks = jnp.stack([kk for kk, _ in sink])  # (nl, B, Lloc, Hkv, hd)
-            vs = jnp.stack([vv for _, vv in sink])
-            return logits, ks, vs
+            logits, _, rows = _forward(params, toks, pos, cfg_fwd,
+                                       attn_shard)
+            # leaf by leaf over the layers: (nl, B, Lloc, Hkv, hd)
+            return logits, tuple(jnp.stack(leaf) for leaf in zip(*rows))
 
         tokens_z, perm = _maybe_zigzag(attn, n_sp, tokens)
         # inference batches are often smaller than the training dp
@@ -792,24 +570,20 @@ def prefill(params: Params, prompt, *,
         fn = shard_map(
             shard_fwd, mesh=mesh,
             in_specs=(P(), P(bspec, sp_axis)),
-            out_specs=(P(bspec, sp_axis),
-                       P(None, bspec, sp_axis),
-                       P(None, bspec, sp_axis)))
-        logits, ks, vs = fn(params, tokens_z)
+            out_specs=(P(bspec, sp_axis), P(None, bspec, sp_axis)))
+        logits, stacked = fn(params, tokens_z)
         if perm is not None:                 # back to standard order
             inv = perm.argsort()
             logits = logits[:, inv]
-            ks, vs = ks[:, :, inv], vs[:, :, inv]
-        kvs = [(ks[i], vs[i]) for i in range(cfg.n_layers)]
-        last = logits[:, -1]
+            stacked = tuple(leaf[:, :, inv] for leaf in stacked)
+        kvs = [tuple(leaf[i] for leaf in stacked)
+               for i in range(cfg.n_layers)]
 
     caches = {}
-    for i, leaves in enumerate(kvs):
-        for name, leaf in zip(_cache_leaves(cfg), leaves):
-            pad = ((0, 0), (0, total - p_len)) + ((0, 0),) * (leaf.ndim - 2)
-            caches[f"L{i}_{name}"] = jnp.pad(leaf, pad).astype(
-                params["tok_emb"].dtype)
-    return caches, last.astype(jnp.float32)
+    for i, rows in enumerate(kvs):
+        caches.update(attention_kind(cfg, i).padded(
+            f"L{i}", rows, total, params["tok_emb"].dtype))
+    return caches, logits[:, -1].astype(jnp.float32)
 
 
 def _prefill_chunked(params: Params, tokens, cfg: TransformerConfig,
@@ -818,7 +592,8 @@ def _prefill_chunked(params: Params, tokens, cfg: TransformerConfig,
     every row at a time: a chunk writes its rows into the caches and
     attends what they hold by then (its own positions and all before)."""
     b, p_len = tokens.shape
-    caches = _empty_latent_caches(cfg, b, total, params["tok_emb"].dtype)
+    caches = _layer_caches(cfg, lambda kind, p: kind.empty(
+        p, b, total, params["tok_emb"].dtype))
 
     def one_chunk(caches, toks_start):
         toks, start = toks_start
@@ -826,20 +601,9 @@ def _prefill_chunked(params: Params, tokens, cfg: TransformerConfig,
         with scope("lm.embed"):
             x = params["tok_emb"][toks]
         for i in range(cfg.n_layers):
-            p = f"L{i}"
-            with scope("lm.attn"):
-                y = _norm(params, f"{p}_ln1", x, cfg)
-                written = {
-                    f"{p}_{name}": lax.dynamic_update_slice(
-                        caches[f"{p}_{name}"], row, (0, start, 0))
-                    for name, row in zip(_cache_leaves(cfg),
-                                         _latent_rows(params, p, y, pos, cfg))}
-                caches = {**caches, **written}
-                x = x + _latent_attention(params, p, y, pos,
-                                          tuple(written.values()), cfg)
-            with scope("lm.ffn"):
-                y = _norm(params, f"{p}_ln2", x, cfg)
-                x = x + _ffn(params, i, y, cfg, None)[0]
+            x, _, caches = _layer(
+                params, i, x, cfg, lambda kind, p, y: kind.chunk(
+                    params, p, y, pos, caches, start))
         return caches, x[:, -1]
 
     caches, last = lax.scan(
@@ -851,27 +615,12 @@ def _prefill_chunked(params: Params, tokens, cfg: TransformerConfig,
     return caches, logits[:, 0].astype(jnp.float32)
 
 
-def _empty_latent_caches(cfg: TransformerConfig, b: int, total: int,
-                         dtype) -> Params:
-    la = cfg.latent
-    widths = dict(zip(_cache_leaves(cfg),
-                      (la.kv_rank + la.rope_dim, la.index_dim)))
-    return {f"L{i}_{name}": jnp.zeros((b, total, width), dtype)
-            for i in range(cfg.n_layers) for name, width in widths.items()}
-
-
-def _cache_len(caches: Params, cfg: TransformerConfig) -> int:
-    """Slots of caches in the decode layout."""
-    if cfg.latent is not None:
-        return caches["L0_ckv"].shape[1]
-    return caches["L0_k"].shape[2]
-
-
-def _cache_leaves(cfg: TransformerConfig) -> tuple:
-    """Names of what a layer caches, in the order `_block` captures."""
-    return ("k", "v") if cfg.latent is None else ("ckv", "ik")
-
-
+def _layer_caches(cfg: TransformerConfig, form) -> Params:
+    """Every layer's caches: the union of ``form(kind, p)`` over them."""
+    caches = {}
+    for i in range(cfg.n_layers):
+        caches.update(form(attention_kind(cfg, i), f"L{i}"))
+    return caches
 
 
 def _selector(cfg: TransformerConfig, temperature: float,
@@ -900,26 +649,6 @@ def _selector(cfg: TransformerConfig, temperature: float,
     return select
 
 
-def _rolls(cfg: TransformerConfig, cache_len: int) -> bool:
-    """Whether caches of ``cache_len`` slots are a ROLLING buffer: they
-    are where they are as long as the window (position p lives in slot
-    p mod window). The one rule for every decode; where the window
-    covers the whole decode, p mod window is p and the two layouts are
-    one."""
-    return bool(cfg.window) and cache_len == cfg.window
-
-
-def _cache_shape(cfg: TransformerConfig, total: int) -> tuple:
-    """(roll, cache_len) of a decode over ``total`` positions. A sliding
-    window makes the cache a rolling buffer of ``window`` slots: the
-    scan carry is O(w) instead of O(total), the serving memory the
-    window exists for. Rolling containment IS the window mask — slot
-    contents are exactly the positions (t-w, t], so the only masking
-    left is "slot not yet filled" during the first w steps."""
-    cache_len = min(cfg.window, total) if cfg.window else total
-    return _rolls(cfg, cache_len), cache_len
-
-
 def decode_caches(caches: Params, *, cfg: TransformerConfig, p_len: int,
                   total: int, kv_q8: bool = False) -> Params:
     """:func:`prefill`'s caches (of a ``p_len`` prompt, padded to
@@ -929,35 +658,12 @@ def decode_caches(caches: Params, *, cfg: TransformerConfig, p_len: int,
     ``kv_q8`` (int8 rows, ``L{i}_{k,v}s`` scales) and folded into the
     rolling layout where the window is shorter than ``total``. Latent
     caches are scanned as prefill lays them out."""
-    if cfg.latent is not None:
-        if kv_q8:
-            raise ValueError("kv_q8 quantizes grouped-query caches; the "
-                             "latent cache has no int8 form")
-        return caches
-    roll, cache_len = _cache_shape(cfg, total)
-    caches = {n: jnp.transpose(c, (0, 2, 1, 3)) for n, c in caches.items()}
-    if kv_q8:
-        quant = {}
-        for n, c in caches.items():
-            quant[n], quant[n + "s"] = quantize_kv(c)
-        caches = quant
-    if not roll:
-        return caches
-    # fold the prompt cache into the rolling layout: slot j holds the
-    # LAST prompt position ≡ j (mod w). Scale entries (kv_q8) are
-    # (B, H_kv, S) — same slot axis, same fold.
-    if p_len >= cache_len:
-        j = jnp.arange(cache_len)
-        src = p_len - 1 - ((p_len - 1 - j) % cache_len)
-        return {n: c[:, :, src] for n, c in caches.items()}
-    # positions 0..p_len-1 land in slots 0..p_len-1 and the prefill
-    # cache is already zero-padded beyond them — a plain truncation IS
-    # the rolling layout
-    return {n: c[:, :, :cache_len] for n, c in caches.items()}
+    return _layer_caches(cfg, lambda kind, p: kind.scanned(
+        p, caches, p_len, total, kv_q8))
 
 
 def _decode_step(params: Params, cfg: TransformerConfig, b: int,
-                 cache_len: int, kv_q8: bool, select, feed, stats: bool):
+                 kv_q8: bool, select, feed, stats: bool):
     """The scan body of every decode: ``step((caches, cur), t)`` feeds
     ``feed(t, cur)`` at position ``t``, writes the position's cache
     rows, attends the cache, and selects the next token. Caches are the
@@ -966,10 +672,6 @@ def _decode_step(params: Params, cfg: TransformerConfig, b: int,
     (``selected``, -1 where the query saw fewer), and from each grouped
     expert layer ``held_assignments``, ``experts_touched`` and the
     experts every token was routed to (``experts``)."""
-    h, hd = cfg.n_heads, head_dim(cfg)
-    hkv = kv_heads(cfg)
-    g = h // hkv            # query heads per kv head (1 = plain MHA)
-    roll = _rolls(cfg, cache_len)
     # the switch router's per-step routing group = B tokens; clamp
     # dispatch capacity to it
     step_cfg = (dataclasses.replace(cfg, moe_capacity=min(cfg.moe_capacity,
@@ -986,17 +688,11 @@ def _decode_step(params: Params, cfg: TransformerConfig, b: int,
             x = x[:, None, :]                               # (B, 1, D)
         selected, moe_stats = [], []
         for i in range(cfg.n_layers):
-            pfx = f"L{i}"
-            with scope("lm.attn"):
-                if cfg.latent is not None:
-                    caches, x, idx = step_latent(caches, x, t, pfx)
-                    selected.append(idx[:, 0])
-                else:
-                    caches, x = step_attn(caches, x, t, pfx)
-            with scope("lm.ffn"):
-                y = _norm(params, f"{pfx}_ln2", x, cfg)
-                ff, _ = _ffn(params, i, y, step_cfg, None, moe_stats)
-                x = x + ff
+            x, _, (caches, idx) = _layer(
+                params, i, x, step_cfg, lambda kind, p, y: kind.step(
+                    params, p, y, t, caches, kv_q8), None, moe_stats)
+            if idx is not None:
+                selected.append(idx[:, 0])
         with scope("lm.head"):
             x = _norm(params, "lnf", x, cfg)
             logits = _head(params, x)[:, 0]             # (B, vocab)
@@ -1009,82 +705,24 @@ def _decode_step(params: Params, cfg: TransformerConfig, b: int,
             out["selected"] = jnp.stack(selected)
         return (caches, nxt), (nxt, out)
 
-    def step_latent(caches, x, t, pfx):
-        """One layer's latent attention at position ``t``: write this
-        position's latent row and indexer key, then attend."""
-        y = _norm(params, f"{pfx}_ln1", x, cfg)
-        ckv_row, ik_row = _latent_rows(params, pfx, y, t[None], cfg)
-        ckv = lax.dynamic_update_slice(caches[f"{pfx}_ckv"], ckv_row,
-                                       (0, t, 0))
-        ik = lax.dynamic_update_slice(caches[f"{pfx}_ik"], ik_row, (0, t, 0))
-        caches = {**caches, f"{pfx}_ckv": ckv, f"{pfx}_ik": ik}
-        a, idx = _latent_attend(params, pfx, y, t[None], ckv, ik, cfg)
-        return caches, x + a, idx
-
-    def step_attn(caches, x, t, pfx):
-        """One layer's attention at position ``t``: project, write this
-        position's cache row, attend the cache, project out."""
-        y = _norm(params, f"{pfx}_ln1", x, cfg)
-        qkv = _mm(params, f"{pfx}_qkv_W", y)
-        q = qkv[..., :h * hd].reshape(b, 1, h, hd)
-        k = qkv[..., h * hd:(h + hkv) * hd].reshape(b, 1, hkv, hd)
-        v = qkv[..., (h + hkv) * hd:].reshape(b, 1, hkv, hd)
-        if cfg.rope:
-            # rotate THIS position; cache stores rotated keys (the
-            # same convention the prefill capture uses)
-            q = _rope(q, t[None], cfg.rope_base)
-            k = _rope(k, t[None], cfg.rope_base)
-        # (B, 1, Hkv, D) → (B, Hkv, 1, D) cache-layout row
-        k = jnp.transpose(k, (0, 2, 1, 3))
-        v = jnp.transpose(v, (0, 2, 1, 3))
-        # head index = (kv head, group member), kv-head major —
-        # the grouping decode_attention's (B, Hkv, G, D) q expects
-        q = q.reshape(b, hkv, g, hd)
-        slot = t % cache_len if roll else t
-        scales = {}
-        if kv_q8:
-            k, ks_row = quantize_kv(k)
-            v, vs_row = quantize_kv(v)
-            cks = lax.dynamic_update_slice(
-                caches[f"{pfx}_ks"], ks_row, (0, 0, slot))
-            cvs = lax.dynamic_update_slice(
-                caches[f"{pfx}_vs"], vs_row, (0, 0, slot))
-            caches = {**caches, f"{pfx}_ks": cks, f"{pfx}_vs": cvs}
-            scales = {"k_scale": cks, "v_scale": cvs}
-        ck = lax.dynamic_update_slice(
-            caches[f"{pfx}_k"], k, (0, 0, slot, 0))
-        cv = lax.dynamic_update_slice(
-            caches[f"{pfx}_v"], v, (0, 0, slot, 0))
-        caches = {**caches, f"{pfx}_k": ck, f"{pfx}_v": cv}
-        # fused decode attention (ops/decode.py): flash-decode
-        # kernel on TPU, the identical einsum+mask+softmax
-        # composition elsewhere. A cache that does not roll holds the
-        # whole decode inside the window, so slot<=t IS the mask.
-        a = decode_attention(q, ck, cv, t, roll=roll,
-                             backend="auto", **scales)
-        a = a.astype(x.dtype).reshape(b, 1, h * hd)
-        return caches, x + _mm(params, f"{pfx}_out_W", a)
-
     return step
 
 
 def _scan_from(params: Params, caches: Params, first_ids, start, n_new: int,
                *, cfg: TransformerConfig, kv_q8: bool, select,
-               stats: bool = False):
+               stats: bool = False, feed=lambda t, cur: cur):
     """``n_new`` positions from ``start`` over caches in the decode
     layout: ``first_ids`` (B,) is fed at ``start``, each later position
-    the token selected before it. Returns (tokens (n_new, B), caches,
-    per-step counters or None)."""
-    step = _decode_step(params, cfg, first_ids.shape[0],
-                        _cache_len(caches, cfg), kv_q8, select,
-                        lambda t, cur: cur, stats)
+    the token selected before it (or what ``feed(t, selected before)``
+    says). Returns (tokens (n_new, B), caches, per-step counters or
+    None)."""
+    step = _decode_step(params, cfg, first_ids.shape[0], kv_q8, select,
+                        feed, stats)
     with scope("lm.decode"):
         (caches, _), ys = lax.scan(step, (caches, first_ids.astype(jnp.int32)),
                                    start + jnp.arange(n_new))
-    if stats:
-        tokens, counters = ys
-        return tokens, caches, counters
-    return ys, caches, None
+    tokens, counters = ys if stats else (ys, None)
+    return tokens, caches, counters
 
 
 @functools.partial(
@@ -1209,11 +847,10 @@ def greedy_decode(params: Params, prompt, n_new: int, *,
                          "empty continuation)")
     total = p_len + n_new
     _check_seq(total, cfg)
-    _, cache_len = _cache_shape(cfg, total)
 
+    if use_prefill and n_new == 0:
+        return prompt.astype(jnp.int32)
     if use_prefill:
-        if n_new == 0:
-            return prompt.astype(jnp.int32)
         with scope("lm.prefill"):
             caches, last_logits = prefill(
                 params, prompt, cfg=cfg, total=total, mesh=mesh, attn=attn,
@@ -1227,42 +864,22 @@ def greedy_decode(params: Params, prompt, n_new: int, *,
                                    cfg=cfg, kv_q8=kv_q8, select=select)
         gen = jnp.concatenate(
             [tok1[:, None], jnp.transpose(emitted, (1, 0))], axis=1)
-        return jnp.concatenate([prompt.astype(jnp.int32), gen], axis=1)
-
-    # from scratch: empty caches in the decode layout — (B, H_kv, S, D),
-    # per-(batch, head) rows contiguous, the ops/decode.py layout
-    # contract; ``kv_q8`` stores them int8 with per-row f32 scales
-    # (ops/decode.quantize_kv), each row quantized as it is written
-    dtype = params["tok_emb"].dtype
-    if cfg.latent is not None:
-        caches = decode_caches(_empty_latent_caches(cfg, b, total, dtype),
-                               cfg=cfg, p_len=0, total=total,
-                               kv_q8=kv_q8)       # refuses kv_q8
     else:
-        hkv, hd = kv_heads(cfg), head_dim(cfg)
-        caches = {
-            f"L{i}_{kv}": jnp.zeros((b, hkv, cache_len, hd),
-                                    jnp.int8 if kv_q8 else dtype)
-            for i in range(cfg.n_layers) for kv in ("k", "v")
-        }
-        if kv_q8:
-            caches.update({
-                f"L{i}_{kv}s": jnp.zeros((b, hkv, cache_len), jnp.float32)
-                for i in range(cfg.n_layers) for kv in ("k", "v")
-            })
-    # position t reads its input from `prompt` while t < p_len, else the
-    # previously generated token riding the carry
-    pad = jnp.zeros((b, total - p_len), jnp.int32)
-    given = jnp.concatenate([prompt.astype(jnp.int32), pad], axis=1)
-    step = _decode_step(
-        params, cfg, b, cache_len, kv_q8, select,
-        lambda t, cur: jnp.where(t < p_len, given[:, t], cur), False)
-    with scope("lm.decode"):
-        (_, _), emitted = lax.scan(step, (caches, given[:, 0]),
-                                   jnp.arange(total))
-    # emitted[t] is the model's prediction AFTER seeing position t;
+        # from scratch: empty caches, each row written (and under
+        # ``kv_q8`` quantized) as its position is scanned
+        caches = _layer_caches(cfg, lambda kind, p: kind.empty(
+            p, b, total, params["tok_emb"].dtype, kv_q8))
+        # position t reads its input from `prompt` while t < p_len, else
+        # the previously generated token riding the carry
+        pad = jnp.zeros((b, total - p_len), jnp.int32)
+        given = jnp.concatenate([prompt.astype(jnp.int32), pad], axis=1)
+        emitted, _, _ = _scan_from(
+            params, caches, given[:, 0], 0, total, cfg=cfg, kv_q8=kv_q8,
+            select=select,
+            feed=lambda t, cur: jnp.where(t < p_len, given[:, t], cur))
+        # emitted[t] is the model's prediction AFTER seeing position t
+        gen = jnp.transpose(emitted, (1, 0))[:, p_len - 1:total - 1]
     # output = prompt ‖ generated continuation
-    gen = jnp.transpose(emitted, (1, 0))[:, p_len - 1:total - 1]
     return jnp.concatenate([prompt.astype(jnp.int32), gen], axis=1)
 
 
@@ -1272,11 +889,9 @@ def transformer_apply(params: Params, tokens, *,
     """Single-device oracle: (B, L) tokens → (B, L, vocab) logits."""
     _check_seq(tokens.shape[1], cfg)
     pos = jnp.arange(tokens.shape[1])
-    logits, _ = _forward(params, tokens, pos, cfg,
-                         functools.partial(attention_reference,
-                                           causal=True,
-                                           window=cfg.window))
-    return logits
+    return _forward(params, tokens, pos, cfg,
+                    functools.partial(attention_reference, causal=True,
+                                      window=cfg.window))[0]
 
 
 def _attn_shard_fn(attn: str, sp_axis: str, n_sp: int,
@@ -1403,8 +1018,8 @@ def lm_loss_local(params, tokens, targets, cfg, attn_fn, pos, block=None):
     the shift crosses shard edges, so it happens host-side before
     sharding)."""
     with scope("lm.loss"):
-        logits, aux = _forward(params, tokens, pos, cfg, attn_fn,
-                               block=block)
+        logits, aux, _ = _forward(params, tokens, pos, cfg, attn_fn,
+                                  block=block)
         with scope("lm.head"):
             nll = _mean_nll(logits, targets)
         return nll + cfg.moe_aux_weight * aux
@@ -1869,11 +1484,9 @@ def _block_stacked(w: Params, x, cfg: TransformerConfig, pos):
     Delegates to _block so the pipeline computes EXACTLY the model the
     oracle it is golden-diffed against computes."""
     prefixed = {f"L0_{k}": v for k, v in w.items()}
-    out, _aux = _block(prefixed, 0, x, cfg,
-                       functools.partial(attention_reference,
-                                         causal=True,
-                                         window=cfg.window), pos)
-    return out
+    return _block(prefixed, 0, x, cfg,
+                  functools.partial(attention_reference, causal=True,
+                                    window=cfg.window), pos)[0]
 
 
 def make_train_step_pp(cfg: TransformerConfig, mesh, optimizer, *,

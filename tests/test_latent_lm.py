@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from lua_mapreduce_tpu.models import attention_kinds as kinds
 from lua_mapreduce_tpu.models import transformer as tfm
 from lua_mapreduce_tpu.ops import sparse_mla
 from lua_mapreduce_tpu.parallel import moe
@@ -152,19 +153,6 @@ def test_routing_miss_by_hand():
     mine = np.array([[[[0, 1, 2], [3, 4, 5]]]])
     theirs = np.array([[[[2, 1, 0], [3, 9, 8]]]])
     assert ref.routing_miss(mine, theirs) == pytest.approx(2 / 6)
-
-
-def test_greedy_decode_runs_the_model_both_ways():
-    cfg = published()
-    pcfg = dsv32_program_config(cfg)
-    params = params_of(cfg)
-    prompt = jnp.asarray(ids(2, 12, cfg["vocab_size"]))
-    stepped = tfm.greedy_decode(params, prompt, 6, cfg=pcfg)
-    fast = tfm.greedy_decode(params, prompt, 6, cfg=pcfg, use_prefill=True)
-    assert np.array_equal(np.asarray(stepped), np.asarray(fast))
-    full = tfm.transformer_apply(params, fast[:, :-1], cfg=pcfg)
-    assert np.array_equal(np.asarray(jnp.argmax(full[:, 11:], -1)),
-                          np.asarray(fast[:, 12:]))
 
 
 @pytest.mark.parametrize("batch", [1, 4], ids=["few-rows", "many-rows"])
@@ -350,8 +338,8 @@ def test_decode_from_after_prefill_is_greedy_decode(cfg, kv_q8):
     if cfg.window == 32:
         # one rule says whether a cache rolls (`_rolls`): a window as long
         # as the whole decode rolls with p mod window = p, from scratch too
-        assert tfm._cache_shape(cfg, 32) == (True, 32)
-        assert tfm._cache_shape(cfg, 24) == (False, 24)
+        assert kinds._cache_shape(cfg, 32) == (True, 32)
+        assert kinds._cache_shape(cfg, 24) == (False, 24)
         assert np.array_equal(np.asarray(tfm.greedy_decode(
             params, prompt, 12, cfg=cfg)), want)
 
@@ -392,7 +380,7 @@ def test_what_is_served_only_says_so_where_it_is_trained():
 def test_yarn_frequencies_are_the_references():
     pcfg = dsv32_program_config(TINY)
     np.testing.assert_allclose(
-        tfm._yarn_freqs(pcfg.latent, pcfg.rope_base),
+        kinds._yarn_freqs(pcfg.latent, pcfg.rope_base),
         ref.yarn_frequencies(ref.Dims.of(TINY)), rtol=1e-6)
     real = dict(TINY, qk_rope_head_dim=64, rope_scaling=dict(
         TINY["rope_scaling"], original_max_position_embeddings=4096))
