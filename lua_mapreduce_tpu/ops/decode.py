@@ -1,30 +1,41 @@
 """Fused decode attention — one query position against the KV cache.
 
-The serving-side gap DESIGN §13 quantifies: the decode scan's per-step
-attention reads the ENTIRE padded cache (0.5 GB at the bench shape)
-through an XLA einsum+mask+softmax+einsum chain shaped badly for the
-TPU — a (B, 1) query has no q axis to tile onto the MXU, the mask and
-f32 score row materialize per step, and slots beyond the current
-position are streamed only to be masked. This kernel is the
-flash-decode form of §9's playbook: stream the cache ONCE through VMEM
-in (block_s, D) tiles, fold scores into an online-softmax accumulator,
-and — because the grid's chunk axis is driven by a SCALAR-PREFETCHED
-position ``t`` — clamp dead chunks onto the live range so their DMAs
-are elided entirely (the §9 dead-tile trick, dynamic this time).
-Cache traffic per step drops from O(S) to O(t), and the masked-score
-materialization disappears.
+A decode step's attention is a stream: a (B, 1) query has no q axis to
+tile onto the MXU, so what the call costs is reading the cache once.
+This kernel is the flash-decode form: stream the cache through VMEM in
+tiles, fold scores into an online-softmax accumulator, and — because
+the grid's chunk axis is driven by a SCALAR-PREFETCHED position ``t`` —
+clamp dead chunks onto the live range so their DMAs are elided entirely
+(the dead-tile trick of ``ops/attention.py``, dynamic this time). Cache
+traffic per step is O(t), not O(S), and no masked score row or
+dequantized tile materializes.
+
+The tiling rule (``_tiles``; PERF.md section 6, PR 31): a grid step
+costs the v5e about 0.3 us whatever it carries, so a step carries
+``r`` (batch, kv-head) rows by ``block_s`` positions, a K and a V tile
+of about a megabyte each, and not the 128 KB of one row's 512 positions
+it carried before: at the two decode cells' shapes (bf16, D 128: 256
+rows of 512 positions, 64 rows of 4096) one row a step read 152 and 282
+us a call, 54% and 58% of the cache's bytes at 819 GB/s; eight rows a
+step read 90 and 179 us, 91% and 92%, which is where XLA's own
+composition of the same call stands with the cache full (91 and 181
+us). Four rows a step already reach it; sixteen and thirty-two lose a
+percent again; chunks longer than 512 buy what rows buy and no more, so
+the chunk stays at the elision's granularity wherever rows can fill
+the tile. The body's masks and the pass that zeroes a ragged chunk's V
+rows showed nothing on the clock at any tiling (the copy hides them);
+the V pass is compiled only for a cache whose length is ragged.
 
 Layout contract: callers hold decode caches as (B, H_kv, S, D) — the
 per-(batch, head) cache rows are contiguous, so the kernel (and XLA)
-stream them without a per-step transpose. ``models/transformer.py``'s
-``greedy_decode`` owns that layout; its public ``prefill`` contract
-stays (B, S, H_kv, D) and is transposed ONCE at the boundary.
+stream them without a per-step transpose, and ``r`` rows of one chunk
+are ``r`` contiguous runs. ``models/transformer.py``'s ``greedy_decode``
+owns that layout; its public ``prefill`` contract stays (B, S, H_kv, D)
+and is transposed ONCE at the boundary.
 
-The XLA path reproduces the previous in-scan composition
-operation-for-operation (same dot dtypes, same f32 softmax, same
-where-mask), so ``backend="xla"`` — the off-TPU resolution — is
-bit-identical to the code it replaced and every token-exactness pin
-keeps meaning what it meant.
+The XLA path is the reference composition (same dot dtypes, same f32
+softmax, same where-mask), so ``backend="xla"`` — the off-TPU
+resolution — is what every token-exactness pin is held against.
 """
 
 from __future__ import annotations
@@ -43,8 +54,8 @@ _NEG_INF = -1e30
 
 
 def _rows(scr):
-    """(G, _LANES) lane-replicated scratch → (G, 1) row values (lanes
-    all equal; max is exact — §9's row-state convention)."""
+    """(r, G, _LANES) lane-replicated scratch → (r, G, 1) row values
+    (lanes all equal; max is exact — §9's row-state convention)."""
     return jnp.max(scr[...], axis=-1, keepdims=True)
 
 
@@ -104,20 +115,97 @@ def _decode_xla(q, k, v, t, roll: bool, k_scale=None, v_scale=None):
                       preferred_element_type=jnp.float32)
 
 
+# A grid step costs about 0.3 us on the v5e whatever it carries (two DMAs
+# started and waited for, the body's fixed work), so a step carries a K
+# and a V tile of about a megabyte each: 2.6 us of copy at 819 GB/s.
+_TILE_BYTES = 1 << 20
+# unrolled copies of the body's two matmuls in one step
+_MAX_ROWS = 16
+# what the kernel may hold in VMEM (the v5e's default scoped limit, said
+# out loud so that every generation compiles the same tiling), and the
+# part of it `_tiles` lets `_vmem_bytes` reach: the rest is the
+# compiler's own (spills, the matmuls' staging)
+_VMEM_LIMIT = 16 << 20
+_VMEM_BUDGET = _VMEM_LIMIT * 3 // 4
+
+
+def _pad(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+def _vmem_bytes(r: int, block_s: int, d: int, itemsize: int, g: int,
+                q8: bool) -> int:
+    """VMEM one grid step of ``_decode_kernel`` holds at ``r`` rows and
+    ``block_s`` positions a step, counted as Mosaic lays it out: the
+    last dim padded to 128 lanes, the one before to a 32-byte sublane
+    group (8 float32, 16 bfloat16, 32 int8). Every block is double-
+    buffered by the pipeline; the body's temporaries are the float32
+    score rows and, with int8 caches, the bfloat16 copies of both
+    tiles."""
+    lanes = _pad(d, 128)
+    tile = r * _pad(block_s, 32 // itemsize) * lanes * itemsize
+    q_out = r * lanes * (_pad(g, 16) * 2 + _pad(g, 8) * 4)  # bf16 q, f32 out
+    total = 2 * (2 * tile + q_out)
+    scores = r * _pad(g, 8) * _pad(block_s, 128) * 4
+    total += 4 * scores                             # s, p, masks
+    total += r * _pad(g, 8) * (lanes + 2 * 128) * 4  # acc, m, l
+    if q8:
+        # (r, 1, block_s) float32 scales: one sublane used of eight
+        total += 2 * 2 * r * 8 * _pad(block_s, 128) * 4
+        total += 2 * r * _pad(block_s, 16) * lanes * 2
+    return total
+
+
+def _tiles(rows: int, s_len: int, d: int, itemsize: int, g: int,
+           q8: bool, block_s: int = 512) -> tuple:
+    """How much cache one grid step carries: ``(r, block_s)``, blocks
+    of ``r`` (batch, kv-head) rows by ``block_s`` positions. A pure
+    function of what the call can see (never of ``t``, which is
+    traced).
+
+    ``block_s`` comes in as the chunk asked for (the granularity of the
+    dead-chunk elision) and is first cut to the cache's own length.
+    ``r`` is the largest divisor of ``rows`` whose K tile stays within
+    ``_TILE_BYTES`` and whose step fits ``_VMEM_BUDGET``. Where rows
+    alone leave the tile under half the target (few rows, or a prime
+    number of them) the chunk doubles while it stays within an eighth
+    of the row: a longer chunk reads up to one chunk more of dead cache
+    at small ``t``, half a chunk on average, so at most a sixteenth of
+    the row. ``(1, block_s)``, the tiling before rows were grouped, is
+    the answer for a shape that fits nothing else."""
+    block_s = min(block_s, max(128, _pad(s_len, 128)))
+
+    def ok(r, bs):
+        return (r * bs * d * itemsize <= _TILE_BYTES
+                and _vmem_bytes(r, bs, d, itemsize, g, q8) <= _VMEM_BUDGET)
+
+    r = max(x for x in range(1, min(rows, _MAX_ROWS) + 1)
+            if rows % x == 0 and (x == 1 or ok(x, block_s)))
+    while 2 * block_s * 8 <= s_len and ok(r, 2 * block_s):
+        block_s *= 2
+    return r, block_s
+
+
 def _decode_kernel(t_ref, q_ref, k_ref, v_ref, *rest,
                    block_s, s_len, scale, roll, n_chunks, q8):
-    """One (batch·kv-head) row: fold cache chunk ``ki`` into the
-    online-softmax state. Row state is lane-replicated (G, _LANES)
-    per §9's Mosaic legality rule. With ``q8``, k/v arrive int8 and
-    two extra (block_s, 1) scale refs follow — the k scale multiplies
-    score COLUMNS after the dot, the v scale folds into p before the
-    value dot, so no dequantized tile ever materializes."""
+    """``r`` (batch·kv-head) rows: fold cache chunk ``ki`` of each into
+    its online-softmax state, both contractions batched over the rows.
+    Row state is lane-replicated (r, G, _LANES) per §9's Mosaic
+    legality rule. With ``q8``, k/v arrive int8 and two extra
+    (r, 1, block_s) scale refs follow, positions on the lanes as the
+    scores have them — the k scale multiplies score COLUMNS after the
+    dot, the v scale folds into p before the value dot, so no
+    dequantized tile ever materializes."""
     if q8:
         ks_ref, vs_ref, o_ref, acc, m_scr, l_scr = rest
     else:
         o_ref, acc, m_scr, l_scr = rest
     ki = pl.program_id(1)
     t = t_ref[0]
+    # the final block of a cache that is no multiple of block_s hangs
+    # over its end: known statically, so only such a cache pays for the
+    # pass over the V tile (no cell's does)
+    ragged = s_len % block_s != 0
 
     @pl.when(ki == 0)
     def _():
@@ -125,60 +213,59 @@ def _decode_kernel(t_ref, q_ref, k_ref, v_ref, *rest,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc[...] = jnp.zeros_like(acc)
 
+    # chunks that start past t are dead: their DMAs are elided by the
+    # index map, their compute by this guard
     @pl.when(ki * block_s <= t)
     def _():
-        q = q_ref[0]                                   # (G, D)
-        k = k_ref[0]                                   # (block_s, D)
-        v = v_ref[0]                                   # (block_s, D)
+        q = q_ref[...]                                 # (r, G, D)
+        k = k_ref[...]                                 # (r, block_s, D)
+        v = v_ref[...]                                 # (r, block_s, D)
         if q8:
             # int8 rows are exact in bf16 (integers ≤ 256): the dot is
             # the exact product-accumulation, scales restore magnitude
             q = q.astype(jnp.bfloat16)
             k = k.astype(jnp.bfloat16)
+            v = v.astype(jnp.bfloat16)
+            vs = vs_ref[...]                           # (r, 1, block_s)
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)          # (G, block_s)
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)          # (r, G, block_s)
         if q8:
-            s = s * ks_ref[0][:, 0][None, :]
+            s = s * ks_ref[...]
         s = s * scale
         col = ki * block_s + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        vis = col < s_len
+            jnp.int32, (1, 1, block_s), 2)
         live = (col <= t) | (t >= s_len) if roll else (col <= t)
-        s = jnp.where(vis & live, s, _NEG_INF)
-        # ragged final block: out-of-bounds v rows hold unspecified
-        # values (NaN in interpret mode); their p weight is exp(-inf)=0
-        # but 0·NaN = NaN, so the rows must be zeroed before the dot
-        row = ki * block_s + jax.lax.broadcasted_iota(
-            jnp.int32, (block_s, 1), 0)
-        v = jnp.where(row < s_len, v, 0).astype(v.dtype)
+        if ragged:
+            # out-of-bounds v rows (and scale lanes) hold unspecified
+            # values (NaN in interpret mode); their p weight is
+            # exp(-inf)=0 but 0·NaN = NaN, so they are zeroed before
+            # the dot
+            live &= col < s_len
+            row = ki * block_s + jax.lax.broadcasted_iota(
+                jnp.int32, (1, block_s, 1), 1)
+            v = jnp.where(row < s_len, v, 0).astype(v.dtype)
+            if q8:
+                vs = jnp.where(col < s_len, vs, 0.0)
+        s = jnp.where(live, s, _NEG_INF)
 
-        m_prev = _rows(m_scr)                          # (G, 1)
+        m_prev = _rows(m_scr)                          # (r, G, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                         # (G, block_s)
-        l_prev = _rows(l_scr)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        p = jnp.exp(s - m_new)                         # (r, G, block_s)
+        l_new = _rows(l_scr) * alpha + jnp.sum(p, axis=-1, keepdims=True)
         if q8:
-            # OOB scale lanes are unspecified like OOB v rows — zero
-            # them for the same 0·NaN reason
-            vs = jnp.where(row[:, 0] < s_len, vs_ref[0][:, 0], 0.0)
-            p = p * vs[None, :]
-            pv = jax.lax.dot_general(
-                p.astype(jnp.bfloat16), v.astype(jnp.bfloat16),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-        else:
-            pv = jax.lax.dot_general(
-                p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            p = p * vs
+        pv = jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)          # (r, G, D)
         acc[...] = acc[...] * alpha + pv
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
     @pl.when(ki == n_chunks - 1)
     def _():
-        o_ref[0] = acc[...] / jnp.maximum(_rows(l_scr), 1e-30)
+        o_ref[...] = acc[...] / jnp.maximum(_rows(l_scr), 1e-30)
 
 
 @functools.partial(
@@ -188,8 +275,9 @@ def _decode_pallas(q, k, v, t, roll: bool = False, block_s: int = 512,
                    interpret: bool = False, k_scale=None, v_scale=None):
     b, hkv, g, d = q.shape
     s_len = k.shape[2]
+    rows = b * hkv
     q8 = k_scale is not None
-    block_s = min(block_s, max(128, -(-s_len // 128) * 128))
+    r, block_s = _tiles(rows, s_len, d, k.dtype.itemsize, g, q8, block_s)
     # ceil-divided grid, NO padding: k/v ride the decode scan's carry,
     # so a jnp.pad here would copy the whole cache every generated
     # token — the O(S) per-step traffic this kernel exists to kill.
@@ -197,9 +285,9 @@ def _decode_pallas(q, k, v, t, roll: bool = False, block_s: int = 512,
     # lanes surface as undefined values in `s`, which the explicit
     # `col < s_len` mask sends to -inf before they touch the softmax.
     n_chunks = -(-s_len // block_s)
-    qb = q.reshape(b * hkv, g, d)
-    kb = k.reshape(b * hkv, s_len, d)
-    vb = v.reshape(b * hkv, s_len, d)
+    qb = q.reshape(rows, g, d)
+    kb = k.reshape(rows, s_len, d)
+    vb = v.reshape(rows, s_len, d)
     scale = 1.0 / float(d) ** 0.5
     tarr = jnp.asarray(t, jnp.int32).reshape(1)
 
@@ -209,42 +297,43 @@ def _decode_pallas(q, k, v, t, roll: bool = False, block_s: int = 512,
         # indices skip the copy; compute is pl.when-guarded anyway
         return jnp.minimum(ki, jnp.maximum(t_ref[0], 0) // block_s)
 
-    qspec = pl.BlockSpec((1, g, d), lambda r, ki, t_ref: (r, 0, 0),
+    qspec = pl.BlockSpec((r, g, d), lambda i, ki, t_ref: (i, 0, 0),
                          memory_space=pltpu.VMEM)
-    cspec = pl.BlockSpec((1, block_s, d),
-                         lambda r, ki, t_ref: (r, chunk(ki, t_ref), 0),
+    cspec = pl.BlockSpec((r, block_s, d),
+                         lambda i, ki, t_ref: (i, chunk(ki, t_ref), 0),
                          memory_space=pltpu.VMEM)
     in_specs = [qspec, cspec, cspec]
     operands = [tarr, qb, kb, vb]
     if q8:
-        # scales ride as (rows, S, 1) so the (block_s, 1) block keeps
-        # Mosaic's trailing-dims rule (1 == array's own trailing dim)
+        # scales ride as (rows, 1, S): positions on the lanes, where
+        # the score columns they multiply are, and one sublane of
+        # eight in VMEM where (rows, S, 1) would pad to 128 lanes
         sspec = pl.BlockSpec(
-            (1, block_s, 1),
-            lambda r, ki, t_ref: (r, chunk(ki, t_ref), 0),
+            (r, 1, block_s),
+            lambda i, ki, t_ref: (i, 0, chunk(ki, t_ref)),
             memory_space=pltpu.VMEM)
         in_specs += [sspec, sspec]
-        operands += [k_scale.reshape(b * hkv, s_len, 1),
-                     v_scale.reshape(b * hkv, s_len, 1)]
+        operands += [k_scale.reshape(rows, 1, s_len),
+                     v_scale.reshape(rows, 1, s_len)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b * hkv, n_chunks),
+        grid=(rows // r, n_chunks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, g, d), lambda r, ki, t_ref: (r, 0, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM((g, d), jnp.float32),
-                        pltpu.VMEM((g, _LANES), jnp.float32),
-                        pltpu.VMEM((g, _LANES), jnp.float32)],
+        out_specs=qspec,
+        scratch_shapes=[pltpu.VMEM((r, g, d), jnp.float32),
+                        pltpu.VMEM((r, g, _LANES), jnp.float32),
+                        pltpu.VMEM((r, g, _LANES), jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(_decode_kernel, block_s=block_s, s_len=s_len,
                           scale=scale, roll=roll, n_chunks=n_chunks,
                           q8=q8),
         grid_spec=grid_spec,
-        out_shape=out_struct((b * hkv, g, d), jnp.float32, qb, kb, vb),
+        out_shape=out_struct((rows, g, d), jnp.float32, qb, kb, vb),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
         name="_decode_pallas",
     )(*operands)

@@ -352,3 +352,35 @@ def test_the_latent_session_entry_reads_an_expert_only_where_touched(
     memory = compiled.memory_analysis()
     # (the chip's tiling pads the rows a little)
     assert memory.alias_size_in_bytes >= 8 * 32832 * (576 + 128) * 2
+
+
+# --------------------------------------------------------------------------
+# the flash-decode kernel alone (PR 31): Mosaic compiles the tiling that
+# `ops/decode._tiles` chose inside the VMEM limit the call hands it, which
+# neither interpret mode nor `jax.export`'s lowering can see
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("shape,q8,roll", [
+    ((32, 8, 4, 128, 512), False, False),       # the chat cell
+    ((8, 8, 4, 128, 4096), False, True),        # long context, rolling
+    ((32, 8, 4, 128, 512), True, False),        # int8 caches, 16 rows a step
+    ((8, 8, 4, 128, 4096), True, True),
+    ((4, 16, 1, 64, 4096), True, False),        # head width 64 pads to 128
+    ((1, 17, 4, 128, 32768), False, False),     # one row, chunks of 4096
+    ((2, 2, 8, 64, 300), True, False),          # a ragged only chunk
+])
+def test_the_decode_kernel_compiles_inside_its_vmem_limit(
+        topo, chip_policy, shape, q8, roll):
+    from jax.sharding import SingleDeviceSharding
+    from lua_mapreduce_tpu.ops.decode import _decode_pallas
+    one = SingleDeviceSharding(topo.devices[0])
+    b, hkv, g, d, s_len = shape
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one)
+    cache = arg((b, hkv, s_len, d), jnp.int8 if q8 else jnp.bfloat16)
+    scales = {"k_scale": arg((b, hkv, s_len), jnp.float32),
+              "v_scale": arg((b, hkv, s_len), jnp.float32)} if q8 else {}
+    text = _decode_pallas.lower(
+        arg((b, hkv, g, d), jnp.bfloat16), cache, cache,
+        arg((), jnp.int32), roll=roll, **scales).compile().as_text()
+    assert "_decode_pallas" in text and "tpu_custom_call" in text

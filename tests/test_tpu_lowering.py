@@ -188,7 +188,10 @@ class TestDecodeLowering:
 
     @pytest.mark.parametrize("shape", [(4, 16, 1, 64, 4096),
                                        (4, 4, 4, 128, 4096),
-                                       (2, 2, 8, 64, 300)])
+                                       (2, 2, 8, 64, 300),
+                                       # the two decode cells, exactly
+                                       (32, 8, 4, 128, 512),
+                                       (8, 8, 4, 128, 4096)])
     def test_decode_kernel(self, shape):
         from lua_mapreduce_tpu.ops.decode import _decode_pallas
 
@@ -199,12 +202,15 @@ class TestDecodeLowering:
         export_tpu(lambda q_, k_, v_, t_: _decode_pallas(q_, k_, v_, t_),
                    q, kv, kv, t)
 
-    def test_decode_kernel_q8(self):
+    @pytest.mark.parametrize("shape", [(4, 16, 1, 64, 4096),
+                                       (8, 8, 4, 128, 4096)])
+    def test_decode_kernel_q8(self, shape):
         from lua_mapreduce_tpu.ops.decode import _decode_pallas
 
-        q = jax.ShapeDtypeStruct((4, 16, 1, 64), jnp.bfloat16)
-        kv = jax.ShapeDtypeStruct((4, 16, 4096, 64), jnp.int8)
-        sc = jax.ShapeDtypeStruct((4, 16, 4096), jnp.float32)
+        b, hkv, g, d, s_len = shape
+        q = jax.ShapeDtypeStruct((b, hkv, g, d), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((b, hkv, s_len, d), jnp.int8)
+        sc = jax.ShapeDtypeStruct((b, hkv, s_len), jnp.float32)
         t = jax.ShapeDtypeStruct((), jnp.int32)
         export_tpu(lambda q_, k_, v_, ks_, vs_, t_: _decode_pallas(
             q_, k_, v_, t_, k_scale=ks_, v_scale=vs_),
