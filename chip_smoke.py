@@ -345,6 +345,15 @@ def _kernel_cases(*, lm, train, decode, pool_shape) -> dict:
         return lambda backend: (ops.decode_attention(
             q, k, v, t, backend=backend, **scales),)
 
+    def latent_decode():
+        # a latent cache's row [c_kv | k_rope]: 576 wide, the first 512
+        # the values (ops/mla_decode.py), every head over the one row
+        b, s = decode["batch"], decode["max_seq"]
+        q, rows = normal(9, (b, h, 576)), normal(10, (b, s, 576))
+        t = jnp.int32(decode["prompt_len"] + decode["n_new"] // 2)
+        return lambda backend: (ops.mla_decode_attention(
+            q, rows, t, v_rank=512, scale=576 ** -0.5, backend=backend),)
+
     def q8(m, kdim, n):
         x = normal(6, (m, kdim))
         w, s = ops.quantize_q8(
@@ -371,6 +380,9 @@ def _kernel_cases(*, lm, train, decode, pool_shape) -> dict:
             (f"mha int8 cache {decode['max_seq']}", decode_attn(h, True)),
             (f"gqa4 bf16 cache {decode['max_seq']}",
              decode_attn(h // 4, False)),
+        ],
+        "mla_decode_attention": [
+            (f"h{h} bf16 cache {decode['max_seq']} x 576", latent_decode()),
         ],
         "q8_matmul": [
             (f"decode qkv {db}x{d}x{qkv}", q8(db, d, qkv)),

@@ -36,15 +36,12 @@ from jax import lax
 
 from lua_mapreduce_tpu.ops import sparse_mla as _sparse
 from lua_mapreduce_tpu.ops.decode import decode_attention, quantize_kv
+from lua_mapreduce_tpu.ops.mla_decode import (mla_causal_attention,
+                                              mla_decode_attention)
 from lua_mapreduce_tpu.ops.q8 import q8_matmul
 from lua_mapreduce_tpu.utils.profiling import scope
 
 Params = Dict[str, jnp.ndarray]
-
-# queries of a latent-attention forward over a full sequence, all rows of
-# the batch together, that meet the cache at a time: the indexer's
-# (queries, heads, keys) scores exist for one such block
-_QUERY_BLOCK = 64
 
 
 def kv_heads(cfg) -> int:
@@ -302,38 +299,64 @@ class GroupedQuery(_Kind):
 
 def _no_int8(kv_q8: bool) -> None:
     if kv_q8:
-        raise ValueError("kv_q8 quantizes grouped-query caches; the "
-                         "latent cache has no int8 form")
+        raise ValueError("kv_q8 quantizes grouped-query caches; a latent "
+                         "cache has no int8 form")
+
+
+# query rows (batch x positions) of a latent-attention forward over a
+# full sequence that meet the cache at a time. With the indexer its
+# (queries, heads, keys) scores exist for one such block; without it a
+# block's queries in the latent's basis (H x R values each) and their
+# float32 sums do
+_QUERY_ROWS_INDEXED = 64
+_QUERY_ROWS_WHOLE = 2048
 
 
 class Latent(_Kind):
-    """Multi-head latent attention with the sparse attention indexer
-    (``cfg.latent``, a ``LatentAttention``): a position caches the row
-    ``[c_kv | k_rope]`` (B, S, kv_rank + rope_dim) for all heads and the
-    indexer's key (B, S, index_dim), and a query attends the rows its
-    indexer selects. The scan carries the caches as prefill hands them
-    out; there is no int8 form."""
-    leaves = ("ckv", "ik")
+    """Multi-head latent attention (``cfg.latent``, a
+    ``LatentAttention``): a position caches the row ``[c_kv | k_rope]``
+    (B, S, kv_rank + rope_dim) for all heads, and attention runs in the
+    absorbed form, where that row is key and value at once. Two parts
+    are the model's to have or to lack. A query latent (``q_rank``):
+    queries projected through a normed low-rank latent, or straight
+    from the stream. An indexer (``index_top_k``): a second cached leaf,
+    its key (B, S, index_dim), and a query attends the rows the indexer
+    selects (``ops/sparse_mla.py``); without one the cache has the one
+    leaf and a query reads every row up to its own
+    (``ops/mla_decode.py``). ``qk_norm`` is an RMSNorm of every head's
+    query before the rope. The scan carries the caches as prefill hands
+    them out, in the model's own type: no latent cache has an int8 form
+    (``kv_q8`` is refused), with or without the indexer."""
+
+    @property
+    def leaves(self) -> tuple:
+        return ("ckv", "ik") if self.cfg.latent.index_top_k else ("ckv",)
 
     def init(self, keys, dtype, p: str) -> Params:
         la, d, h = self.cfg.latent, self.cfg.d_model, self.cfg.n_heads
-        shapes = {
-            "qa_W": (d, la.q_rank),
-            "qb_W": (la.q_rank, h * (la.nope_dim + la.rope_dim)),
+        q_out = h * (la.nope_dim + la.rope_dim)
+        shapes = ({"qa_W": (d, la.q_rank), "qb_W": (la.q_rank, q_out)}
+                  if la.q_rank else {"q_W": (d, q_out)})
+        shapes.update({
             "kva_W": (d, la.kv_rank + la.rope_dim),
             "kvb_W": (la.kv_rank, h * (la.nope_dim + la.v_dim)),
-            "out_W": (h * la.v_dim, d),
-            "iq_W": (la.q_rank, la.index_heads * la.index_dim),
-            "ik_W": (d, la.index_dim),
-            "iw_W": (d, la.index_heads),
-        }
+            "out_W": (h * la.v_dim, d)})
+        if la.index_top_k:
+            shapes.update({
+                "iq_W": (la.q_rank, la.index_heads * la.index_dim),
+                "ik_W": (d, la.index_dim),
+                "iw_W": (d, la.index_heads)})
         out = {f"{p}_{n}": _dense(k, shape, dtype)
                for (n, shape), k in zip(shapes.items(), jax.random.split(
                    next(keys), len(shapes)))}
-        out[f"{p}_qa_g"] = jnp.ones((la.q_rank,), dtype)
+        if la.q_rank:
+            out[f"{p}_qa_g"] = jnp.ones((la.q_rank,), dtype)
+        if la.qk_norm:
+            out[f"{p}_q_g"] = jnp.ones((la.nope_dim + la.rope_dim,), dtype)
         out[f"{p}_kv_g"] = jnp.ones((la.kv_rank,), dtype)
-        out[f"{p}_ik_g"] = jnp.ones((la.index_dim,), dtype)
-        out[f"{p}_ik_b"] = jnp.zeros((la.index_dim,), dtype)
+        if la.index_top_k:
+            out[f"{p}_ik_g"] = jnp.ones((la.index_dim,), dtype)
+            out[f"{p}_ik_b"] = jnp.zeros((la.index_dim,), dtype)
         return out
 
     def _rope_head(self, x, pos):
@@ -344,11 +367,11 @@ class Latent(_Kind):
                        _yarn_freqs(la, base))
         return jnp.concatenate([turned, x[..., la.rope_dim:]], axis=-1)
 
-    def rows(self, params: Params, p: str, y, pos):
-        """What ``y`` (B, L, d) at ``pos`` caches: the row ``[c_kv |
-        k_rope]`` (the normed latent, the rotated rope key that all
-        heads share) and the indexer's key (LayerNorm, rope on its first
-        ``rope_dim`` values)."""
+    def rows(self, params: Params, p: str, y, pos) -> tuple:
+        """What ``y`` (B, L, d) at ``pos`` caches, leaf by leaf: the row
+        ``[c_kv | k_rope]`` (the normed latent, the rotated rope key
+        that all heads share) and, with an indexer, its key (LayerNorm,
+        rope on its first ``rope_dim`` values)."""
         cfg, la = self.cfg, self.cfg.latent
         with scope("lm.mla"):
             kv = _mm(params, f"{p}_kva_W", y)
@@ -356,6 +379,8 @@ class Latent(_Kind):
                           cfg.norm_eps)
             k_r = self._rope_head(kv[..., None, la.kv_rank:], pos)[..., 0, :]
             ckv = jnp.concatenate([c, k_r], axis=-1)
+        if not la.index_top_k:
+            return (ckv,)
         with scope("lm.indexer"):
             ik = _layer_norm(_mm(params, f"{p}_ik_W", y),
                              params[f"{p}_ik_g"], params[f"{p}_ik_b"],
@@ -363,14 +388,15 @@ class Latent(_Kind):
             ik = self._rope_head(ik[..., None, :], pos)[..., 0, :]
         return ckv, ik.astype(y.dtype)
 
-    def attend(self, params: Params, p: str, y, pos, ckv, ik):
+    def attend(self, params: Params, p: str, y, pos, ckv, ik=None):
         """Latent attention of the queries ``y`` (B, Q, d) at positions
-        ``pos`` over caches ``ckv`` and ``ik`` that hold these
-        positions' own rows already. Absorbed form: ``q_nope`` is taken
-        into the latent's basis through ``kvb_W``'s key half, the
-        selected rows are key and value at once, and the sum comes out
-        through its value half. Returns (out (B, Q, d), idx (B, Q, K)
-        selected positions, -1 where the query sees fewer than K)."""
+        ``pos`` over caches that hold these positions' own rows already.
+        Absorbed form: ``q_nope`` is taken into the latent's basis
+        through ``kvb_W``'s key half, a cached row is key and value at
+        once, and the sum comes out through its value half. Returns (out
+        (B, Q, d), the positions the indexer selected (B, Q, K), -1
+        where the query sees fewer than K; None where there is no
+        indexer and every row up to the query's own is read)."""
         cfg, la, h = self.cfg, self.cfg.latent, self.cfg.n_heads
         b, q_len, _ = y.shape
         freqs = _yarn_freqs(la, cfg.rope_base)
@@ -379,58 +405,76 @@ class Latent(_Kind):
         w_kv = params[f"{p}_kvb_W"].reshape(la.kv_rank, h,
                                             la.nope_dim + la.v_dim)
         with scope("lm.mla"):
-            c_q = _rms_norm(_mm(params, f"{p}_qa_W", y),
-                            params[f"{p}_qa_g"], cfg.norm_eps)
-            q = _mm(params, f"{p}_qb_W", c_q).reshape(
-                b, q_len, h, la.nope_dim + la.rope_dim)
+            if la.q_rank:
+                c_q = _rms_norm(_mm(params, f"{p}_qa_W", y),
+                                params[f"{p}_qa_g"], cfg.norm_eps)
+                q = _mm(params, f"{p}_qb_W", c_q)
+            else:
+                c_q, q = None, _mm(params, f"{p}_q_W", y)
+            q = q.reshape(b, q_len, h, la.nope_dim + la.rope_dim)
+            if la.qk_norm:
+                q = _rms_norm(q, params[f"{p}_q_g"], cfg.norm_eps)
             q_rope = _rope(q[..., la.nope_dim:], pos, cfg.rope_base, freqs)
             q_lat = jnp.einsum("bqhn,chn->bqhc", q[..., :la.nope_dim],
                                w_kv[..., :la.nope_dim])
             q = jnp.concatenate([q_lat, q_rope], axis=-1)
-        with scope("lm.indexer"):
-            q_i = _mm(params, f"{p}_iq_W", c_q).reshape(
-                b, q_len, la.index_heads, la.index_dim)
-            q_i = self._rope_head(q_i, pos)
-            w = _mm(params, f"{p}_iw_W", y) * float(
-                la.index_heads ** -0.5 * la.index_dim ** -0.5)
-            idx, valid = _sparse.select_top_k(
-                _sparse.index_scores(q_i, w, ik, pos), la.index_top_k)
-        with scope("lm.sparse"):
-            o_lat = _sparse.sparse_latent_attention(
-                q, ckv, idx, valid, scale=scale, v_rank=la.kv_rank)
+        if la.index_top_k:
+            with scope("lm.indexer"):
+                q_i = _mm(params, f"{p}_iq_W", c_q).reshape(
+                    b, q_len, la.index_heads, la.index_dim)
+                q_i = self._rope_head(q_i, pos)
+                w = _mm(params, f"{p}_iw_W", y) * float(
+                    la.index_heads ** -0.5 * la.index_dim ** -0.5)
+                idx, valid = _sparse.select_top_k(
+                    _sparse.index_scores(q_i, w, ik, pos), la.index_top_k)
+            with scope("lm.sparse"):
+                o_lat = _sparse.sparse_latent_attention(
+                    q, ckv, idx, valid, scale=scale, v_rank=la.kv_rank)
+        else:
+            with scope("lm.latent"):
+                if q_len == 1:      # a decode step: the flash-decode kernel
+                    o_lat = mla_decode_attention(
+                        q[:, 0], ckv, pos[0], v_rank=la.kv_rank,
+                        scale=scale, backend="auto")[:, None]
+                else:
+                    o_lat = mla_causal_attention(
+                        q, ckv, pos, v_rank=la.kv_rank, scale=scale)
         with scope("lm.mla"):
             o = jnp.einsum("bqhc,chv->bqhv", o_lat.astype(y.dtype),
                            w_kv[..., la.nope_dim:])
             out = _mm(params, f"{p}_out_W",
                       o.reshape(b, q_len, h * la.v_dim))
-        return out, jnp.where(valid, idx, -1)
+        return out, jnp.where(valid, idx, -1) if la.index_top_k else None
 
-    def attend_blocked(self, params: Params, p: str, y, pos, ckv, ik):
-        """:meth:`attend`'s output for every position of a sequence,
-        ``_QUERY_BLOCK`` queries at a time."""
+    def attend_blocked(self, params: Params, p: str, y, pos, *caches):
+        """:meth:`attend`'s output for every position of a sequence, a
+        block of queries at a time."""
         b, l, d = y.shape
-        block = min(max(1, _QUERY_BLOCK // b), l)
+        rows = (_QUERY_ROWS_INDEXED if self.cfg.latent.index_top_k
+                else _QUERY_ROWS_WHOLE)
+        block = min(max(1, rows // b), l)
         if l == block:
-            return self.attend(params, p, y, pos, ckv, ik)[0]
+            return self.attend(params, p, y, pos, *caches)[0]
         n = -(-l // block)
         pad = n * block - l             # padded queries repeat the last
         yb = jnp.pad(y, ((0, 0), (0, pad), (0, 0)), mode="edge")
         pb = jnp.pad(pos, (0, pad), mode="edge")
         out = lax.map(
-            lambda blk: self.attend(params, p, blk[0], blk[1], ckv, ik)[0],
+            lambda blk: self.attend(params, p, blk[0], blk[1], *caches)[0],
             (yb.reshape(b, n, block, d).transpose(1, 0, 2, 3),
              pb.reshape(n, block)))
         return out.transpose(1, 0, 2, 3).reshape(b, n * block, d)[:, :l]
 
     def full(self, params: Params, p: str, y, pos, attn_fn):
-        """Sparse over what it caches; ``attn_fn`` is left aside."""
+        """Over what it caches, in its own form; ``attn_fn`` is left
+        aside."""
         rows = self.rows(params, p, y, pos)
         return self.attend_blocked(params, p, y, pos, *rows), rows
 
     def _written(self, params: Params, p: str, y, pos, caches: Params,
                  start):
         """``caches`` with the rows of ``y`` at ``pos`` (from ``start``
-        on) in them, and this layer's two."""
+        on) in them, and this layer's own."""
         caches = _put(caches, dict(zip(
             self.names(p), self.rows(params, p, y, pos))), (0, start))
         return caches, [caches[name] for name in self.names(p)]
@@ -443,8 +487,8 @@ class Latent(_Kind):
              kv_q8: bool):
         """(``kv_q8`` was refused where the caches were made.)"""
         caches, mine = self._written(params, p, y, t[None], caches, t)
-        out, idx = self.attend(params, p, y, t[None], *mine)
-        return out, (caches, idx)
+        out, selected = self.attend(params, p, y, t[None], *mine)
+        return out, (caches, selected)
 
     def empty(self, p: str, b: int, total: int, dtype,
               kv_q8: bool = False) -> Params:

@@ -52,22 +52,29 @@ from lua_mapreduce_tpu.utils.profiling import annotate, scope
 
 @dataclasses.dataclass(frozen=True)
 class LatentAttention:
-    """Multi-head latent attention (DeepSeek-V2/V3) with the sparse
-    attention indexer of V3.2: queries and keys go through low-rank
-    latents, the cache holds one ``kv_rank + rope_dim`` row a token for
-    ALL heads, and a query attends the ``index_top_k`` cached rows that
-    a small indexer (``index_heads`` x ``index_dim``, its own cached
-    key a token) scores highest. ``n_heads`` of the enclosing config
-    are the query heads; ``nope_dim + rope_dim`` is a head's score
-    width and ``v_dim`` its value width."""
+    """Multi-head latent attention (DeepSeek-V2/V3 and what followed):
+    keys and values go through a low-rank latent, and the cache holds
+    one ``kv_rank + rope_dim`` row a token for ALL heads. ``n_heads`` of
+    the enclosing config are the query heads; ``nope_dim + rope_dim`` is
+    a head's score width and ``v_dim`` its value width. Two parts a
+    model may lack. ``q_rank`` > 0: queries go through a normed latent
+    of that rank; 0: ``q`` is projected straight from the stream.
+    ``index_top_k`` > 0: V3.2's sparse attention indexer
+    (``index_heads`` x ``index_dim``, its own cached key a token), a
+    query attends the ``index_top_k`` cached rows it scores highest
+    (the indexer's queries come from the query latent, so it needs
+    ``q_rank``); 0: no indexer, a query reads every cached row up to
+    its own. ``qk_norm``: an RMSNorm with a gain (``q_g``) of every
+    head's query before the rope; the key side's norm is the latent's
+    own (``kv_g``), which every such model has."""
     q_rank: int
     kv_rank: int
     nope_dim: int
     rope_dim: int
     v_dim: int
-    index_heads: int
-    index_dim: int
-    index_top_k: int
+    index_heads: int = 0
+    index_dim: int = 0
+    index_top_k: int = 0
     # YaRN on the rotary frequencies (factor 1 = plain rope) and its
     # softmax-scale correction (0.1 * mscale_all_dim * ln(factor) + 1)^2
     rope_factor: float = 1.0
@@ -75,6 +82,7 @@ class LatentAttention:
     beta_fast: float = 32.0
     beta_slow: float = 1.0
     mscale_all_dim: float = 0.0
+    qk_norm: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,7 +114,7 @@ class TransformerConfig:
     # the output head is ``tok_emb.T`` (tied) or a matrix of its own
     tied_head: bool = True
     # None = grouped-query attention over (k, v) caches; else latent
-    # attention over a latent cache, sparse by its indexer
+    # attention over a latent cache, sparse where it has an indexer
     latent: Optional[LatentAttention] = None
     # "gelu" (2-matmul MLP with biases) or "swiglu" (gate/up/down,
     # no biases — the llama-style FFN)
@@ -243,6 +251,23 @@ def _check_arch(cfg: TransformerConfig) -> None:
         if cfg.latent.rope_dim % 2 or cfg.window:
             raise ValueError("latent attention needs an even rope_dim and "
                              "takes no window")
+        la = cfg.latent
+        if la.q_rank < 0 or la.index_top_k < 0:
+            raise ValueError(f"q_rank={la.q_rank} and index_top_k="
+                             f"{la.index_top_k} are sizes or 0 (the part "
+                             f"is absent)")
+        if la.index_top_k and not (la.index_heads > 0 and la.index_dim > 0):
+            raise ValueError(f"index_top_k={la.index_top_k} needs an "
+                             f"indexer: index_heads={la.index_heads}, "
+                             f"index_dim={la.index_dim}")
+        if la.index_top_k and not la.q_rank:
+            raise ValueError(f"index_top_k={la.index_top_k} with q_rank=0: "
+                             f"the indexer's queries come from the query "
+                             f"latent")
+        if not la.index_top_k and (la.index_heads or la.index_dim):
+            raise ValueError(f"index_heads={la.index_heads} and index_dim="
+                             f"{la.index_dim} without index_top_k: an "
+                             f"indexer that selects nothing")
     if cfg.window < 0:
         raise ValueError(f"window must be >= 0, got {cfg.window}")
 
@@ -507,8 +532,8 @@ def prefill(params: Params, prompt, *,
     ``L{i}_{k,v} -> (B, total, H_kv, Dh)`` dict :func:`greedy_decode`
     uses (H_kv = ``kv_heads(cfg)``, which is where GQA's group-factor
     cache shrink shows up; zero-padded to ``total``, default P), or for
-    latent attention ``L{i}_ckv -> (B, total, kv_rank + rope_dim)`` and
-    the indexer's ``L{i}_ik -> (B, total, index_dim)``;
+    latent attention ``L{i}_ckv -> (B, total, kv_rank + rope_dim)`` and,
+    where it has an indexer, ``L{i}_ik -> (B, total, index_dim)``;
     last_logits is (B, vocab). :func:`decode_caches` turns them into
     what :func:`decode_from` scans over. With ``chunk`` (latent
     attention, single-device; it must divide P) the prompt goes through
@@ -668,8 +693,9 @@ def _decode_step(params: Params, cfg: TransformerConfig, b: int,
     ``feed(t, cur)`` at position ``t``, writes the position's cache
     rows, attends the cache, and selects the next token. Caches are the
     layout of :func:`decode_caches`. With ``stats`` a step also yields
-    its counters: the positions latent attention selected in each layer
-    (``selected``, -1 where the query saw fewer), and from each grouped
+    its counters: the positions an indexer selected in each layer
+    (``selected``, -1 where the query saw fewer; absent where attention
+    reads its cache whole), and from each grouped
     expert layer ``held_assignments``, ``experts_touched`` and the
     experts every token was routed to (``experts``)."""
     # the switch router's per-step routing group = B tokens; clamp
@@ -756,7 +782,8 @@ def decode_from(params: Params, caches: Params, first_ids, start,
     that one back, keep a copy). With ``stats`` a third
     value holds each step's counters, stacked over the steps:
     ``selected`` (n_new, layers, B, K) the cache positions latent
-    attention read, -1 where fewer than K existed, and from the grouped
+    attention's indexer chose, -1 where fewer than K existed (no such
+    entry where attention reads its cache whole), and from the grouped
     expert layers ``held_assignments`` / ``experts_touched`` (n_new,
     expert layers) and ``experts`` (n_new, expert layers, B, top_k),
     the experts each token was routed to."""

@@ -42,6 +42,10 @@ _TPU_AUTO_POLICY = {
     # flash-decode (ops/decode.py): one query position vs the KV
     # cache, chunk-streamed with dynamic dead-chunk DMA elision
     "decode_attention": "pallas",
+    # latent flash-decode (ops/mla_decode.py): a cached row is key and
+    # value at once and crosses the bus once; XLA's composition reads
+    # the cache for the scores and again for the values
+    "mla_decode_attention": "pallas",
 }
 
 
@@ -81,11 +85,13 @@ from lua_mapreduce_tpu.ops.conv import conv2d  # noqa: E402
 from lua_mapreduce_tpu.ops.pool import avgpool2d, maxpool2d  # noqa: E402
 from lua_mapreduce_tpu.ops.attention import flash_attention  # noqa: E402
 from lua_mapreduce_tpu.ops.decode import decode_attention  # noqa: E402
+from lua_mapreduce_tpu.ops.mla_decode import (  # noqa: E402
+    mla_causal_attention, mla_decode_attention)
 from lua_mapreduce_tpu.ops.q8 import q8_matmul, quantize_q8  # noqa: E402
 
 __all__ = [
     "default_backend", "resolve_backend",
     "matmul", "log_softmax", "softmax", "conv2d",
     "maxpool2d", "avgpool2d", "flash_attention", "decode_attention",
-    "q8_matmul", "quantize_q8",
+    "mla_decode_attention", "mla_causal_attention", "q8_matmul", "quantize_q8",
 ]

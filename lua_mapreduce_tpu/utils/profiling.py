@@ -30,16 +30,17 @@ LM_SCOPES = ("lm.loss", "lm.embed", "lm.attn", "lm.ffn", "lm.head",
              "lm.opt", "lm.ring", "lm.prefill", "lm.first_token",
              "lm.decode",
              # inside lm.attn, latent attention: its projections, the
-             # indexer's scores and top-k, the gather and attention
-             "lm.mla", "lm.indexer", "lm.sparse",
+             # indexer's scores and top-k, the gather and attention; or,
+             # without an indexer, attention over the whole cache
+             "lm.mla", "lm.indexer", "lm.sparse", "lm.latent",
              # inside lm.ffn, the grouped expert layer
              "lm.moe.route", "lm.moe.experts", "lm.moe.shared")
 LM_HOST_SPANS = ("lm.shard_batch",)
 # ``name=`` of the pallas_calls (ops/attention.py, ops/decode.py,
-# ops/q8.py): the custom call's HLO result is ``%<name>.<n>`` whatever
+# ops/mla_decode.py, ops/q8.py): the custom call's HLO result is ``%<name>.<n>`` whatever
 # scope or transformation encloses it.
 LM_KERNELS = ("flash_pallas", "flash_bwd_pallas_dq", "flash_bwd_pallas_dkv",
-              "_decode_pallas", "q8_matmul_pallas")
+              "_decode_pallas", "q8_matmul_pallas", "_mla_decode_pallas")
 # the jitted functions, so the trace's programs are ``jit_<name>``
 LM_PROGRAMS = ("lm_train_step", "greedy_decode", "decode_from")
 

@@ -39,6 +39,15 @@ LATENT_CFG = tfm.TransformerConfig.llama_style(
 SESSION_SCOPES = {"lm.mla": "lm.attn", "lm.indexer": "lm.attn",
                   "lm.sparse": "lm.attn", "lm.moe.route": "lm.ffn",
                   "lm.moe.experts": "lm.ffn", "lm.moe.shared": "lm.ffn"}
+# latent attention that reads its cache whole: no query latent, no
+# indexer, the qk-norm
+WHOLE_CFG = tfm.TransformerConfig.llama_style(
+    vocab=64, d_model=32, n_heads=4, n_layers=2, d_ff=48, max_seq=64,
+    norm_eps=1e-6, tied_head=False, latent=tfm.LatentAttention(
+        q_rank=0, kv_rank=16, nope_dim=8, rope_dim=4, v_dim=8,
+        rope_factor=40.0, rope_original=16, mscale_all_dim=1.0,
+        qk_norm=True))
+WHOLE_SCOPES = {"lm.mla": "lm.attn", "lm.latent": "lm.attn"}
 
 
 def train_setup(shape, make=tfm.make_train_step, **kw):
@@ -177,18 +186,15 @@ def test_decode_blocks_lie_under_their_phase(lowered_decode):
                              for p in first)
 
 
-@pytest.mark.parametrize("name", sorted(SESSION_SCOPES))
-def test_the_session_entry_nests_the_layers_scopes(lowered_session, name):
-    """`lm.attn/lm.indexer`, `lm.ffn/lm.moe.experts`, ...: inside the
-    block's scope, so that a reader of blocks still holds them, inside
-    the scan of `lm.decode`."""
+def nests(lowered, name: str, block: str) -> None:
+    """``name`` lies inside ``block``'s scope, inside the scan of
+    `lm.decode`, in the compiled session entry."""
     assert "decode_from" in profiling.LM_PROGRAMS
-    assert "module @jit_decode_from" in lowered_session.as_text()
+    assert "module @jit_decode_from" in lowered.as_text()
     paths = set(re.findall(r'op_name="([^"]*)"',
-                           lowered_session.compile().as_text()))
+                           lowered.compile().as_text()))
     under = [p for p in paths if name in p.split("/")]
     assert under, name
-    block = SESSION_SCOPES[name]
     assert any(p.startswith("jit(decode_from)/lm.decode/while/body/")
                for p in under)
     for p in under:         # a reducer's own computation has a relative path
@@ -196,6 +202,25 @@ def test_the_session_entry_nests_the_layers_scopes(lowered_session, name):
         assert block in parts[:parts.index(name)], p
         assert not p.startswith("jit(") or parts[1:4] == [
             "lm.decode", "while", "body"], p
+
+
+@pytest.mark.parametrize("name", sorted(SESSION_SCOPES))
+def test_the_session_entry_nests_the_layers_scopes(lowered_session, name):
+    """`lm.attn/lm.indexer`, `lm.ffn/lm.moe.experts`, ...: inside the
+    block's scope, so that a reader of blocks still holds them, inside
+    the scan of `lm.decode`."""
+    nests(lowered_session, name, SESSION_SCOPES[name])
+
+
+@pytest.mark.parametrize("name", sorted(WHOLE_SCOPES))
+def test_attention_over_the_whole_cache_has_a_scope_of_its_own(
+        lowered_whole, name):
+    """`lm.attn/lm.latent` beside `lm.attn/lm.mla`; no indexer, so
+    neither `lm.indexer` nor `lm.sparse`, and no `selected` counter."""
+    nests(lowered_whole, name, WHOLE_SCOPES[name])
+    paths = scope_paths(lowered_whole)
+    assert not any("lm.indexer" in p or "lm.sparse" in p for p in paths)
+    assert "selected" not in lowered_whole.out_info[2]
 
 
 def test_the_session_entry_runs_no_prefill(lowered_session):
@@ -206,19 +231,21 @@ def test_the_session_entry_runs_no_prefill(lowered_session):
 
 
 def test_every_scope_of_the_contract_is_used(lowered_train, lowered_decode,
-                                             lowered_session):
+                                             lowered_session, lowered_whole):
     found = (scope_paths(lowered_train[2, 2]) | scope_paths(lowered_decode)
-             | scope_paths(lowered_session))
+             | scope_paths(lowered_session) | scope_paths(lowered_whole))
     for name in profiling.LM_SCOPES:
         assert any(name in p for p in found), name
     assert set(TRAIN_SCOPES + DECODE_SCOPES + ("lm.ring",)
-               + tuple(SESSION_SCOPES)) == set(profiling.LM_SCOPES)
+               + tuple(SESSION_SCOPES) + tuple(WHOLE_SCOPES)) == set(
+                   profiling.LM_SCOPES)
 
 
-def test_the_new_layers_add_no_kernel():
-    """Latent attention, the indexer and the expert layer are XLA's: the
-    session entry of that model holds no pallas_call, so the pinned
-    kernel names stay the five."""
+def test_the_indexed_layers_add_no_kernel():
+    """Latent attention over selected rows, the indexer and the expert
+    layer are XLA's: the session entry of that model holds no
+    pallas_call. The sixth pinned name is the kernel of latent attention
+    over a whole cache (`ops/mla_decode.py`)."""
     params = tfm.init_transformer(jax.random.PRNGKey(0), LATENT_CFG)
     caches, _ = tfm.prefill(params, jnp.zeros((2, 8), jnp.int32),
                             cfg=LATENT_CFG, total=12)
@@ -226,7 +253,19 @@ def test_the_new_layers_add_no_kernel():
         p, c, jnp.zeros((2,), jnp.int32), 8, 4, cfg=LATENT_CFG))(
             params, caches)
     assert pallas_calls(jaxpr.jaxpr) == []
-    assert len(profiling.LM_KERNELS) == 5
+    assert len(profiling.LM_KERNELS) == 6
+
+
+@pytest.fixture(scope="module")
+def lowered_whole():
+    """The session entry of the model whose latent attention has no
+    indexer."""
+    params = tfm.init_transformer(jax.random.PRNGKey(0), WHOLE_CFG)
+    caches, _ = tfm.prefill(params, jnp.zeros((2, 8), jnp.int32),
+                            cfg=WHOLE_CFG, total=12)
+    caches = tfm.decode_caches(caches, cfg=WHOLE_CFG, p_len=8, total=12)
+    return tfm.decode_from.lower(params, caches, jnp.zeros((2,), jnp.int32),
+                                 8, 4, cfg=WHOLE_CFG, stats=True)
 
 
 def pallas_calls(jaxpr, out=None) -> list:
@@ -258,8 +297,15 @@ def kernel_sites():
         return ops.q8_matmul(x, *ops.quantize_q8(w),
                              backend="pallas_interpret")
 
+    def mla(q, rows):
+        return ops.mla_decode_attention(q, rows, jnp.int32(5), v_rank=64,
+                                        scale=0.1,
+                                        backend="pallas_interpret")
+
     cache = jnp.ones((1, 2, 128, 64), jnp.float32)
     return {
+        "_mla_decode_pallas": (mla, (jnp.ones((1, 4, 96)),
+                                     jnp.ones((1, 128, 96)))),
         "flash_pallas": (flash, (q, q, q)),
         "flash_bwd_pallas_dq": (jax.grad(flash, (0, 1, 2)), (q, q, q)),
         "flash_bwd_pallas_dkv": (jax.grad(flash, (0, 1, 2)), (q, q, q)),

@@ -384,3 +384,56 @@ def test_the_decode_kernel_compiles_inside_its_vmem_limit(
         arg((b, hkv, g, d), jnp.bfloat16), cache, cache,
         arg((), jnp.int32), roll=roll, **scales).compile().as_text()
     assert "_decode_pallas" in text and "tpu_custom_call" in text
+
+
+# --------------------------------------------------------------------------
+# latent attention over a whole cache (PR 32): the latent flash-decode
+# kernel at the sarvam-105b cell's shape, and the session entry around it
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("b,heads,s_len,tiles", [
+    (16, 64, 32832, (2, 1024)),     # the sarvam-105b cell
+    (1, 64, 32832, (1, 2048)),      # one session: a longer chunk
+    (8, 128, 32832, (2, 1024)),     # 128 heads over the same row
+    (4, 16, 300, (4, 384)),         # a ragged only chunk
+])
+def test_the_latent_decode_kernel_compiles_inside_its_vmem_limit(
+        topo, chip_policy, b, heads, s_len, tiles):
+    from jax.sharding import SingleDeviceSharding
+    from lua_mapreduce_tpu.ops import mla_decode
+    one = SingleDeviceSharding(topo.devices[0])
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one)
+    assert mla_decode._tiles(b, s_len, 576, 512, heads, 2) == tiles
+    assert (mla_decode._vmem_bytes(*tiles, 576, 512, heads, 2)
+            <= mla_decode._VMEM_BUDGET)
+    text = mla_decode._mla_decode_pallas.lower(
+        arg((b, heads, 576), jnp.bfloat16),
+        arg((b, s_len, 576), jnp.bfloat16), arg((), jnp.int32),
+        v_rank=512).compile().as_text()
+    assert "_mla_decode_pallas" in text and "tpu_custom_call" in text
+
+
+def test_the_whole_cache_session_entry_keeps_kernel_and_caches_in_place(
+        topo, chip_policy):
+    """One expert layer of sarvam-105b's share at its real widths: the
+    kernel is there, every held expert sits behind a conditional, and
+    the latent cache stays in place in the layout the chip keeps it in:
+    the scan re-lays no (16, 32832, 576) array (a kernel over (B, S, R)
+    blocks made XLA copy the whole cache into the scan and out of it,
+    and hold a second one)."""
+    from perfbench.model_sarvam import program_config as sarvam_config
+    import dataclasses
+    cfg = dataclasses.replace(
+        sarvam_config(serve_config("sarvam-105b.serve-ep4")),
+        n_layers=1, moe_first_dense=0)
+    compiled = compiled_turn(topo, cfg, 16, 32768, 64)
+    text = compiled.as_text()
+    assert "_mla_decode_pallas" in text
+    assert len(re.findall(r" conditional\(", text)) == 32
+    assert not re.findall(
+        r"= bf16\[16,(?:32832,576|576,32832)\][^ ]* (?:copy|transpose)\(",
+        text)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 16 * 32832 * 576 * 2
+    assert memory.temp_size_in_bytes < 256 * 2 ** 20
