@@ -71,55 +71,102 @@ def kth_largest_key(keys, k: int):
 
 
 _LANES = 128
-# rows up to which `select_top_k` goes without a sort. Two exact ways stay
-# because each wins on one side of a size the code can see, and the
-# session cell has both sides: the 8 rows of a timed decode step (0.5 ms
-# against the sort's 3.5) and the 64 rows of a set-up prefill block (6.1 ms
-# against 3.4: over the cell's 20,480 blocks 55 s more of a 107 s prefill;
-# my chip runs, PR 28)
-_FEW_ROWS = 16
+# rows up to which `select_top_k` goes without a sort: both sizes the
+# session cell has, the 8 rows of a timed decode step and the 64 rows of
+# a set-up prefill block. The limit is memory, not speed: a row costs
+# this way about 4 MB of temporaries (2 GB at 512 rows), and the sort
+# won at no size measured (us a call of 32,832 scores a row, top 2048,
+# this way / `lax.top_k`: 8 rows 48 / 2,453, 64 rows 571 / 2,392, 128
+# rows 1,610 / 4,785, 512 rows 6,826 / 20,330; my chip runs, PR 33,
+# `benchmarks/selection_bench.py`)
+_FEW_ROWS = 64
+
+
+def _ones_below(n: int):
+    """(n, n) bfloat16, one where the row's number is at most the
+    column's: a product with it is an inclusive running sum."""
+    i = jnp.arange(n)
+    return (i[:, None] <= i[None, :]).astype(jnp.bfloat16)
+
+
+def _running_counts(flags):
+    """Of each row of ``flags`` (..., S) bool, cut into blocks of 128
+    lanes (False behind the row's end): the running count of True inside
+    each block, inclusive (R, nb, 128), and the blocks' running totals,
+    inclusive (R, nb); float32, whole numbers. Both are products with a
+    triangle of ones on the matrix unit, and exact: every factor is a
+    whole number of at most 128 and every sum stays under 2**24 (a
+    longer row is refused). (The chip's own running sum over 128 lanes
+    is a window reduction: 89 us for (8, 257, 128) where this product
+    takes under one; my chip runs, PR 33.)"""
+    if flags.shape[-1] >= 2 ** 24:
+        raise ValueError(f"a row of {flags.shape[-1]} positions: the "
+                         f"running counts are exact under 2**24")
+    rows = flags.reshape(-1, flags.shape[-1])
+    rows = jnp.pad(rows, ((0, 0), (0, -rows.shape[-1] % _LANES)))
+    c = rows.reshape(rows.shape[0], -1, _LANES)                # (R, nb, 128)
+    inside = jnp.einsum("rbj,jl->rbl", c.astype(jnp.bfloat16),
+                        _ones_below(_LANES),
+                        preferred_element_type=jnp.float32)
+    ends = jnp.einsum("rb,bc->rc", inside[..., -1].astype(jnp.bfloat16),
+                      _ones_below(c.shape[1]),
+                      preferred_element_type=jnp.float32)
+    return inside, ends
+
+
+def _running_count(flags):
+    """The inclusive running count of True along each row of ``flags``
+    (..., S), float32: inside a block of 128 plus the blocks before."""
+    inside, ends = _running_counts(flags)
+    before = ends - inside[..., -1]
+    return (inside + before[..., None]).reshape(ends.shape[0], -1)[
+        :, :flags.shape[-1]].reshape(flags.shape)
 
 
 def _positions(chosen, k: int):
-    """The positions of the ``k`` True of each row of ``chosen`` (..., S),
-    rising, with one gather of k scalars a row and no sort. The row is
-    cut into blocks of 128: inside a block the r-th True is found by
-    comparing every lane's running count with r; a slot finds its block
-    by comparing with the blocks' running totals. (A binary search a
-    slot costs the chip 2 ms a row block, as much as the sort.)"""
+    """The positions of the True of each row of ``chosen`` (..., S),
+    rising, in ``k`` slots; no sort and no gather. The row is cut into
+    blocks of 128 lanes. A slot finds its block by comparing with the
+    blocks' running totals, and fetches that block's 128 running counts
+    (and, as three bytes, the count before the block: bfloat16 holds a
+    byte whole, and a count is under 2**24) by a one-hot product on the
+    matrix unit: exact, one term of each sum is not zero and none is
+    over 255. The lane of the block's r-th True is the number of its
+    running counts at or under r. Slots past the row's last True hold
+    some position of the row."""
     *lead, s = chosen.shape
-    rows = chosen.reshape(-1, s)
-    rows = jnp.pad(rows, ((0, 0), (0, -s % _LANES)))
-    c = rows.reshape(rows.shape[0], -1, _LANES)                # (R, nb, 128)
-    lane = jnp.arange(_LANES, dtype=jnp.int32)
-    rank = jnp.cumsum(c, axis=-1, dtype=jnp.int32) - 1
-    # table[R, b, r]: the lane of block b's r-th True
-    table = jnp.sum(jnp.where(c[..., :, None]
-                              & (rank[..., :, None] == lane),
-                              lane[:, None], 0), axis=-2)
-    counts = jnp.sum(c, axis=-1, dtype=jnp.int32)              # (R, nb)
-    ends = jnp.cumsum(counts, axis=-1)
-    slot = jnp.arange(k, dtype=jnp.int32)
-    before = ends[:, None, :] <= slot[None, :, None]           # (R, k, nb)
+    inside, ends = _running_counts(chosen)
+    starts = ends - inside[..., -1]                            # (R, nb)
+    table = jnp.concatenate(
+        [inside, jnp.stack([starts // 65536, starts // 256 % 256,
+                            starts % 256], axis=-1)],
+        axis=-1).astype(jnp.bfloat16)
+    slot = jnp.arange(k, dtype=jnp.float32)[None, :, None]
+    before = ends[:, None, :] <= slot                          # (R, k, nb)
     block = jnp.sum(before, axis=-1, dtype=jnp.int32)
-    start = jnp.sum(jnp.where(before, counts[:, None, :], 0), axis=-1)
-    block = jnp.minimum(block, c.shape[1] - 1)
-    inside = jnp.take_along_axis(table.reshape(rows.shape[0], -1),
-                                 block * _LANES + (slot - start), axis=-1)
-    idx = jnp.minimum(block * _LANES + inside, s - 1)
-    return idx.reshape(*lead, k).astype(jnp.int32)
+    here = (starts[:, None, :] <= slot) & ~before              # one-hot
+    mine = jnp.einsum("rkb,rbl->rkl", here.astype(jnp.bfloat16), table,
+                      preferred_element_type=jnp.float32)
+    high, mid, low = (mine[..., _LANES + i, None] for i in range(3))
+    r = slot - (65536 * high + 256 * mid + low)
+    lane = jnp.sum(mine[..., :_LANES] <= r, axis=-1, dtype=jnp.int32)
+    idx = jnp.minimum(block * _LANES + lane, s - 1)
+    return idx.reshape(*lead, k)
 
 
 def select_top_k(scores, top_k: int):
     """The ``top_k`` highest-scoring keys of each query (all of the
     cache where it is shorter): (idx (B, Q, K) int32, valid (B, Q, K)).
-    An entry is invalid where the query sees fewer than K keys. Equal
+    An entry is invalid where the query sees fewer than K keys (a score
+    of minus infinity is a key not seen, wherever it lies). Equal
     scores go to the lower position. For a few rows (a decode step)
-    without a sort: the k-th score by :func:`kth_largest_key`, then the
-    positions at or over it (:func:`_positions`), rising; for many (a
-    block of a prefill) `lax.top_k`, whose sort is then the cheaper: 64
-    rows of 32k take the chip 3.4 ms sorted and 6.1 ms this way, 8 rows
-    3.5 ms and 0.5 ms (my chip run, PR 28). The same set either way."""
+    without a sort and without a gather: the k-th score by
+    :func:`kth_largest_key`, then the positions at or over it
+    (:func:`_positions`), rising, the first ``n`` of them valid where
+    ``n`` scores are finite: 8 rows of 32,832 take the chip 48 us (with
+    a table of lanes and two gathers of a scalar a slot 540, sorted
+    2,453), 64 rows 571 us (5,099; 2,392) (my chip runs, PR 33). For
+    more rows `lax.top_k`. The same set either way."""
     k = min(top_k, scores.shape[-1])
     if scores[..., 0].size > _FEW_ROWS:
         vals, idx = lax.top_k(scores, k)
@@ -128,10 +175,10 @@ def select_top_k(scores, top_k: int):
     kth = kth_largest_key(keys, k)[..., None]
     above, tied = keys > kth, keys == kth
     room = k - jnp.sum(above, axis=-1, keepdims=True)
-    chosen = above | (tied & (jnp.cumsum(tied, axis=-1) <= room))
-    idx = _positions(chosen, k)
-    picked = jnp.take_along_axis(scores, idx, axis=-1)
-    return idx, picked > _NEG_INF
+    chosen = ((above | (tied & (_running_count(tied) <= room)))
+              & (scores > _NEG_INF))
+    n = jnp.sum(chosen, axis=-1, keepdims=True)
+    return _positions(chosen, k), jnp.arange(k) < n
 
 
 def sparse_latent_attention(q, cache, idx, valid, *, scale: float,

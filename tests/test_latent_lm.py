@@ -155,7 +155,7 @@ def test_routing_miss_by_hand():
     assert ref.routing_miss(mine, theirs) == pytest.approx(2 / 6)
 
 
-@pytest.mark.parametrize("batch", [1, 4], ids=["few-rows", "many-rows"])
+@pytest.mark.parametrize("batch", [1, 12], ids=["few-rows", "many-rows"])
 def test_the_indexer_breaks_ties_as_the_reference_does(batch):
     """Scores from a few integers, so that most are tied: the lower
     position wins in the reference and in both of the program's ways
@@ -163,7 +163,7 @@ def test_the_indexer_breaks_ties_as_the_reference_does(batch):
     the many of a prefill block)."""
     rng = np.random.default_rng(3)
     scores = rng.integers(0, 3, (batch, 6, 32)).astype(np.float32)
-    assert (scores[..., 0].size > sparse_mla._FEW_ROWS) == (batch == 4)
+    assert (scores[..., 0].size > sparse_mla._FEW_ROWS) == (batch == 12)
     pos = np.arange(26, 32)
     seen = np.arange(32)[None, :] <= pos[:, None]
     idx, valid = sparse_mla.select_top_k(
@@ -181,6 +181,81 @@ def test_the_indexer_breaks_ties_as_the_reference_does(batch):
     idx, valid = sparse_mla.select_top_k(few, 8)
     assert sorted(np.asarray(idx[0, 0])[np.asarray(valid[0, 0])]) == \
         [0, 1, 2, 3, 4]
+
+
+@pytest.mark.parametrize("rows,length,k,where", [
+    (8, 32832, 2048, "spread"), (1, 32832, 2048, "spread"),
+    (8, 32832, 2048, "first-block"), (8, 32832, 2048, "last-blocks"),
+    (1, 32832, 2048, "last-blocks"), (3, 1000, 64, "spread"),
+    (3, 1000, 64, "first-block"), (3, 1000, 64, "last-blocks"),
+    (2, 1024, 64, "last-blocks"), (1, 131072, 2048, "last-blocks"),
+    (1, 66000, 66000, "everywhere"),
+])
+def test_positions_are_where_nonzero_finds_them(rows, length, k, where):
+    """`_positions` against `np.nonzero`, row for row: up to ``k`` True
+    spread over the row, all inside the first block of 128 lanes, or all
+    in the last blocks; rows that end inside a block of 128 (32,832 and
+    1,000 positions) and one that ends with a block (1,024); a row of
+    131,072, and one with more True before a block than two bytes count
+    in bfloat16 (65,792: the count before a block rides as bytes)."""
+    rng = np.random.default_rng(rows * length + k)
+    chosen = np.zeros((rows, length), bool)
+    for r in range(rows):
+        n = k if r == 0 else int(rng.integers(0, k + 1))
+        if where == "everywhere":
+            at = slice(None)
+        elif where == "first-block":
+            at = rng.choice(128, min(n, 128), replace=False)
+        elif where == "last-blocks":
+            at = length - 1 - rng.choice(k + 40, n, replace=False)
+        else:
+            at = rng.choice(length, n, replace=False)
+        chosen[r, at] = True
+    got = np.asarray(sparse_mla._positions(jnp.asarray(chosen), k))
+    assert got.shape == (rows, k) and got.dtype == np.int32
+    assert got.min() >= 0 and got.max() < length
+    for r in range(rows):
+        want = np.nonzero(chosen[r])[0]
+        assert np.array_equal(got[r, :len(want)], want)
+
+
+def test_a_row_too_long_to_count_exactly_is_refused():
+    """The running counts are float32: a row of 2**24 positions or more
+    is refused where the program is traced, not answered wrongly."""
+    row = jax.ShapeDtypeStruct((1, 2 ** 24), bool)
+    with pytest.raises(ValueError, match="2\\*\\*24"):
+        jax.eval_shape(lambda c: sparse_mla._positions(c, 8), row)
+
+
+@pytest.mark.parametrize("batch", [1, 12], ids=["few-rows", "many-rows"])
+def test_a_key_not_seen_is_never_selected_wherever_it_lies(batch):
+    """Minus infinities BETWEEN finite scores, and fewer finite scores
+    than ``top_k`` in some rows: every valid index is of a finite score,
+    every finite score of such a row is chosen, and a row has
+    min(k, finite) valid entries, in both of the program's ways."""
+    rng = np.random.default_rng(7)
+    k, length = 24, 300
+    scores = rng.normal(size=(batch, 6, length)).astype(np.float32)
+    scores[:, :, rng.choice(length, 120, replace=False)] = -np.inf
+    for q, finite in enumerate([0, 1, 5, 23]):          # rows short of k
+        gone = rng.permutation(length)[finite:]
+        scores[:, q, gone] = -np.inf
+    assert (scores[..., 0].size > sparse_mla._FEW_ROWS) == (batch == 12)
+    idx, valid = map(np.asarray,
+                     sparse_mla.select_top_k(jnp.asarray(scores), k))
+    assert idx.min() >= 0 and idx.max() < length
+    for b in range(batch):
+        for q in range(6):
+            finite = np.isfinite(scores[b, q])
+            mine = idx[b, q][valid[b, q]]
+            assert len(set(mine)) == len(mine) == min(k, finite.sum())
+            assert finite[mine].all()
+            if finite.sum() <= k:
+                assert set(mine) == set(np.nonzero(finite)[0])
+            else:
+                kth = np.sort(scores[b, q][finite])[-k]
+                assert scores[b, q][mine].min() >= kth
+                assert set(np.nonzero(scores[b, q] > kth)[0]) <= set(mine)
 
 
 def test_the_kth_largest_key_is_exact():

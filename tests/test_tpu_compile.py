@@ -10,6 +10,7 @@ imports every test file. Keep every such compile in this one file.
 
 import collections
 import json
+import math
 import os
 import re
 
@@ -333,18 +334,32 @@ def test_the_dense_session_entry_keeps_kernel_and_caches_in_place(
     assert memory.temp_size_in_bytes < 64 * 2 ** 20
 
 
+@pytest.fixture(scope="module")
+def latent_turn(topo):
+    """One expert layer of DeepSeek-V3.2-Exp's share at its real widths,
+    the cell's turn (8 sessions, 32,768 cached positions, 64 scanned),
+    compiled once for the file. Call it under `chip_policy`."""
+    built = []
+
+    def turn():
+        if not built:
+            from perfbench.model_dsv32 import program_config
+            import dataclasses
+            cfg = dataclasses.replace(program_config(serve_config(
+                "deepseek-v3.2-exp.serve-ep16")), n_layers=1,
+                moe_first_dense=0)
+            built.append(compiled_turn(topo, cfg, 8, 32768, 64))
+        return built[0]
+
+    return turn
+
+
 def test_the_latent_session_entry_reads_an_expert_only_where_touched(
-        topo, chip_policy):
-    """One expert layer of DeepSeek-V3.2-Exp's share at its real widths:
-    every held expert sits behind a conditional (no token, no read of
+        chip_policy, latent_turn):
+    """Every held expert sits behind a conditional (no token, no read of
     its weights), nothing sorts 32k scores, and the latent caches stay
     in place."""
-    from perfbench.model_dsv32 import program_config as dsv32_program_config
-    import dataclasses
-    cfg = dataclasses.replace(
-        dsv32_program_config(serve_config("deepseek-v3.2-exp.serve-ep16")),
-        n_layers=1, moe_first_dense=0)
-    compiled = compiled_turn(topo, cfg, 8, 32768, 64)
+    compiled = latent_turn()
     text = compiled.as_text()
     assert len(re.findall(r" conditional\(", text)) == 16
     assert not re.findall(r"sort\([^)]*\[8,1,32832\]", text)
@@ -352,6 +367,22 @@ def test_the_latent_session_entry_reads_an_expert_only_where_touched(
     memory = compiled.memory_analysis()
     # (the chip's tiling pads the rows a little)
     assert memory.alias_size_in_bytes >= 8 * 32832 * (576 + 128) * 2
+
+
+def test_the_latent_session_entry_gathers_the_selected_rows_and_no_more(
+        chip_policy, latent_turn):
+    """The selection of a decode step (8 rows x 2048 slots) looks nothing
+    up index by index: the one gather over the selected slots is the row
+    gather from the (8, 32832, 576) cache, and none gives an `s32` or
+    `f32` scalar a slot, whatever axes of one it keeps (the lane lookup
+    and the re-read of the scores, 7.9 ns an index each on the chip;
+    ledger, PR 32)."""
+    text = latent_turn().as_text()
+    gathers = re.findall(r"= (\w+)\[([\d,]*)\]\S* gather\(", text)
+    slots = [(dtype, dims) for dtype, dims in gathers
+             if math.prod(map(int, re.findall(r"\d+", dims)))
+             in (8 * 2048, 8 * 2048 * 576)]          # a scalar, a row a slot
+    assert slots == [("bf16", "8,2048,576")]
 
 
 # --------------------------------------------------------------------------
