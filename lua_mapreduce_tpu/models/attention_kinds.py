@@ -1,6 +1,10 @@
-"""What a decoder layer's attention is: a KIND owns its weights, what a
-position caches, the cache's format and the attention over it. Two
-exist, :class:`GroupedQuery` and :class:`Latent`;
+"""What a decoder layer's attention is (its MIXER, where the layer has
+no attention): a KIND owns its weights, what a position caches, the
+cache's format and the attention over it. :class:`GroupedQuery` and
+:class:`Latent` are attention over caches of their own;
+:class:`StateSpace`, :class:`Cross` and :class:`GatedMemory` are the
+further layers of a hybrid stack (a recurrent state; attention over
+ANOTHER layer's cache; a gate on another layer's activation).
 ``models/transformer.attention_kind(cfg, i)`` says which one layer ``i``
 has, and nothing there knows more of a kind than the methods below.
 ``cfg`` is the model's ``TransformerConfig``; ``p`` is the layer's
@@ -13,6 +17,7 @@ parameter and cache prefix (``L{i}``), ``y`` the normed layer input.
     empty(p, b, total, dtype, kv_q8)         -> the layer's empty caches
     padded(p, rows, total, dtype)            -> prefill's public caches
     scanned(p, caches, p_len, total, kv_q8)  -> the public ones, as scanned
+    turn_start(p, caches, start)             -> leaves to replace (often none)
 
 ``full`` attends a whole sequence (``y`` (B, L, d) at positions ``pos``)
 and returns what it cached of it; ``chunk`` and ``step`` write their
@@ -20,12 +25,25 @@ positions into ``caches`` (every layer's, as ``empty`` and ``scanned``
 lay them out) and attend what the caches hold by then, ``step`` for the
 one position ``t`` with the cache positions it selected (None where the
 kind reads them all). ``out`` is the attention's output through its out
-projection, for the residual. This module sits below
-``models/transformer.py`` and never imports it.
+projection, for the residual. ``turn_start`` is called once before a
+turn's scan (``decode_from``) with the position the turn starts from: a
+kind whose caches a turn changes for good (a state, a rolling buffer:
+nothing in them says what they held before) keeps ONE snapshot beside
+them and puts it back when the turn before started from the same
+position, so that a turn can be taken back and made again. A kind with
+``shares`` set takes part in
+a hybrid stack's hand-over: ``full``, ``chunk`` and ``step`` are then
+given ``handed=``, the dict in which the layers of ONE forward leave
+what later layers of the same forward read (``memory``: a state-space
+layer's scan output at the current positions; ``kv``: a full-attention
+layer's rows, for the cross layers of a full-sequence forward, which
+has no caches). This module sits below ``models/transformer.py`` and
+never imports it.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Dict
 
@@ -35,7 +53,9 @@ import numpy as np
 from jax import lax
 
 from lua_mapreduce_tpu.ops import sparse_mla as _sparse
-from lua_mapreduce_tpu.ops.decode import decode_attention, quantize_kv
+from lua_mapreduce_tpu.ops import ssm as _ssm
+from lua_mapreduce_tpu.ops.decode import (cache_attention, decode_attention,
+                                          quantize_kv)
 from lua_mapreduce_tpu.ops.mla_decode import (mla_causal_attention,
                                               mla_decode_attention)
 from lua_mapreduce_tpu.ops.q8 import q8_matmul
@@ -139,7 +159,8 @@ def _rolls(cfg, cache_len: int) -> bool:
     are where they are as long as the window (position p lives in slot
     p mod window). The one rule for every decode; where the window
     covers the whole decode, p mod window is p and the two layouts are
-    one."""
+    one. ``cfg`` is whatever has the ``window``: a kind, or a
+    configuration with one window for all layers."""
     return bool(cfg.window) and cache_len == cfg.window
 
 
@@ -169,12 +190,23 @@ class _Kind:
 
     # names of what a layer caches, in the order ``full`` returns them
     leaves = ()
+    # whether the kind takes part in a hybrid stack's hand-over: its
+    # forms are then given ``handed=`` (the module's docstring)
+    shares = False
+    # whether prefill's public caches are the scan's own: a chunked
+    # prefill fills the scan's caches, so it is for such kinds
+    one_form = False
 
     def names(self, p: str) -> list:
         return [f"{p}_{leaf}" for leaf in self.leaves]
 
     def chunk(self, params, p, y, pos, caches, start):
-        raise ValueError("chunk is for single-device latent attention")
+        raise ValueError(f"{type(self).__name__} has no chunk form")
+
+    def turn_start(self, p: str, caches: Params, start) -> Params:
+        """Nothing to put back: a turn from ``start`` writes positions
+        from ``start`` on and reads none it has not written."""
+        return {}
 
     def padded(self, p: str, rows, total: int, dtype) -> Params:
         """``full``'s rows as :func:`prefill` hands them out: leaves
@@ -187,6 +219,80 @@ class _Kind:
         return out
 
 
+def _with_snapshot(leaves: Params, p: str) -> Params:
+    """``leaves`` (a layer's caches that a turn changes for good) with
+    room for one snapshot of them: ``<leaf>0``, and ``L{i}_at0``, the
+    position the snapshot was taken at (-1: none yet)."""
+    return {**leaves,
+            **{n + "0": jnp.zeros_like(x) for n, x in leaves.items()},
+            f"{p}_at0": jnp.full((), -1, jnp.int32)}
+
+
+def _taken_back(names, p: str, caches: Params, start) -> Params:
+    """:meth:`_Kind.turn_start` for leaves ``names`` kept by
+    :func:`_with_snapshot`: where the snapshot is of ``start`` (the turn
+    before started here too) it is put back; else the leaves stand at
+    ``start`` (the caller's word, as for every cache) and are what is
+    kept. Either way both then hold the caches as of ``start``."""
+    same = caches[f"{p}_at0"] == start
+    out = {f"{p}_at0": jnp.asarray(start, jnp.int32)}
+    for n in names:
+        out[n] = out[n + "0"] = jnp.where(same, caches[n + "0"], caches[n])
+    return out
+
+
+def _rolled(c, p_len: int, cache_len: int):
+    """(B, H, S, D) rows of positions 0 .. S-1, of which the first
+    ``p_len`` are a prompt's, as a rolling buffer of ``cache_len``
+    slots holds them."""
+    # positions 0..p_len-1 land in slots 0..p_len-1 and the rows are
+    # zero beyond them: a plain truncation IS the rolling layout
+    src = slice(cache_len)
+    if p_len >= cache_len:
+        # fold the prompt into it: slot j holds the LAST prompt position
+        # that is j (mod w). Scale entries (kv_q8) are (B, H_kv, S): the
+        # same slot axis, the same fold.
+        j = jnp.arange(cache_len)
+        src = p_len - 1 - ((p_len - 1 - j) % cache_len)
+    return c[:, :, src]
+
+
+def _differential(params: Params, p: str, a, depth: int, eps: float):
+    """Differential attention's second half (Ye et al. 2024,
+    arXiv:2410.05258): ``a`` (B, Q, pairs, 2 g, 2 D) float32 holds, for
+    each key/value pair-head and each of its ``g`` query pairs, the two
+    softmaxes' sums over the 2 D-wide values ``[v1 | v2]``. Returns
+    ``RMSNorm(a1 - lam a2) * (1 - lam0)`` as (B, Q, pairs * g * 2 D):
+    ``lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam0`` from the layer's
+    four vectors, ``lam0 = 0.8 - 0.6 exp(-0.3 depth)``."""
+    b, q_len, pairs, rows, width = a.shape
+    lam0 = 0.8 - 0.6 * float(np.exp(-0.3 * depth))
+    lq1, lk1, lq2, lk2 = params[f"{p}_lam"].astype(jnp.float32)
+    lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + lam0
+    a = a.reshape(b, q_len, pairs, rows // 2, 2, width)
+    diff = a[..., 0, :] - lam * a[..., 1, :]
+    diff = _rms_norm(diff, params[f"{p}_sub_g"].astype(jnp.float32), eps)
+    return (diff * (1.0 - lam0)).reshape(b, q_len, -1)
+
+
+def _pair_queries(q):
+    """(B, L, pairs, 2 g, D) queries, rows alternating the pair's first
+    and second query, as 2 D-wide rows against ``[k1 | k2]``: ``[q1 |
+    0]`` and ``[0 | q2]``."""
+    first = (jnp.arange(q.shape[3]) % 2 == 0)[:, None]
+    zero = jnp.zeros_like(q)
+    return jnp.concatenate([jnp.where(first, q, zero),
+                            jnp.where(first, zero, q)], axis=-1)
+
+
+def _biased(params: Params, name: str, y):
+    """``y @ W`` and the bias where the dict holds one."""
+    out = _mm(params, f"{name}_W", y)
+    bias = params.get(f"{name}_b")
+    return out if bias is None else out + bias
+
+
+@dataclasses.dataclass(frozen=True)
 class GroupedQuery(_Kind):
     """Grouped-query attention over (k, v) caches: a fused qkv
     projection, rope on q and k, the caller's attention over a full
@@ -195,73 +301,180 @@ class GroupedQuery(_Kind):
     head) rows contiguous, the ops/decode.py layout contract —, int8
     with f32 scales a row (``L{i}_{k,v}s``) under ``kv_q8``, and a
     rolling buffer of ``window`` slots where the window is shorter than
-    the decode."""
+    the decode.
+
+    ``window`` is the layer's own (0 = full causal). ``differential``
+    (arXiv:2410.05258): heads pair up, ``(q1, q2)`` = heads ``(2 j, 2 j
+    + 1)`` and ``(k1, k2)``, ``(v1, v2)`` = kv heads ``(2 m, 2 m + 1)``,
+    query pair ``j`` on kv pair ``j // g``; the layer's output is
+    ``RMSNorm(softmax(q1 k1) [v1|v2] - lam softmax(q2 k2) [v1|v2])``
+    (:func:`_differential`; ``depth`` sets ``lam0``). A pair is held as
+    ONE row twice as wide, ``[k1|k2]`` and ``[v1|v2]``, and meets the
+    queries ``[q1|0]`` and ``[0|q2]``: the same kernel at H_kv / 2
+    heads, 2 g query rows and 2 D. Such a cache has one form,
+    (B, H_kv / 2, S, 2 D), rolling where windowed: prefill hands it out
+    as the scan carries it, it has no int8 form, and its attention over
+    a full sequence is its own (the caller's ``attn_fn`` knows one
+    window and one width). ``shares``: later layers read this layer's
+    keys and values (:class:`Cross`), so a full-sequence forward hands
+    them on."""
+    window: int = 0
+    differential: bool = False
+    depth: int = 0
+    shares: bool = False
+
     leaves = ("k", "v")
+
+    @property
+    def one_form(self) -> bool:
+        return self.differential
+
+    def _shape(self) -> tuple:
+        """(kv rows, query rows a kv row, a row's width, the scores'
+        scale or None for the width's own) as attention meets them."""
+        cfg, hkv, hd = self.cfg, kv_heads(self.cfg), head_dim(self.cfg)
+        g = cfg.n_heads // hkv
+        if self.differential:
+            return hkv // 2, 2 * g, 2 * hd, hd ** -0.5
+        return hkv, g, hd, None
 
     def init(self, keys, dtype, p: str) -> Params:
         cfg, hd = self.cfg, head_dim(self.cfg)
         cols = (cfg.n_heads + 2 * kv_heads(cfg)) * hd
-        return {f"{p}_qkv_W": _dense(next(keys), (cfg.d_model, cols), dtype),
-                f"{p}_out_W": _dense(next(keys), (cfg.n_heads * hd,
-                                                  cfg.d_model), dtype)}
+        out = {f"{p}_qkv_W": _dense(next(keys), (cfg.d_model, cols), dtype),
+               f"{p}_out_W": _dense(next(keys), (cfg.n_heads * hd,
+                                                 cfg.d_model), dtype)}
+        if self.differential:
+            out.update(_differential_init(next(keys), dtype, p, hd))
+            out[f"{p}_qkv_b"] = jnp.zeros((cols,), dtype)
+            out[f"{p}_out_b"] = jnp.zeros((cfg.d_model,), dtype)
+        return out
 
     def project(self, params: Params, p: str, y, pos):
         """q (B, L, H, hd) and the rows (k, v), each (B, L, H_kv, hd),
         of ``y`` at ``pos``. With rope k is the ROTATED one: what
-        attention consumes and what the cache stores."""
+        attention consumes and what the cache stores. Differential: q
+        (B, L, H_kv / 2, 2 g, 2 hd) and rows (B, L, H_kv / 2, 2 hd)."""
         cfg = self.cfg
         b, l, _ = y.shape
         h, hkv, hd = cfg.n_heads, kv_heads(cfg), head_dim(cfg)
-        qkv = _mm(params, f"{p}_qkv_W", y)  # (B, L, (H+2Hkv)·hd) MXU
+        qkv = _biased(params, f"{p}_qkv", y)  # (B, L, (H+2Hkv)·hd) MXU
         q = qkv[..., :h * hd].reshape(b, l, h, hd)
         k = qkv[..., h * hd:(h + hkv) * hd].reshape(b, l, hkv, hd)
         v = qkv[..., (h + hkv) * hd:].reshape(b, l, hkv, hd)
         if cfg.rope:
             q = _rope(q, pos, cfg.rope_base)
             k = _rope(k, pos, cfg.rope_base)
+        if self.differential:
+            rows, g, width, _ = self._shape()
+            q = _pair_queries(q.reshape(b, l, rows, g, hd))
+            k, v = k.reshape(b, l, rows, width), v.reshape(b, l, rows, width)
         return q, (k, v)
 
-    def full(self, params: Params, p: str, y, pos, attn_fn):
-        q, rows = self.project(params, p, y, pos)
-        a = attn_fn(q, *rows).reshape(*y.shape[:2], -1)
-        return _mm(params, f"{p}_out_W", a), rows
+    def _out(self, params: Params, p: str, a, y):
+        """Attention's sums ``a`` (B, Q, ...) through what follows them:
+        the differential half where the kind has it, the out
+        projection."""
+        if self.differential:
+            a = _differential(params, p, a.astype(jnp.float32), self.depth,
+                              self.cfg.norm_eps)
+        a = a.astype(y.dtype).reshape(*y.shape[:2], -1)
+        return _biased(params, f"{p}_out", a)
+
+    def _scope(self):
+        if not self.differential:
+            return contextlib.nullcontext()
+        return scope("lm.swa" if self.window else "lm.full")
+
+    def full(self, params: Params, p: str, y, pos, attn_fn, handed=None):
+        with self._scope():
+            q, rows = self.project(params, p, y, pos)
+            if not self.differential:
+                return self._out(params, p, attn_fn(q, *rows), y), rows
+            k, v = (jnp.transpose(row, (0, 2, 1, 3)) for row in rows)
+            if self.shares:
+                handed["kv"] = (k, v)
+            a = cache_attention(q, k, v, pos, pos, window=self.window,
+                                scale=self._shape()[3])
+            return self._out(params, p, a, y), rows
+
+    def chunk(self, params: Params, p: str, y, pos, caches: Params, start,
+              handed=None):
+        """Over the scan's (B, H_kv, S, D) caches. A whole cache takes
+        the chunk's rows, then the queries see it up to their own
+        positions. A rolling buffer is attended BEFORE it is written
+        (the chunk's later rows take the slots of positions that its
+        earlier queries still see): the buffer as it stands, beside the
+        chunk's own rows."""
+        with self._scope():
+            q, rows = self.project(params, p, y, pos)
+            rows_kv, g, width, scale = self._shape()
+            b, c = y.shape[:2]
+            q = q.reshape(b, c, rows_kv, g, width)
+            k, v = (jnp.transpose(row, (0, 2, 1, 3)) for row in rows)
+            kn, vn = self.names(p)
+            cache_len = caches[kn].shape[2]
+            if not _rolls(self, cache_len):
+                caches = _put(caches, {kn: k, vn: v}, (0, 0, start))
+                a = cache_attention(
+                    q, caches[kn], caches[vn], pos, jnp.arange(cache_len),
+                    window=self.window, scale=scale, live=pos[-1] + 1)
+                return self._out(params, p, a, y), caches
+            slot = jnp.arange(cache_len)
+            held = start - 1 - (start - 1 - slot) % cache_len
+            beside = {n: jnp.concatenate([caches[n], row], axis=2)
+                      for n, row in ((kn, k), (vn, v))}
+            a = cache_attention(
+                q, beside[kn], beside[vn], pos,
+                jnp.concatenate([held, pos.astype(held.dtype)]),
+                window=self.window, scale=scale)
+            # slot j then holds the last position that is j (mod w):
+            # one of the chunk's rows, or what it held
+            last = pos[-1]
+            then = last - (last - slot) % cache_len
+            take = jnp.where(then >= start, cache_len + then - start, slot)
+            caches = {**caches, **{n: jnp.take(beside[n], take, axis=2)
+                                   for n in (kn, vn)}}
+            return self._out(params, p, a, y), caches
 
     def step(self, params: Params, p: str, y, t, caches: Params,
-             kv_q8: bool):
-        q, rows = self.project(params, p, y, t[None])
-        b, _, h, hd = q.shape
-        hkv = kv_heads(self.cfg)
-        kn, vn = self.names(p)
-        cache_len = caches[kn].shape[2]
-        roll = _rolls(self.cfg, cache_len)
-        # (B, 1, Hkv, D) → (B, Hkv, 1, D) cache-layout row
-        k, v = (jnp.transpose(row, (0, 2, 1, 3)) for row in rows)
-        # head index = (kv head, group member), kv-head major —
-        # the grouping decode_attention's (B, Hkv, G, D) q expects
-        q = q.reshape(b, hkv, h // hkv, hd)
-        slot = t % cache_len if roll else t
-        scales = {}
-        if kv_q8:
-            (k, ks_row), (v, vs_row) = quantize_kv(k), quantize_kv(v)
-            caches = _put(caches, {kn + "s": ks_row, vn + "s": vs_row},
-                          (0, 0, slot))
-            scales = {"k_scale": caches[kn + "s"],
-                      "v_scale": caches[vn + "s"]}
-        caches = _put(caches, {kn: k, vn: v}, (0, 0, slot))
-        # fused decode attention (ops/decode.py): flash-decode
-        # kernel on TPU, the identical einsum+mask+softmax
-        # composition elsewhere. A cache that does not roll holds the
-        # whole decode inside the window, so slot<=t IS the mask.
-        a = decode_attention(q, caches[kn], caches[vn], t, roll=roll,
-                             backend="auto", **scales)
-        a = a.astype(y.dtype).reshape(b, 1, h * hd)
-        return _mm(params, f"{p}_out_W", a), (caches, None)
+             kv_q8: bool, handed=None):
+        with self._scope():
+            q, rows = self.project(params, p, y, t[None])
+            rows_kv, g, width, scale = self._shape()
+            b = y.shape[0]
+            kn, vn = self.names(p)
+            cache_len = caches[kn].shape[2]
+            roll = _rolls(self, cache_len)
+            # (B, 1, Hkv, D) → (B, Hkv, 1, D) cache-layout row
+            k, v = (jnp.transpose(row, (0, 2, 1, 3)) for row in rows)
+            # head index = (kv head, group member), kv-head major —
+            # the grouping decode_attention's (B, Hkv, G, D) q expects
+            q = q.reshape(b, rows_kv, g, width)
+            slot = t % cache_len if roll else t
+            scales = {}
+            if kv_q8:
+                (k, ks_row), (v, vs_row) = quantize_kv(k), quantize_kv(v)
+                caches = _put(caches, {kn + "s": ks_row, vn + "s": vs_row},
+                              (0, 0, slot))
+                scales = {"k_scale": caches[kn + "s"],
+                          "v_scale": caches[vn + "s"]}
+            caches = _put(caches, {kn: k, vn: v}, (0, 0, slot))
+            # fused decode attention (ops/decode.py): flash-decode
+            # kernel on TPU, the identical einsum+mask+softmax
+            # composition elsewhere. A cache that does not roll holds the
+            # whole decode inside the window, so slot<=t IS the mask.
+            a = decode_attention(q, caches[kn], caches[vn], t, roll=roll,
+                                 backend="auto", scale=scale, **scales)
+            return self._out(params, p, a[:, None], y), (caches, None)
 
     def empty(self, p: str, b: int, total: int, dtype,
               kv_q8: bool = False) -> Params:
-        cfg = self.cfg
-        shape = (b, kv_heads(cfg), _cache_shape(cfg, total)[1])
-        out = {n: jnp.zeros(shape + (head_dim(cfg),),
+        rows_kv, _, width, _ = self._shape()
+        if self.differential:
+            _no_int8(kv_q8)
+        shape = (b, rows_kv, _cache_shape(self, total)[1])
+        out = {n: jnp.zeros(shape + (width,),
                             jnp.int8 if kv_q8 else dtype)
                for n in self.names(p)}
         if kv_q8:
@@ -269,12 +482,30 @@ class GroupedQuery(_Kind):
                         for n in self.names(p)})
         return out
 
+    def padded(self, p: str, rows, total: int, dtype) -> Params:
+        if not self.differential:
+            return super().padded(p, rows, total, dtype)
+        # the one form: the scan's layout, rolled where it rolls
+        roll, cache_len = _cache_shape(self, total)
+        out = {}
+        for name, leaf in zip(self.names(p), rows):
+            p_len = leaf.shape[1]
+            c = jnp.pad(jnp.transpose(leaf, (0, 2, 1, 3)).astype(dtype), (
+                (0, 0), (0, 0), (0, max(0, cache_len - p_len)), (0, 0)))
+            out[name] = _rolled(c, p_len, cache_len) if roll else c
+        return out
+
     def scanned(self, p: str, caches: Params, p_len: int, total: int,
                 kv_q8: bool = False) -> Params:
         """One transpose at the boundary, not one per step; quantized
         under ``kv_q8``; folded into the rolling layout where the
-        window is shorter than ``total``."""
-        roll, cache_len = _cache_shape(self.cfg, total)
+        window is shorter than ``total``. (A differential cache is
+        handed out so already.)"""
+        roll, cache_len = _cache_shape(self, total)
+        if self.differential:
+            _no_int8(kv_q8)
+            out = {n: caches[n] for n in self.names(p)}
+            return _with_snapshot(out, p) if roll else out
         out = {n: jnp.transpose(caches[n], (0, 2, 1, 3))
                for n in self.names(p)}
         if kv_q8:
@@ -284,23 +515,29 @@ class GroupedQuery(_Kind):
             out = quant
         if not roll:
             return out
-        # positions 0..p_len-1 land in slots 0..p_len-1 and the prefill
-        # cache is already zero-padded beyond them — a plain truncation IS
-        # the rolling layout
-        src = slice(cache_len)
-        if p_len >= cache_len:
-            # fold the prompt cache into it: slot j holds the LAST prompt
-            # position ≡ j (mod w). Scale entries (kv_q8) are
-            # (B, H_kv, S) — same slot axis, same fold.
-            j = jnp.arange(cache_len)
-            src = p_len - 1 - ((p_len - 1 - j) % cache_len)
-        return {n: c[:, :, src] for n, c in out.items()}
+        return {n: _rolled(c, p_len, cache_len) for n, c in out.items()}
+
+    def turn_start(self, p: str, caches: Params, start) -> Params:
+        """A differential layer's rolling buffer keeps a snapshot (a
+        plain one is the caller's to copy, as ``decode_from`` says)."""
+        if f"{p}_at0" not in caches:
+            return {}
+        return _taken_back(self.names(p), p, caches, start)
+
+
+def _differential_init(key, dtype, p: str, hd: int) -> Params:
+    """The differential half's own weights: the four ``lam`` vectors
+    (lq1, lk1, lq2, lk2; N(0, 0.1) as the paper draws them) and the
+    gain of the norm over a pair's 2 hd values."""
+    return {f"{p}_lam": 0.1 * jax.random.normal(key, (4, hd), dtype),
+            f"{p}_sub_g": jnp.ones((2 * hd,), dtype)}
 
 
 def _no_int8(kv_q8: bool) -> None:
     if kv_q8:
-        raise ValueError("kv_q8 quantizes grouped-query caches; a latent "
-                         "cache has no int8 form")
+        raise ValueError("kv_q8 quantizes plain grouped-query caches; a "
+                         "latent cache, a differential pair's and a "
+                         "recurrent state have no int8 form")
 
 
 # query rows (batch x positions) of a latent-attention forward over a
@@ -327,6 +564,7 @@ class Latent(_Kind):
     query before the rope. The scan carries the caches as prefill hands
     them out, in the model's own type: no latent cache has an int8 form
     (``kv_q8`` is refused), with or without the indexer."""
+    one_form = True
 
     @property
     def leaves(self) -> tuple:
@@ -502,3 +740,233 @@ class Latent(_Kind):
                 kv_q8: bool = False) -> Params:
         _no_int8(kv_q8)
         return {name: caches[name] for name in self.names(p)}
+
+
+def _inner(cfg) -> tuple:
+    """(inner width E, state N, taps K, dt rank R) of the stack's
+    state-space layers."""
+    hy = cfg.hybrid
+    return (hy.ssm_expand * cfg.d_model, hy.ssm_state, hy.ssm_conv,
+            hy.ssm_rank or -(-cfg.d_model // 16))
+
+
+@dataclasses.dataclass(frozen=True)
+class StateSpace(_Kind):
+    """A Mamba-1 selective state-space mixer (``cfg.hybrid`` has its
+    sizes; ``ops/ssm.py`` the scan):
+
+        [u, z] = y W_in;  u = silu(conv1d(u) + b);  [dt, B, C] = u W_x
+        delta = softplus(dt W_dt + b_dt);  A = -exp(A_log)
+        h_t = exp(delta_t A) h_{t-1} + (delta_t u_t) outer B_t
+        s_t = h_t C_t + D u_t;  out = (s_t silu(z_t)) W_out
+
+    What a session caches has no axis of positions: the convolution's
+    tail ``conv`` (B, K - 1, E), the last inputs before the next
+    position, in the model's type, and the state ``ssm`` (B, N, E) in
+    float32. ``hands``: the layer leaves ``s``, the scan's output
+    BEFORE the ``z`` gate, as ``handed["memory"]`` for the gated memory
+    units after it."""
+    hands: bool = False
+
+    leaves = ("conv", "ssm")
+    shares = True
+    one_form = True
+
+    def init(self, keys, dtype, p: str) -> Params:
+        d = self.cfg.d_model
+        e, n, taps, r = _inner(self.cfg)
+        k = iter(jax.random.split(next(keys), 5))
+        # Mamba's own: A_log = log(1..N) a channel, the step's bias the
+        # inverse softplus of a log-uniform draw in [0.001, 0.1]
+        dt = jnp.exp(jax.random.uniform(next(k), (e,), jnp.float32,
+                                        np.log(1e-3), np.log(1e-1)))
+        f32 = jnp.float32
+        return {
+            f"{p}_in_W": _dense(next(k), (d, 2 * e), dtype),
+            f"{p}_conv_W": _dense(next(k), (taps, e), dtype),
+            f"{p}_conv_b": jnp.zeros((e,), dtype),
+            f"{p}_x_W": _dense(next(k), (e, r + 2 * n), dtype),
+            f"{p}_dt_W": _dense(next(k), (r, e), dtype),
+            f"{p}_dt_b": (dt + jnp.log(-jnp.expm1(-dt))).astype(f32),
+            f"{p}_A_log": jnp.broadcast_to(jnp.log(jnp.arange(
+                1, n + 1, dtype=f32))[:, None], (n, e)),
+            f"{p}_D": jnp.ones((e,), f32),
+            f"{p}_out_W": _dense(next(keys), (e, d), dtype)}
+
+    def _mix(self, params: Params, p: str, y, tail, h, handed):
+        """The mixer over ``y`` (B, L, d) from the tail and state before
+        it. Returns (out (B, L, d), the tail and the state after)."""
+        e, n, _, r = _inner(self.cfg)
+        with scope("lm.ssm"):
+            uz = _mm(params, f"{p}_in_W", y)
+            u, tail = _ssm.causal_conv(uz[..., :e], tail,
+                                       params[f"{p}_conv_W"],
+                                       params[f"{p}_conv_b"])
+            u = jax.nn.silu(u).astype(y.dtype)
+            dbc = _mm(params, f"{p}_x_W", u)
+            delta = jax.nn.softplus(
+                jnp.dot(dbc[..., :r], params[f"{p}_dt_W"],
+                        preferred_element_type=jnp.float32)
+                + params[f"{p}_dt_b"])
+            a = -jnp.exp(params[f"{p}_A_log"].astype(jnp.float32))
+            args = (a, dbc[..., r:r + n], dbc[..., r + n:], params[f"{p}_D"])
+            if y.shape[1] == 1:     # a decode step
+                h, s = _ssm.selective_step(h, u[:, 0], delta[:, 0], args[0],
+                                           args[1][:, 0], args[2][:, 0],
+                                           args[3])
+                s = s[:, None]
+            else:
+                h, s = _ssm.selective_scan(h, u, delta, *args)
+            if self.hands:
+                handed["memory"] = s.astype(y.dtype)
+            gated = s * jax.nn.silu(uz[..., e:].astype(jnp.float32))
+            return (_mm(params, f"{p}_out_W", gated.astype(y.dtype)),
+                    tail.astype(y.dtype), h)
+
+    def full(self, params: Params, p: str, y, pos, attn_fn, handed):
+        fresh = self.empty(p, y.shape[0], 0, y.dtype)
+        out, tail, h = self._mix(params, p, y, *fresh.values(), handed)
+        return out, (tail, h)
+
+    def chunk(self, params: Params, p: str, y, pos, caches: Params, start,
+              handed):
+        cn, sn = self.names(p)
+        out, tail, h = self._mix(params, p, y, caches[cn], caches[sn], handed)
+        return out, {**caches, cn: tail, sn: h}
+
+    def step(self, params: Params, p: str, y, t, caches: Params,
+             kv_q8: bool, handed):
+        out, caches = self.chunk(params, p, y, t[None], caches, t, handed)
+        return out, (caches, None)
+
+    def empty(self, p: str, b: int, total: int, dtype,
+              kv_q8: bool = False) -> Params:
+        _no_int8(kv_q8)
+        e, n, taps, _ = _inner(self.cfg)
+        cn, sn = self.names(p)
+        return {cn: jnp.zeros((b, taps - 1, e), dtype),
+                sn: jnp.zeros((b, n, e), _ssm.STATE_DTYPE)}
+
+    def padded(self, p: str, rows, total: int, dtype) -> Params:
+        return dict(zip(self.names(p), rows))
+
+    def scanned(self, p: str, caches: Params, p_len: int, total: int,
+                kv_q8: bool = False) -> Params:
+        _no_int8(kv_q8)
+        return _with_snapshot({name: caches[name] for name in self.names(p)},
+                              p)
+
+    def turn_start(self, p: str, caches: Params, start) -> Params:
+        return _taken_back(self.names(p), p, caches, start)
+
+
+class _Cacheless(_Kind):
+    """A mixer that caches nothing of its own."""
+    shares = True
+    one_form = True
+
+    def empty(self, p: str, b: int, total: int, dtype,
+              kv_q8: bool = False) -> Params:
+        return {}
+
+    def padded(self, p: str, rows, total: int, dtype) -> Params:
+        return {}
+
+    def scanned(self, p: str, caches: Params, p_len: int, total: int,
+                kv_q8: bool = False) -> Params:
+        return {}
+
+
+@dataclasses.dataclass(frozen=True)
+class Cross(_Cacheless):
+    """Cross-attention of a decoder that shares one cache (YOCO, Sun et
+    al. 2024): a query projection and an out projection of its own;
+    keys and values are layer ``source``'s, read where that layer left
+    them (``caches[L{source}_k]``, ``_v``, which every form is handed;
+    in a full-sequence forward, which has no caches, ``handed["kv"]``).
+    Causal: a position reads the rows up to its own. Differential, as
+    :class:`GroupedQuery`'s pairs are (the source's cache holds pairs).
+    Where ``y`` holds fewer positions than ``pos`` names, they are the
+    LAST ones (a prefill runs these layers for the last position
+    only)."""
+    source: int = 0
+    depth: int = 0
+
+    def init(self, keys, dtype, p: str) -> Params:
+        cfg, hd = self.cfg, head_dim(self.cfg)
+        width = cfg.n_heads * hd
+        return {f"{p}_q_W": _dense(next(keys), (cfg.d_model, width), dtype),
+                f"{p}_q_b": jnp.zeros((width,), dtype),
+                f"{p}_out_W": _dense(next(keys), (width, cfg.d_model), dtype),
+                f"{p}_out_b": jnp.zeros((cfg.d_model,), dtype),
+                **_differential_init(next(keys), dtype, p, hd)}
+
+    def _queries(self, params: Params, p: str, y):
+        cfg, hd = self.cfg, head_dim(self.cfg)
+        pairs = kv_heads(cfg) // 2
+        q = _biased(params, f"{p}_q", y).reshape(
+            *y.shape[:2], pairs, cfg.n_heads // pairs, hd)
+        return _pair_queries(q), hd ** -0.5
+
+    def _out(self, params: Params, p: str, a, y):
+        a = _differential(params, p, a.astype(jnp.float32), self.depth,
+                          self.cfg.norm_eps)
+        return _biased(params, f"{p}_out", a.astype(y.dtype))
+
+    def _over(self, params: Params, p: str, y, pos, k, v, live=None):
+        with scope("lm.cross"):
+            q, scale = self._queries(params, p, y)
+            a = cache_attention(q, k, v, pos[-y.shape[1]:],
+                                jnp.arange(k.shape[2]), scale=scale,
+                                live=live)
+            return self._out(params, p, a, y)
+
+    def full(self, params: Params, p: str, y, pos, attn_fn, handed):
+        return self._over(params, p, y, pos, *handed["kv"]), ()
+
+    def chunk(self, params: Params, p: str, y, pos, caches: Params, start,
+              handed):
+        src = f"L{self.source}"
+        return self._over(params, p, y, pos, caches[f"{src}_k"],
+                          caches[f"{src}_v"], live=pos[-1] + 1), caches
+
+    def step(self, params: Params, p: str, y, t, caches: Params,
+             kv_q8: bool, handed):
+        src = f"L{self.source}"
+        with scope("lm.cross"):
+            q, scale = self._queries(params, p, y)
+            a = decode_attention(q[:, 0], caches[f"{src}_k"],
+                                 caches[f"{src}_v"], t, backend="auto",
+                                 scale=scale)
+            return self._out(params, p, a[:, None], y), (caches, None)
+
+
+class GatedMemory(_Cacheless):
+    """A gated memory unit (Ren et al. 2025, arXiv:2507.06607): the
+    memory ``m`` an earlier state-space layer left of the SAME
+    positions (``handed["memory"]``, its scan's output before the
+    gate), gated by the layer's own input: ``out = (m * silu(y W_1))
+    W_2``. No state, no cache; where ``y`` holds fewer positions than
+    the memory, they are the last ones."""
+
+    def init(self, keys, dtype, p: str) -> Params:
+        d, e = self.cfg.d_model, _inner(self.cfg)[0]
+        return {f"{p}_in_W": _dense(next(keys), (d, e), dtype),
+                f"{p}_out_W": _dense(next(keys), (e, d), dtype)}
+
+    def _gate(self, params: Params, p: str, y, handed):
+        with scope("lm.gmu"):
+            m = handed["memory"][:, -y.shape[1]:]
+            gate = jax.nn.silu(_mm(params, f"{p}_in_W", y))
+            return _mm(params, f"{p}_out_W", m * gate)
+
+    def full(self, params: Params, p: str, y, pos, attn_fn, handed):
+        return self._gate(params, p, y, handed), ()
+
+    def chunk(self, params: Params, p: str, y, pos, caches: Params, start,
+              handed):
+        return self._gate(params, p, y, handed), caches
+
+    def step(self, params: Params, p: str, y, t, caches: Params,
+             kv_q8: bool, handed):
+        return self._gate(params, p, y, handed), (caches, None)

@@ -35,8 +35,8 @@ from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from lua_mapreduce_tpu.models.attention_kinds import (
-    GroupedQuery, Latent, Params, _dense, _mm, _norm, _rope, head_dim,
-    kv_heads)
+    Cross, GatedMemory, GroupedQuery, Latent, Params, StateSpace, _dense,
+    _mm, _norm, _rope, head_dim, kv_heads)
 from lua_mapreduce_tpu.ops.attention import flash_attention
 from lua_mapreduce_tpu.ops.q8 import quantize_q8
 from lua_mapreduce_tpu.parallel import moe as _moe
@@ -86,6 +86,61 @@ class LatentAttention:
 
 
 @dataclasses.dataclass(frozen=True)
+class HybridStack:
+    """A stack whose layers differ in their MIXER (SambaY, Ren et al.
+    2025, arXiv:2507.06607, is the one written for): ``kinds[i]`` says
+    what layer ``i`` has in attention's place.
+
+    - ``"ssm"``: a Mamba-1 selective state space (``ssm_*``: the
+      state's size, the convolution's taps, the inner width as a
+      multiple of ``d_model``, the rank of the step's projection, 0 =
+      ceil(d_model / 16)); it caches a state, not positions;
+    - ``"swa"``: self-attention over the last ``window`` positions (a
+      rolling cache); ``"full"``: over all of them (the one cache that
+      grows);
+    - ``"cross"``: attention with queries of its own over the keys and
+      values that layer ``kv_from`` (a ``"full"`` one) cached;
+    - ``"gmu"``: a gated memory unit over what layer ``memory_from``
+      (an ``"ssm"`` one) made of the same positions.
+
+    The two references point BACK: a layer reads what an earlier one
+    left. Every attention layer is differential (pairs of heads,
+    ``models/attention_kinds.GroupedQuery``). Layers from
+    :attr:`cached_layers` on cache nothing, so a prefill needs them for
+    its last position only."""
+    kinds: Tuple[str, ...]
+    window: int
+    kv_from: int
+    memory_from: int
+    ssm_state: int = 16
+    ssm_conv: int = 4
+    ssm_expand: int = 2
+    ssm_rank: int = 0
+
+    @property
+    def cached_layers(self) -> int:
+        """Layers up to the last that caches something."""
+        return 1 + max(i for i, k in enumerate(self.kinds)
+                       if k in ("ssm", "swa", "full"))
+
+    @staticmethod
+    def sambay(n_layers: int, window: int, **sizes) -> "HybridStack":
+        """The SambaY pattern: a self-decoder of ``n_layers // 2``
+        layers alternating state space and window attention, one
+        state-space and one full-attention layer, then a cross-decoder
+        alternating gated memory units (on the last state-space layer)
+        and cross-attention (on the full-attention layer's cache)."""
+        half = n_layers // 2
+        kinds = tuple(
+            ("ssm" if i % 2 == 0 else "swa") if i < half
+            else "ssm" if i == half else "full" if i == half + 1
+            else ("gmu" if i % 2 == 0 else "cross")
+            for i in range(n_layers))
+        return HybridStack(kinds, window, kv_from=half + 1,
+                           memory_from=half, **sizes)
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab: int = 256
     d_model: int = 128
@@ -108,6 +163,11 @@ class TransformerConfig:
     # global positions). head_dim must be even.
     rope: bool = False
     rope_base: float = 10000.0
+    # None = the scheme ``rope`` says: rotary, or else a learned table
+    # added to the embeddings (``pos_emb``). "none" = no positional
+    # encoding at all (a model whose state-space and window layers carry
+    # the order)
+    positions: Optional[str] = None
     # "ln" (pre-LN with bias) or "rms" (RMSNorm, scale only)
     norm: str = "ln"
     norm_eps: float = 1e-5
@@ -116,6 +176,9 @@ class TransformerConfig:
     # None = grouped-query attention over (k, v) caches; else latent
     # attention over a latent cache, sparse where it has an indexer
     latent: Optional[LatentAttention] = None
+    # None = every layer has the one attention above; else the mixer is
+    # chosen by layer
+    hybrid: Optional[HybridStack] = None
     # "gelu" (2-matmul MLP with biases) or "swiglu" (gate/up/down,
     # no biases — the llama-style FFN)
     ffn: str = "gelu"
@@ -211,10 +274,30 @@ def moe_layer(cfg: TransformerConfig, i: int) -> bool:
 
 
 def attention_kind(cfg: TransformerConfig, i: int):
-    """Layer ``i``'s attention: its weights, what a position caches, the
-    cache's format and the attention over it
-    (models/attention_kinds.py)."""
-    return GroupedQuery(cfg) if cfg.latent is None else Latent(cfg)
+    """Layer ``i``'s attention (or what it has in attention's place):
+    its weights, what a position caches, the cache's format and the
+    attention over it (models/attention_kinds.py). The one place that
+    reads the configuration for it."""
+    if cfg.latent is not None:
+        return Latent(cfg)
+    hy = cfg.hybrid
+    if hy is None:
+        return GroupedQuery(cfg, window=cfg.window)
+    kind = hy.kinds[i]
+    if kind == "ssm":
+        return StateSpace(cfg, hands=i == hy.memory_from)
+    if kind == "gmu":
+        return GatedMemory(cfg)
+    if kind == "cross":
+        return Cross(cfg, source=hy.kv_from, depth=i)
+    return GroupedQuery(cfg, window=hy.window if kind == "swa" else 0,
+                        differential=True, depth=i, shares=i == hy.kv_from)
+
+
+def _has_pos_table(cfg: TransformerConfig) -> bool:
+    """Whether positions are a learned table added to the embeddings
+    (rope turns q and k inside the layers; "none" has neither)."""
+    return not cfg.rope and cfg.positions is None
 
 
 def _check_arch(cfg: TransformerConfig) -> None:
@@ -270,14 +353,51 @@ def _check_arch(cfg: TransformerConfig) -> None:
                              f"indexer that selects nothing")
     if cfg.window < 0:
         raise ValueError(f"window must be >= 0, got {cfg.window}")
+    if cfg.positions not in (None, "none") or (cfg.rope and cfg.positions):
+        raise ValueError(f"positions={cfg.positions!r}: None (rope, or the "
+                         f"learned table) or 'none', which takes no rope")
+    if cfg.hybrid is not None:
+        _check_hybrid(cfg)
+
+
+def _check_hybrid(cfg: TransformerConfig) -> None:
+    """What a hybrid stack is written for: pre-LN layers with a dense
+    SwiGLU FFN, no rope (its attention layers turn nothing), pairs of
+    heads, and references that point back to a layer of the right
+    kind."""
+    hy = cfg.hybrid
+    known = ("ssm", "swa", "full", "cross", "gmu")
+    if len(hy.kinds) != cfg.n_layers or set(hy.kinds) - set(known):
+        raise ValueError(f"hybrid.kinds names each of the {cfg.n_layers} "
+                         f"layers' mixer, one of {known}; got {hy.kinds}")
+    if (cfg.latent is not None or cfg.window or cfg.rope or cfg.moe_experts
+            or cfg.remat or cfg.norm != "ln" or cfg.ffn != "swiglu"):
+        raise ValueError(
+            "a hybrid stack is written for norm='ln', ffn='swiglu' and no "
+            "rope; its windows are the stack's (window=0), and it takes "
+            "no latent attention, experts or remat")
+    if cfg.n_heads % 2 or kv_heads(cfg) % 2 or hy.window < 1:
+        raise ValueError("a hybrid stack's attention is differential: "
+                         "n_heads and n_kv_heads are even; its 'swa' "
+                         "layers need hybrid.window >= 1")
+    for what, kind, of, readers in (("kv_from", "full", hy.kv_from, "cross"),
+                                    ("memory_from", "ssm", hy.memory_from,
+                                     "gmu")):
+        first = min((i for i, k in enumerate(hy.kinds) if k == readers),
+                    default=cfg.n_layers)
+        if not (0 <= of < first and hy.kinds[of] == kind):
+            raise ValueError(
+                f"hybrid.{what}={of} must be a {kind!r} layer before the "
+                f"first {readers!r} layer ({first}); kinds {hy.kinds}")
 
 
 def _check_sharded(cfg: TransformerConfig) -> None:
     """What the sharded forward and the train steps cannot run yet."""
-    if cfg.latent is not None or (cfg.moe_experts
-                                  and cfg.moe_router == "grouped"):
+    if (cfg.latent is not None or cfg.hybrid is not None
+            or (cfg.moe_experts and cfg.moe_router == "grouped")):
         raise ValueError(
-            "latent attention and the grouped expert layer are served "
+            "latent attention, a hybrid stack and the grouped expert "
+            "layer are served "
             "(prefill, decode_from, greedy_decode, transformer_apply); "
             "the sharded forward and the train steps run grouped-query "
             "attention and the switch MoE")
@@ -310,14 +430,16 @@ def init_transformer(key, cfg: TransformerConfig = TransformerConfig(),
     _check_arch(cfg)
     d, ff = cfg.d_model, cfg.d_ff
     params: Params = {}
-    keys = iter(jax.random.split(key, 2 + 5 * cfg.n_layers))
+    # (a hybrid stack's attention layers draw a sixth key)
+    keys = iter(jax.random.split(
+        key, 2 + (6 if cfg.hybrid else 5) * cfg.n_layers))
 
     def dense(shape):
         return _dense(next(keys), shape, dtype)
 
     params["tok_emb"] = 0.02 * jax.random.normal(
         next(keys), (cfg.vocab, d), dtype)
-    if not cfg.rope:        # rope needs no position table
+    if _has_pos_table(cfg):     # rope needs no position table
         params["pos_emb"] = 0.02 * jax.random.normal(
             next(keys), (cfg.max_seq, d), dtype)
     for i in range(cfg.n_layers):
@@ -465,15 +587,26 @@ def _layer(params: Params, i: int, x, cfg: TransformerConfig, attend,
         return x + out, aux, kept
 
 
+def _given(kind, handed: Optional[dict]) -> dict:
+    """The keyword under which a kind that takes part in a hybrid
+    stack's hand-over (``kind.shares``) is given ``handed``: the dict in
+    which the layers of ONE forward (a sequence, a chunk, a decode
+    step) leave what later layers of that forward read, made where the
+    forward's layer loop is. Nothing for any other kind."""
+    return {"handed": handed} if kind.shares else {}
+
+
 def _block(params: Params, i: int, x, cfg: TransformerConfig, attn_fn,
-           pos, moe_axis: Optional[str] = None):
+           pos, moe_axis: Optional[str] = None,
+           handed: Optional[dict] = None):
     """The layer over a full sequence; ``attn_fn(q, k, v) -> out``
     supplies the (possibly sequence-parallel) attention where the kind
     takes one; ``pos`` are the GLOBAL positions of the L rows (rope
     consumes them; ignored otherwise). Returns (x, moe_aux, what the
     layer cached: the prefill path hands it out as the decode cache)."""
     return _layer(params, i, x, cfg,
-                  lambda kind, p, y: kind.full(params, p, y, pos, attn_fn),
+                  lambda kind, p, y: kind.full(params, p, y, pos, attn_fn,
+                                               **_given(kind, handed)),
                   moe_axis)
 
 
@@ -486,19 +619,25 @@ def _check_seq(global_len: int, cfg: TransformerConfig) -> None:
 
 
 def _forward(params: Params, tokens, pos, cfg: TransformerConfig,
-             attn_fn, block=None):
+             attn_fn, block=None, last_only: bool = False):
     """Shared body: tokens (B, L) int32, pos (L,) global positions;
     ``block`` swaps the decoder-block implementation (the 3-D form
     passes its tensor-parallel block) — one forward for every path.
     Returns (logits, summed moe aux loss; 0.0 for dense blocks, what
-    each layer cached, where the block hands that back)."""
-    block = block or _block
+    each layer cached, where the block hands that back). With
+    ``last_only`` the logits are wanted for the last position alone: a
+    hybrid stack's layers that cache nothing then run on that position
+    only (the others' outputs would feed no cache and no logit)."""
+    # what a hybrid stack's layers hand on, for this one forward
+    block = block or functools.partial(_block, handed={})
     with scope("lm.embed"):
         x = params["tok_emb"][tokens]
-        if not cfg.rope:
+        if _has_pos_table(cfg):
             x = x + params["pos_emb"][pos]   # rope positions live in-block
     aux_total, cached = 0.0, []
     for i in range(cfg.n_layers):
+        if last_only and cfg.hybrid and i == cfg.hybrid.cached_layers:
+            x = x[:, -1:]
         def run_block(p, xx, _i=i):
             return block(p, _i, xx, cfg, attn_fn, pos)
         if cfg.remat:
@@ -535,8 +674,15 @@ def prefill(params: Params, prompt, *,
     latent attention ``L{i}_ckv -> (B, total, kv_rank + rope_dim)`` and,
     where it has an indexer, ``L{i}_ik -> (B, total, index_dim)``;
     last_logits is (B, vocab). :func:`decode_caches` turns them into
-    what :func:`decode_from` scans over. With ``chunk`` (latent
-    attention, single-device; it must divide P) the prompt goes through
+    what :func:`decode_from` scans over. A hybrid stack's layers hand
+    out what the scan carries (``models/attention_kinds.py``): a
+    state-space layer ``L{i}_conv`` and ``L{i}_ssm`` with no axis of
+    positions, an attention layer ``L{i}_{k,v}`` as (B, H_kv / 2, S,
+    2 Dh) pairs with S the window's slots where the layer has one, a
+    cross layer and a memory unit nothing; the layers that cache
+    nothing run for the last position only. With ``chunk``
+    (single-device, kinds whose caches have that one form: latent
+    attention, a hybrid stack; it must divide P) the prompt goes through
     the layers that many positions at a time over the growing cache, so
     a long prompt's activations exist for one chunk only.
     Dense and MoE configs single-device; the
@@ -554,9 +700,14 @@ def prefill(params: Params, prompt, *,
     tokens = prompt.astype(jnp.int32)
 
     if chunk:
-        if mesh is not None or p_len % chunk:
-            raise ValueError("chunk is for single-device latent attention "
-                             f"and must divide the prompt ({p_len})")
+        if (mesh is not None or p_len % chunk
+                or not all(attention_kind(cfg, i).one_form
+                           for i in range(cfg.n_layers))):
+            raise ValueError(
+                "chunk is for a single device and kinds whose caches "
+                "have one form, prefill's and the scan's (latent "
+                "attention, a hybrid stack's layers), and must divide "
+                f"the prompt ({p_len})")
         return _prefill_chunked(params, tokens, cfg_fwd, total, chunk)
     if mesh is None:
         # backend="auto": the fused flash kernel on TPU — prefilling a
@@ -566,7 +717,8 @@ def prefill(params: Params, prompt, *,
             params, tokens, jnp.arange(p_len), cfg_fwd,
             lambda q, k, v: flash_attention(q, k, v, causal=True,
                                             backend="auto",
-                                            window=cfg.window))
+                                            window=cfg.window),
+            last_only=True)
     else:
         _check_sharded(cfg)
         if cfg.moe_experts:
@@ -613,30 +765,42 @@ def prefill(params: Params, prompt, *,
 
 def _prefill_chunked(params: Params, tokens, cfg: TransformerConfig,
                      total: int, chunk: int):
-    """:func:`prefill` for latent attention, ``chunk`` positions of
-    every row at a time: a chunk writes its rows into the caches and
-    attends what they hold by then (its own positions and all before)."""
+    """:func:`prefill`, ``chunk`` positions of every row at a time: a
+    chunk writes its rows into the caches and attends what they hold by
+    then (its own positions and all before). A hybrid stack's layers
+    that cache nothing (its cross-decoder) run once, on the last
+    position of the last chunk, which is all the last logits need."""
     b, p_len = tokens.shape
     caches = _layer_caches(cfg, lambda kind, p: kind.empty(
         p, b, total, params["tok_emb"].dtype))
+    n_cached = cfg.hybrid.cached_layers if cfg.hybrid else cfg.n_layers
+
+    def layers(x, pos, caches, start, handed, which):
+        for i in which:
+            x, _, caches = _layer(
+                params, i, x, cfg, lambda kind, p, y: kind.chunk(
+                    params, p, y, pos, caches, start,
+                    **_given(kind, handed)))
+        return x, caches
 
     def one_chunk(caches, toks_start):
         toks, start = toks_start
         pos = start + jnp.arange(chunk)
         with scope("lm.embed"):
             x = params["tok_emb"][toks]
-        for i in range(cfg.n_layers):
-            x, _, caches = _layer(
-                params, i, x, cfg, lambda kind, p, y: kind.chunk(
-                    params, p, y, pos, caches, start))
-        return caches, x[:, -1]
+        handed = {}
+        x, caches = layers(x, pos, caches, start, handed, range(n_cached))
+        return caches, (x[:, -1], {k: v[:, -1] for k, v in handed.items()})
 
-    caches, last = lax.scan(
+    caches, (last, handed) = lax.scan(
         one_chunk, caches,
         (tokens.reshape(b, p_len // chunk, chunk).transpose(1, 0, 2),
          jnp.arange(0, p_len, chunk)))
+    x, _ = layers(last[-1][:, None], jnp.arange(p_len - 1, p_len), caches,
+                  p_len - 1, {k: v[-1][:, None] for k, v in handed.items()},
+                  range(n_cached, cfg.n_layers))
     with scope("lm.head"):
-        logits = _head(params, _norm(params, "lnf", last[-1][:, None], cfg))
+        logits = _head(params, _norm(params, "lnf", x, cfg))
     return caches, logits[:, 0].astype(jnp.float32)
 
 
@@ -682,7 +846,9 @@ def decode_caches(caches: Params, *, cfg: TransformerConfig, p_len: int,
     transpose at the boundary, not one per step —, are quantized under
     ``kv_q8`` (int8 rows, ``L{i}_{k,v}s`` scales) and folded into the
     rolling layout where the window is shorter than ``total``. Latent
-    caches are scanned as prefill lays them out."""
+    caches and a hybrid stack's are scanned as prefill lays them out; a
+    state and a differential layer's rolling buffer get room for the one
+    snapshot that lets :func:`decode_from` take a turn back."""
     return _layer_caches(cfg, lambda kind, p: kind.scanned(
         p, caches, p_len, total, kv_q8))
 
@@ -709,14 +875,16 @@ def _decode_step(params: Params, cfg: TransformerConfig, b: int,
         with scope("lm.embed"):
             tok = feed(t, cur)                              # (B,)
             x = params["tok_emb"][tok]                      # (B, D)
-            if not cfg.rope:
+            if _has_pos_table(cfg):
                 x = x + params["pos_emb"][t]
             x = x[:, None, :]                               # (B, 1, D)
         selected, moe_stats = [], []
+        handed = {}     # what a hybrid stack's layers hand on, this step
         for i in range(cfg.n_layers):
             x, _, (caches, idx) = _layer(
                 params, i, x, step_cfg, lambda kind, p, y: kind.step(
-                    params, p, y, t, caches, kv_q8), None, moe_stats)
+                    params, p, y, t, caches, kv_q8, **_given(kind, handed)),
+                None, moe_stats)
             if idx is not None:
                 selected.append(idx[:, 0])
         with scope("lm.head"):
@@ -779,7 +947,12 @@ def decode_from(params: Params, caches: Params, first_ids, start,
     ``>= start`` only, and reads none it has not written, so a cache
     taken back to ``start`` needs no clearing (a ROLLING cache, where
     the window is shorter than the whole, reuses its slots: to take
-    that one back, keep a copy). With ``stats`` a third
+    that one back, keep a copy). A hybrid stack's recurrent states and
+    rolling buffers keep that copy themselves, ONE, of the last turn's
+    ``start``: a turn from the same ``start`` as the turn before finds
+    them as that turn found them (``turn_start`` of
+    models/attention_kinds.py), a turn from anywhere else takes them to
+    stand at its ``start``. With ``stats`` a third
     value holds each step's counters, stacked over the steps:
     ``selected`` (n_new, layers, B, K) the cache positions latent
     attention's indexer chose, -1 where fewer than K existed (no such
@@ -790,8 +963,11 @@ def decode_from(params: Params, caches: Params, first_ids, start,
     _check_arch(cfg)
     if cfg.moe_experts:
         _check_moe(cfg)
+    start = jnp.asarray(start, jnp.int32)
+    caches = {**caches, **_layer_caches(
+        cfg, lambda kind, p: kind.turn_start(p, caches, start))}
     tokens, caches, counters = _scan_from(
-        params, caches, first_ids, jnp.asarray(start, jnp.int32), n_new,
+        params, caches, first_ids, start, n_new,
         cfg=cfg, kv_q8=kv_q8,
         select=_selector(cfg, temperature, top_k, key), stats=stats)
     tokens = jnp.transpose(tokens, (1, 0))
@@ -1549,7 +1725,7 @@ def make_train_step_pp(cfg: TransformerConfig, mesh, optimizer, *,
             pos = jnp.arange(l)
             with scope("lm.embed"):
                 x_micro = p["tok_emb"][tok_m]
-                if not cfg.rope:
+                if _has_pos_table(cfg):
                     x_micro = x_micro + p["pos_emb"][pos]
 
             def stage(x):

@@ -72,7 +72,8 @@ def quantize_kv(x, axis: int = -1):
     return q.astype(jnp.int8), scale
 
 
-def _decode_xla(q, k, v, t, roll: bool, k_scale=None, v_scale=None):
+def _decode_xla(q, k, v, t, roll: bool, k_scale=None, v_scale=None,
+                scale=None):
     """Reference composition — exactly the ops the decode scan ran
     in-line before this module existed (models/transformer.py), with
     the q-length-1 axis dropped and the (B, H_kv, S, D) cache layout.
@@ -94,7 +95,7 @@ def _decode_xla(q, k, v, t, roll: bool, k_scale=None, v_scale=None):
                        k.astype(jnp.bfloat16),
                        preferred_element_type=jnp.float32)
         s = s * k_scale[:, :, None, :]
-    s = s / jnp.sqrt(jnp.float32(d))
+    s = s / jnp.sqrt(jnp.float32(d)) if scale is None else s * scale
     seen = jnp.arange(s_len)[None, None, None, :]
     if roll:
         # rolling containment IS the window (models/transformer.py):
@@ -270,9 +271,10 @@ def _decode_kernel(t_ref, q_ref, k_ref, v_ref, *rest,
 
 @functools.partial(
     jax.jit,
-    static_argnames=("roll", "block_s", "interpret"))
+    static_argnames=("roll", "block_s", "interpret", "scale"))
 def _decode_pallas(q, k, v, t, roll: bool = False, block_s: int = 512,
-                   interpret: bool = False, k_scale=None, v_scale=None):
+                   interpret: bool = False, k_scale=None, v_scale=None,
+                   scale=None):
     b, hkv, g, d = q.shape
     s_len = k.shape[2]
     rows = b * hkv
@@ -288,7 +290,7 @@ def _decode_pallas(q, k, v, t, roll: bool = False, block_s: int = 512,
     qb = q.reshape(rows, g, d)
     kb = k.reshape(rows, s_len, d)
     vb = v.reshape(rows, s_len, d)
-    scale = 1.0 / float(d) ** 0.5
+    scale = 1.0 / float(d) ** 0.5 if scale is None else float(scale)
     tarr = jnp.asarray(t, jnp.int32).reshape(1)
 
     def chunk(ki, t_ref):
@@ -342,7 +344,7 @@ def _decode_pallas(q, k, v, t, roll: bool = False, block_s: int = 512,
 
 def decode_attention(q, k, v, t, *, roll: bool = False,
                      backend: str = "auto", block_s: int = 512,
-                     k_scale=None, v_scale=None):
+                     k_scale=None, v_scale=None, scale=None):
     """One decode position's attention against the KV cache.
 
     q: (B, H_kv, G, D) — the G query heads grouped under each kv head
@@ -355,13 +357,79 @@ def decode_attention(q, k, v, t, *, roll: bool = False,
     per-row scales, shape (B, H_kv, S) — :func:`quantize_kv` produces
     them. Cache HBM traffic halves (the dominant decode byte stream);
     the scales factor out of both contractions so neither path
-    materializes a dequantized cache. Returns f32 (B, H_kv, G, D).
+    materializes a dequantized cache. ``scale`` multiplies the scores
+    (default ``D ** -0.5``; a caller whose rows are wider than its
+    heads says its own). Returns f32 (B, H_kv, G, D).
     """
     if (k_scale is None) != (v_scale is None):
         raise ValueError("k_scale and v_scale must be passed together")
     backend = resolve_backend(backend, "decode_attention")
     if backend == "xla":
-        return _decode_xla(q, k, v, t, roll, k_scale, v_scale)
+        return _decode_xla(q, k, v, t, roll, k_scale, v_scale, scale)
     return _decode_pallas(q, k, v, t, roll=roll, block_s=block_s,
                           interpret=backend == "pallas_interpret",
-                          k_scale=k_scale, v_scale=v_scale)
+                          k_scale=k_scale, v_scale=v_scale, scale=scale)
+
+
+# float32 scores of one key block that `cache_attention` lets exist at a
+# time
+_SCORE_BYTES = 256 << 20
+
+
+def cache_attention(q, k, v, q_pos, k_pos, *, window: int = 0, scale=None,
+                    live=None):
+    """Several query positions against a cache: a prefill chunk's, or a
+    whole sequence's against its own rows. XLA's composition, a block of
+    keys at a time folded into an online softmax, so the scores exist
+    for one block only.
+
+    q: (B, Q, H_kv, G, D); k, v: (B, H_kv, S, D) in the decode layout;
+    ``q_pos`` (Q,) int32 the queries' positions; ``k_pos`` (S,) int32
+    the position each slot holds, negative where it holds none (a
+    rolling buffer's slots are in no order of position). A query sees
+    the slots whose position is at most its own and, with ``window``,
+    fewer than ``window`` behind it; every query sees one at least (its
+    own). ``live``, where given (traced), says that slots from ``live``
+    on hold nothing any query sees: their blocks are not read. Returns
+    f32 (B, Q, H_kv, G, D)."""
+    b, q_len, hkv, g, d = q.shape
+    s_len = k.shape[2]
+    rows = q_len * g
+    kb = max(128, _SCORE_BYTES // (4 * b * hkv * rows) // 128 * 128)
+    kb = min(kb, s_len)
+    scale = d ** -0.5 if scale is None else scale
+    qf = jnp.transpose(q, (0, 2, 1, 3, 4)).reshape(b, hkv, rows, d)
+    row_pos = jnp.repeat(q_pos.astype(jnp.int32), g)[:, None]
+    k_pos = k_pos.astype(jnp.int32)
+
+    def fold(i, state):
+        m, l, acc = state
+        # the last block starts where it still fits; what it then
+        # shares with the block before is masked out
+        start = jnp.minimum(i * kb, s_len - kb)
+        kc = jax.lax.dynamic_slice(k, (0, 0, start, 0), (b, hkv, kb, d))
+        vc = jax.lax.dynamic_slice(v, (0, 0, start, 0), (b, hkv, kb, d))
+        pos = jax.lax.dynamic_slice(k_pos, (start,), (kb,))[None, :]
+        seen = ((pos >= 0) & (pos <= row_pos)
+                & (start + jnp.arange(kb)[None, :] >= i * kb))
+        if window:
+            seen &= row_pos - pos < window
+        s = jnp.einsum("bhmd,bhnd->bhmn", qf, kc,
+                       preferred_element_type=jnp.float32) * scale
+        s = jnp.where(seen, s, _NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.where(seen, jnp.exp(s - m_new), 0.0)
+        l = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + jnp.einsum(
+            "bhmn,bhnd->bhmd", p.astype(v.dtype), vc,
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    init = (jnp.full((b, hkv, rows, 1), _NEG_INF, jnp.float32),
+            jnp.zeros((b, hkv, rows, 1), jnp.float32),
+            jnp.zeros((b, hkv, rows, d), jnp.float32))
+    n = -(-s_len // kb) if live is None else (live + kb - 1) // kb
+    _, l, acc = jax.lax.fori_loop(0, n, fold, init)
+    out = (acc / jnp.maximum(l, 1e-30)).reshape(b, hkv, q_len, g, d)
+    return jnp.transpose(out, (0, 2, 1, 3, 4))

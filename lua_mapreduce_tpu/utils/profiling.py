@@ -33,6 +33,11 @@ LM_SCOPES = ("lm.loss", "lm.embed", "lm.attn", "lm.ffn", "lm.head",
              # indexer's scores and top-k, the gather and attention; or,
              # without an indexer, attention over the whole cache
              "lm.mla", "lm.indexer", "lm.sparse", "lm.latent",
+             # inside lm.attn, a hybrid stack's mixers, projections and
+             # all: the state-space layer, the gated memory unit, and
+             # differential attention over a window, over the whole
+             # cache, and over another layer's cache
+             "lm.ssm", "lm.gmu", "lm.swa", "lm.full", "lm.cross",
              # inside lm.ffn, the grouped expert layer
              "lm.moe.route", "lm.moe.experts", "lm.moe.shared")
 LM_HOST_SPANS = ("lm.shard_batch",)

@@ -18,8 +18,9 @@ import pytest
 
 from lua_mapreduce_tpu.models import attention_kinds as kinds
 from lua_mapreduce_tpu.models import transformer as tfm
-from perfbench import weights, weights_dsv32
+from perfbench import weights, weights_dsv32, weights_phi4flash
 from perfbench.model_dsv32 import program_config as dsv32_program_config
+from perfbench.model_phi4flash import program_config as phi4flash_config
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEED = 2 ** 31 + 5
@@ -82,10 +83,26 @@ def latent():
             jnp.float32, via=jnp.bfloat16))
 
 
+def hybrid():
+    """`tests/test_hybrid_lm.py`'s model: a SambaY stack (state-space and
+    window layers, a full-attention cache that a cross layer reads, a
+    gated memory unit) whose window of 8 is shorter than the prompt, so
+    that the buffers roll; with the seed's weights."""
+    with open(os.path.join(ROOT, "perfbench", "tests", "data",
+                           "tiny-phi4flash.json")) as f:
+        published = json.load(f)
+    return phi4flash_config(published), lambda: weights_phi4flash.finish(
+        published, weights.make_leaves(
+            weights.seed_key(SEED), weights_phi4flash.indexed(published),
+            jnp.float32, via=jnp.bfloat16))
+
+
 @pytest.mark.parametrize("model,kind", [
     (lambda: dense(59), None), (latent, None),
     (lambda: dense(61), Renamed), (lambda: dense(67), RunningMean),
-], ids=["grouped-query", "latent", "renamed-leaves", "running-mean"])
+    (hybrid, None),
+], ids=["grouped-query", "latent", "renamed-leaves", "running-mean",
+        "hybrid"])
 def test_every_entry_serves_a_kind_as_the_full_forward_does(
         model, kind, monkeypatch):
     """`greedy_decode` both ways, `prefill` + `decode_caches` +
@@ -108,6 +125,17 @@ def test_every_entry_serves_a_kind_as_the_full_forward_does(
     caches, last = tfm.prefill(params, prompt, cfg=cfg, total=18)
     if kind is not None:
         assert {n.split("_", 1)[1] for n in caches} == set(kind.leaves)
+    if cfg.hybrid is not None:
+        # the same caches and logits a chunk of 4 positions at a time; the
+        # rolling buffers of 8 slots hold positions 4 .. 11
+        assert caches["L1_k"].shape[2] == 8 and caches["L5_k"].shape[2] == 18
+        chunked, last_chunked = tfm.prefill(params, prompt, cfg=cfg,
+                                            total=18, chunk=4)
+        assert set(chunked) == set(caches)
+        for name in caches:
+            np.testing.assert_allclose(chunked[name], caches[name],
+                                       rtol=1e-4, atol=1e-5)
+        np.testing.assert_allclose(last_chunked, last, rtol=1e-4, atol=1e-5)
     caches = tfm.decode_caches(caches, cfg=cfg, p_len=12, total=18)
     first = jnp.argmax(last, -1).astype(jnp.int32)
     tokens, caches = tfm.decode_from(params, caches, first, 12, 5, cfg=cfg)
@@ -120,6 +148,11 @@ def test_the_lookup_is_the_one_place_that_reads_the_configuration():
     cfg, _ = dense(59)
     assert type(tfm.attention_kind(cfg, 0)) is kinds.GroupedQuery
     assert type(tfm.attention_kind(latent()[0], 2)) is kinds.Latent
+    # a hybrid stack answers by layer
+    assert [type(tfm.attention_kind(hybrid()[0], i)).__name__
+            for i in range(8)] == [
+        "StateSpace", "GroupedQuery", "StateSpace", "GroupedQuery",
+        "StateSpace", "GroupedQuery", "GatedMemory", "Cross"]
 
 
 def test_a_kind_refuses_the_forms_it_does_not_have():

@@ -48,6 +48,13 @@ WHOLE_CFG = tfm.TransformerConfig.llama_style(
         rope_factor=40.0, rope_original=16, mscale_all_dim=1.0,
         qk_norm=True))
 WHOLE_SCOPES = {"lm.mla": "lm.attn", "lm.latent": "lm.attn"}
+# a hybrid stack: every mixer under a scope of its own, inside lm.attn
+HYBRID_CFG = tfm.TransformerConfig(
+    vocab=64, d_model=32, n_heads=8, n_kv_heads=4, n_layers=8, d_ff=48,
+    max_seq=64, positions="none", ffn="swiglu",
+    hybrid=tfm.HybridStack.sambay(8, 4, ssm_state=4, ssm_rank=3))
+HYBRID_SCOPES = {name: "lm.attn" for name in (
+    "lm.ssm", "lm.gmu", "lm.swa", "lm.full", "lm.cross")}
 
 
 def train_setup(shape, make=tfm.make_train_step, **kw):
@@ -223,6 +230,45 @@ def test_attention_over_the_whole_cache_has_a_scope_of_its_own(
     assert "selected" not in lowered_whole.out_info[2]
 
 
+@pytest.mark.parametrize("name", sorted(HYBRID_SCOPES))
+def test_a_hybrid_stacks_mixers_have_scopes_of_their_own(lowered_hybrid, name):
+    """`lm.attn/lm.ssm`, `lm.attn/lm.cross`, ...: in the scan of the
+    session entry, and in the prefill (chunked: every layer that caches
+    something in the scan over the chunks, the cross-decoder after it)."""
+    session, prefill = lowered_hybrid
+    nests(session, name, HYBRID_SCOPES[name])
+    under = [p for p in scope_paths(prefill) if name in p.split("/")]
+    assert under and all("lm.attn" in p.split("/") for p in under), name
+
+
+def test_the_hybrid_steps_attention_is_the_decode_kernels():
+    """Window, full and cross attention of a decode step all go through
+    `ops/decode.decode_attention`: on the chip `_decode_pallas`, no
+    kernel of their own."""
+    params = tfm.init_transformer(jax.random.PRNGKey(0), HYBRID_CFG)
+    caches, _ = tfm.prefill(params, jnp.zeros((2, 8), jnp.int32),
+                            cfg=HYBRID_CFG, total=12)
+    caches = tfm.decode_caches(caches, cfg=HYBRID_CFG, p_len=8, total=12)
+    real = ops.decode.decode_attention
+    seen = []
+
+    def interpreted(q, k, v, t, **kw):
+        seen.append((k.shape, kw.get("roll", False)))
+        return real(q, k, v, t, **dict(kw, backend="pallas_interpret"))
+
+    import lua_mapreduce_tpu.models.attention_kinds as kinds
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kinds, "decode_attention", interpreted)
+        jaxpr = jax.make_jaxpr(lambda p, c: tfm.decode_from.__wrapped__(
+            p, c, jnp.zeros((2,), jnp.int32), 8, 4, cfg=HYBRID_CFG))(
+                params, caches)
+    assert set(pallas_calls(jaxpr.jaxpr)) == {"_decode_pallas"}
+    # two window layers on rolling buffers of 4 slots, the full layer and
+    # the cross layer on the one cache of 12
+    assert sorted(seen) == [((2, 2, 4, 8), True)] * 2 + [
+        ((2, 2, 12, 8), False)] * 2
+
+
 def test_the_session_entry_runs_no_prefill(lowered_session):
     paths = scope_paths(lowered_session)
     assert not any("lm.prefill" in p or "lm.first_token" in p for p in paths)
@@ -231,14 +277,16 @@ def test_the_session_entry_runs_no_prefill(lowered_session):
 
 
 def test_every_scope_of_the_contract_is_used(lowered_train, lowered_decode,
-                                             lowered_session, lowered_whole):
+                                             lowered_session, lowered_whole,
+                                             lowered_hybrid):
     found = (scope_paths(lowered_train[2, 2]) | scope_paths(lowered_decode)
-             | scope_paths(lowered_session) | scope_paths(lowered_whole))
+             | scope_paths(lowered_session) | scope_paths(lowered_whole)
+             | scope_paths(lowered_hybrid[0]))
     for name in profiling.LM_SCOPES:
         assert any(name in p for p in found), name
     assert set(TRAIN_SCOPES + DECODE_SCOPES + ("lm.ring",)
-               + tuple(SESSION_SCOPES) + tuple(WHOLE_SCOPES)) == set(
-                   profiling.LM_SCOPES)
+               + tuple(SESSION_SCOPES) + tuple(WHOLE_SCOPES)
+               + tuple(HYBRID_SCOPES)) == set(profiling.LM_SCOPES)
 
 
 def test_the_indexed_layers_add_no_kernel():
@@ -266,6 +314,20 @@ def lowered_whole():
     caches = tfm.decode_caches(caches, cfg=WHOLE_CFG, p_len=8, total=12)
     return tfm.decode_from.lower(params, caches, jnp.zeros((2,), jnp.int32),
                                  8, 4, cfg=WHOLE_CFG, stats=True)
+
+
+@pytest.fixture(scope="module")
+def lowered_hybrid():
+    """The hybrid stack's session entry and its chunked prefill."""
+    params = tfm.init_transformer(jax.random.PRNGKey(0), HYBRID_CFG)
+    ids = jnp.zeros((2, 8), jnp.int32)
+    prefill = jax.jit(lambda p, i: tfm.prefill(p, i, cfg=HYBRID_CFG,
+                                               total=12, chunk=4))
+    caches, _ = prefill(params, ids)
+    caches = tfm.decode_caches(caches, cfg=HYBRID_CFG, p_len=8, total=12)
+    return (tfm.decode_from.lower(params, caches, jnp.zeros((2,), jnp.int32),
+                                  8, 4, cfg=HYBRID_CFG),
+            prefill.lower(params, ids))
 
 
 def pallas_calls(jaxpr, out=None) -> list:

@@ -468,3 +468,50 @@ def test_the_whole_cache_session_entry_keeps_kernel_and_caches_in_place(
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 16 * 32832 * 576 * 2
     assert memory.temp_size_in_bytes < 256 * 2 ** 20
+
+
+# --------------------------------------------------------------------------
+# a hybrid stack (PR 34): the Phi-4-mini-flash-reasoning cell's turn, whole
+# --------------------------------------------------------------------------
+
+def test_the_hybrid_session_entry_reads_one_shared_cache_in_place(
+        topo, chip_policy):
+    """All 32 layers at the cell's size (32 sessions, 16,384 cached
+    positions, 32 scanned): sixteen calls of the decode kernel a step,
+    eight over rolling windows of 512 and eight over the ONE whole cache
+    (layer 17's: its own read and the seven cross layers' are the same
+    two operands); the scan re-lays no cache (no copy or transpose of a
+    (32, 10, 16416, 128) or (32, 10, 512, 128) array: a kernel over
+    another layout made XLA copy 3.96 GB into the scan, PR 32), the
+    donated caches and their snapshots are written in place, and the
+    snapshots cost the scan nothing (they are not in its temporaries)."""
+    from perfbench.model_phi4flash import program_config
+    cfg = program_config(serve_config("phi-4-mini-flash-reasoning.serve"))
+    compiled = compiled_turn(topo, cfg, 32, 16384, 32)
+    text = compiled.as_text()
+    calls = re.findall(
+        r"= f32\[320,4,128\][^ ]* custom-call\(([^)]*)\), "
+        r"custom_call_target=\"tpu_custom_call\", operand_layout_constraints="
+        r"\{s32\[1\]\{0\}, bf16\[320,4,128\]\{[^}]*\}, bf16\[320,(\d+),128\]",
+        text)
+    whole = [ops_.split(", ")[2:] for ops_, s_len in calls
+             if s_len == "16416"]
+    assert len(calls) == 16 and len(whole) == 8
+    assert len({tuple(kv) for kv in whole}) == 1
+    assert len({tuple(ops_.split(", ")[2:]) for ops_, s_len in calls
+                if s_len == "512"}) == 8
+    relaid = [line for line in text.splitlines() if re.search(
+        r"= bf16\[(?:32,10|320),(?:16416|512),128\][^ ]* (?:copy|transpose)\(",
+        line)]
+    # a turn's start keeps a snapshot of the sixteen rolling buffers: one
+    # copy each, before the scan and not in it; the whole cache has none
+    assert len(relaid) == 16 and not any(
+        "16416" in line.split(" copy(")[0] or "while/body" in line
+        for line in relaid), relaid
+    memory = compiled.memory_analysis()
+    held = (2 * 32 * 10 * 16416 * 128 * 2           # the one whole cache
+            + 2 * 8 * 2 * 32 * 10 * 512 * 128 * 2   # windows and snapshots
+            + 2 * 9 * 32 * 5120 * (16 * 4 + 3 * 2))  # states and snapshots
+    # (and seventeen scalars: the positions the snapshots are of)
+    assert held <= memory.alias_size_in_bytes < held + 2 ** 16
+    assert memory.temp_size_in_bytes < 128 * 2 ** 20
