@@ -1,22 +1,28 @@
 """Block-size sweep for the fused flash-attention kernels.
 
-The forward and backward default to (block_q, block_k) = (128, 128);
-this sweep times candidate schedules on the real chip for the shapes
-the LM family actually runs — forward AND fwd+bwd (the training path
-exercises the dq/dkv kernels, whose best blocks need not match the
-forward's). Same elision-proof measurement discipline as
-kernel_bench._measure_op; evidence goes to stdout as JSON for baking
-winners into ops/attention.py defaults.
+Times every candidate (block_q, block_k) schedule on the real chip at
+the shapes the benchmark's training cells run, the forward and the
+gradient apart, and the three kernels apart (``flash_pallas``,
+``flash_bwd_pallas_dq``, ``flash_bwd_pallas_dkv``): device time of each
+kernel's events in a profiler trace, so the transposes and the loss
+around a call are not in it. Beside every timing stands the tile
+schedule it ran (``ops.attention.tile_classes``: dead tiles not visited,
+interior tiles folded with no mask, edge tiles masked). The table is
+the evidence ``ops/attention._resolve_blocks`` is written from
+(``tests/test_policy_artifact.py`` holds the two together).
 
-Usage: python benchmarks/flash_tune.py [--seqs 2048,4096]
+Usage: python benchmarks/flash_tune.py [--shapes train-1chip,ring-hop1]
+           [--install]
 """
 
 from __future__ import annotations
 
 import argparse
+import glob
 import json
 import os
 import sys
+import tempfile
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -25,54 +31,100 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # illegal candidate can never burn a hardware window
 CANDIDATES = [(64, 128), (128, 128), (128, 256), (256, 128), (256, 256),
               (128, 512), (512, 128), (256, 512), (512, 256), (512, 512),
-              # round-3 sweep: (512, 512) won everywhere; probe whether
-              # the trend continues (1 MB→2 MB f32 score tile)
               (512, 1024), (1024, 512)]
+
+# what the training cells hand the kernels (Mistral-7B: 32 heads over 8
+# kv heads of 128, window 4096): name -> (batch, length, q_offset, lse).
+# One chip folds 3 x 4096 in one call; a chip of the 2x2 (dp 2, sp 2)
+# folds its 4 x 2048 against its own keys (hop 0) and against its ring
+# neighbour's (hop 1, every tile interior), both through the lse path.
+SHAPES = {"train-1chip": (3, 4096, 0, False),
+          "ring-hop0": (4, 2048, 0, True),
+          "ring-hop1": (4, 2048, 2048, True)}
+HEADS, KV_HEADS, HEAD_DIM, WINDOW = 32, 8, 128, 4096
+KERNELS = ("flash_pallas", "flash_bwd_pallas_dq", "flash_bwd_pallas_dkv")
+CALLS = 5
 sys.path.insert(0, REPO)
 
-from benchmarks.kernel_bench import _call_overhead, _measure_op  # noqa: E402
+
+def kernel_us(run, args, calls: int = CALLS) -> dict:
+    """Device microseconds a call of each of KERNELS inside ``run``,
+    from a trace of ``calls`` calls (the first device's XLA Ops)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.block_until_ready(run(*args))               # compile + warm
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with tempfile.TemporaryDirectory() as d:
+        jax.profiler.start_trace(d, profiler_options=opts)
+        try:
+            for _ in range(calls):
+                jax.block_until_ready(run(*args))
+        finally:
+            jax.profiler.stop_trace()
+        path, = glob.glob(os.path.join(d, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+        planes = [p for p in ProfileData.from_file(path).planes
+                  if p.name == "/device:TPU:0"]
+    spent = dict.fromkeys(KERNELS, 0.0)
+    for line in planes[0].lines:
+        if line.name != "XLA Ops":
+            continue
+        for ev in line.events:
+            # "%flash_bwd_pallas_dq.3 = ..." -> the kernel's own name
+            name = ev.name.split(" ")[0].lstrip("%").split(".")[0]
+            if name in spent:
+                spent[name] += ev.duration_ns / 1e3 / calls
+    return {k: v for k, v in spent.items() if v}
 
 
-def time_config(seq, bq, bk, grad, target_s=0.35, b=4, heads=8, d=128):
+def time_config(shape: str, bq, bk) -> dict:
+    """One candidate at one shape: the forward's kernel alone and the
+    gradient's three, us a call, and the tiles a (batch, head) row is
+    made of at these blocks."""
     import jax
     import jax.numpy as jnp
 
-    from lua_mapreduce_tpu.ops.attention import flash_attention
-    from lua_mapreduce_tpu.utils.roofline import peak_flops_per_s
+    from lua_mapreduce_tpu.ops.attention import (flash_attention,
+                                                 tile_classes)
 
-    q = jax.random.normal(jax.random.PRNGKey(0), (b, seq, heads, d),
+    b, l, q_offset, lse = SHAPES[shape]
+    q = jax.random.normal(jax.random.PRNGKey(0), (b, l, HEADS, HEAD_DIM),
                           jnp.bfloat16)
-    k = jax.random.normal(jax.random.PRNGKey(1), (b, seq, heads, d),
-                          jnp.bfloat16)
-    v = jax.random.normal(jax.random.PRNGKey(2), (b, seq, heads, d),
-                          jnp.bfloat16)
-    mult = 14.0 if grad else 4.0          # bwd ≈ 2.5x fwd matmul work
-    flops = mult * b * heads * seq * seq * d * 0.5     # causal
-    inner_cap = max(16, int(2.0 * target_s * peak_flops_per_s() / flops))
+    k, v = (jax.random.normal(jax.random.PRNGKey(i),
+                              (b, l, KV_HEADS, HEAD_DIM), jnp.bfloat16)
+            for i in (1, 2))
 
-    if grad:
-        def loss(q, k, v):
-            out = flash_attention(q, k, v, causal=True, backend="pallas",
-                                  block_q=bq, block_k=bk)
-            return jnp.sum(out.astype(jnp.float32) ** 2)
+    def attend(q, k, v):
+        out = flash_attention(q, k, v, causal=True, backend="pallas",
+                              block_q=bq, block_k=bk, window=WINDOW,
+                              q_offset=q_offset, return_lse=lse)
+        return out if lse else (out,)
 
-        def run(q, k, v):
-            g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
-            return sum(x.astype(jnp.float32).sum() for x in g).reshape(1)
-    else:
-        def run(q, k, v):
-            return flash_attention(q, k, v, causal=True,
-                                   backend="pallas", block_q=bq,
-                                   block_k=bk)
+    def loss(q, k, v):
+        return sum(jnp.sum(x.astype(jnp.float32) ** 2)
+                   for x in attend(q, k, v))
 
-    per_op, _ = _measure_op(run, (q, k, v), 0, inner_cap, target_s,
-                            _call_overhead())
-    return per_op, flops
+    fwd = kernel_us(jax.jit(attend), (q, k, v))
+    grad = kernel_us(jax.jit(jax.grad(loss, argnums=(0, 1, 2))),
+                     (q, k, v))
+    dead, interior, edge = tile_classes(
+        l, l, bq, bk, True, WINDOW, q_offset)
+    live = (interior + edge) * b * HEADS
+    row = {"blocks": [bq, bk], "tiles": [dead, interior, edge],
+           "fwd_us": round(fwd["flash_pallas"], 1),
+           "grad_us": {k: round(v, 1) for k, v in grad.items()},
+           "fwdbwd_us": round(sum(grad.values()), 1)}
+    if live:
+        row["us_a_live_tile"] = {k: round(v / live, 3)
+                                 for k, v in grad.items()}
+    return row
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--seqs", default="2048,4096")
+    ap.add_argument("--shapes", default=",".join(SHAPES))
     ap.add_argument("--install", action="store_true",
                     help="write results/flash_tune.json (full rows + "
                          "provenance) instead of leaving installation "
@@ -85,31 +137,23 @@ def main():
     require_tpu("flash_tune.py")
     import jax
 
-    cands = CANDIDATES
     results = {}
-    for seq in (int(s) for s in args.seqs.split(",")):
-        for grad in (False, True):
-            tag = f"s{seq}_{'fwdbwd' if grad else 'fwd'}"
-            best, rows = None, []
-            for bq, bk in cands:
-                try:
-                    dt, flops = time_config(seq, bq, bk, grad)
-                except Exception as e:
-                    rows.append({"blocks": [bq, bk],
-                                 "error": str(e)[:80]})
-                    continue
-                tf = flops / dt / 1e12
-                rows.append({"blocks": [bq, bk],
-                             "ms": round(dt * 1e3, 3),
-                             "tflops": round(tf, 1)})
-                print(f"{tag} ({bq:4d},{bk:4d}) {dt * 1e3:8.3f} ms "
-                      f"{tf:6.1f} TF/s", flush=True)
-                if best is None or dt < best[1]:
-                    best = ((bq, bk), dt)
-            results[tag] = ({"best_blocks": best[0],
-                             "best_ms": round(best[1] * 1e3, 3),
-                             "all": rows} if best else
-                            {"error": "no runnable config", "all": rows})
+    for shape in args.shapes.split(","):
+        rows = []
+        for bq, bk in CANDIDATES:
+            try:
+                row = time_config(shape, bq, bk)
+            except Exception as e:
+                row = {"blocks": [bq, bk], "error": str(e)[:80]}
+            rows.append(row)
+            print(shape, json.dumps(row), flush=True)
+        ran = [r for r in rows if "error" not in r]
+        results[shape] = {"all": rows}
+        if ran:
+            for key in ("fwd_us", "fwdbwd_us"):
+                best = min(ran, key=lambda r: r[key])
+                results[shape]["best_" + key] = {"blocks": best["blocks"],
+                                                 key: best[key]}
     print(json.dumps({k: {kk: vv for kk, vv in v.items() if kk != "all"}
                       for k, v in results.items()}))
     if args.install:
@@ -118,9 +162,11 @@ def main():
             "benchmarks/flash_tune.py --install, "
             + jax.devices()[0].device_kind + ", "
             + time.strftime("%Y-%m-%d %H:%M UTC", time.gmtime())
-            + "; candidates swept fwd AND fwdbwd per sequence length; "
-            "ops/attention.py's _DEFAULT_BLOCK_Q/K must match the "
-            "winners (tests/test_policy_artifact.py).")
+            + "; device us a call of each kernel from a trace of "
+            f"{CALLS} calls; tiles = (dead, interior, edge) a row; "
+            "ops/attention._resolve_blocks must give each shape its "
+            "fwdbwd winner or stand level with it "
+            "(tests/test_policy_artifact.py).")
         dest = os.path.join(REPO, "benchmarks", "results",
                             "flash_tune.json")
         with open(dest + ".tmp", "w") as f:

@@ -10,20 +10,25 @@ fold — ``return_lse`` exposes the mergeable-softmax state, and partial
 attentions over disjoint KV shards combine by logaddexp weights — so
 ring = flash with the KV loop distributed over ICI, literally.
 
-Grid: (batch·heads, q-blocks, kv-blocks); the kv axis is the innermost
-(sequential) dimension, accumulating into scratch and writing the
-normalized output tile on its last step — the accumulator discipline of
-ops/matmul.py. Causal masking compares global row/column indices built
-from the program ids; padded tail rows/columns are masked the same way.
+Grid: (batch·heads, the row's live tiles): the inner (sequential) axis
+walks a scalar-prefetched table of the (q-block, kv-block) pairs that
+hold a visible score, a q block's kv blocks in a run, accumulating into
+scratch and writing the normalized output tile on the run's last step —
+the accumulator discipline of ops/matmul.py. A tile's class is decided
+once, on the host, from its block indices (_class_grid): a dead tile
+(above the causal diagonal, behind the window) is no step at all, an
+interior tile (every pair visible) folds with no mask, and only an edge
+tile (the diagonal, the window's far edge, the padded tail of columns)
+compares global row/column indices.
 
 Backward: fused too (FlashAttention-2 shape). The forward saves only
 (q, k, v, o, per-row logsumexp); the backward re-materializes each
 (block_q, block_k) probability tile in VMEM from those — p = exp(s −
-lse) — and accumulates dq in one kernel (kv innermost) and dk/dv in a
-second (q innermost). No (L, L) matrix ever touches HBM in EITHER
-direction, so training through the kernel is O(L·d) memory like
-inference — previously the custom VJP re-ran the XLA composition,
-paying the O(L²) HBM the forward existed to avoid.
+lse) — and accumulates dq in one kernel (the forward's walk) and dk/dv
+in a second (a kv block's live q blocks in a run). No (L, L) matrix
+ever touches HBM in EITHER direction, so training through the kernel is
+O(L·d) memory like inference — previously the custom VJP re-ran the XLA
+composition, paying the O(L²) HBM the forward existed to avoid.
 """
 
 from __future__ import annotations
@@ -32,6 +37,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -84,71 +90,125 @@ def _tile_mask(rows, cols, causal: bool, window: int, seq_len: int,
     return valid
 
 
-def _tile_live(qi, ki, block_q: int, block_k: int, causal: bool,
-               window: int, q_offset: int = 0):
-    """Whether tile (qi, ki) contains ANY visible score — the block-skip
-    predicate (None = statically always live). Causal prunes tiles
-    wholly above the diagonal; a window additionally prunes tiles wholly
-    behind it (~L/window of the causal work at long L)."""
-    row0 = qi * block_q + q_offset
-    conds = []
+# A tile's class, decided once on the host from its block indices — the
+# kernels read it from the scalar-prefetched table and never ask again.
+_FIRST, _LAST, _INTERIOR, _EDGE = 1, 2, 4, 8
+
+
+def _class_grid(n_q: int, n_kv: int, *, block_q: int, block_k: int,
+                causal: bool, window: int, seq_len: int,
+                q_offset: int = 0):
+    """(n_q, n_kv) classes of a (batch, head) row's tiles — _tile_mask's
+    algebra on a tile's extreme rows and columns instead of on every
+    element: 0 where NO pair is visible (dead: above the causal
+    diagonal, wholly behind the window), _INTERIOR where EVERY pair is
+    (the mask is the identity there, padded q rows included: it never
+    looks at a row's own validity), _EDGE for the rest (the diagonal,
+    the window's far edge, the ragged tail of columns)."""
+    row_lo = np.arange(n_q)[:, None] * block_q + q_offset
+    row_hi = row_lo + block_q - 1
+    col_lo = np.arange(n_kv)[None, :] * block_k
+    col_hi = col_lo + block_k - 1
+    live = np.ones((n_q, n_kv), bool)
+    interior = live & (col_hi < seq_len)
     if causal:
-        conds.append(ki * block_k <= row0 + block_q - 1)
+        live &= col_lo <= row_hi
+        interior &= col_hi <= row_lo
     if window:
-        conds.append(row0 - (ki * block_k + block_k - 1) < window)
-    if not conds:
-        return None
-    live = conds[0]
-    for c in conds[1:]:
-        live = jnp.logical_and(live, c)
-    return live
+        live &= row_lo - col_hi < window
+        interior &= row_hi - col_lo < window
+    return np.where(live, np.where(interior, _INTERIOR, _EDGE), 0)
 
 
-def _kv_clamp(qi, ki, *, block_q, block_k, causal, window, q_offset,
-              n_kv):
-    """Clamp a kv block index into q-block ``qi``'s LIVE range — the
-    dead-tile DMA elision. ``pl.when`` skips the masked COMPUTE, but the
-    pipeline still fetches every tile the index map names; re-mapping a
-    dead step onto the nearest live block makes consecutive indices
-    equal, and Pallas skips the copy when the index does not change.
-    Causal halves kv traffic; a sliding window cuts it to O(window/L).
-    Exactly _tile_live's algebra: live ⟹ clamp is the identity, so live
-    steps always see their own tile (pinned by the interpret-mode parity
-    suite across causal/window/offset/GQA)."""
-    if not (causal or window):
-        return ki
-    row0 = qi * block_q + q_offset
-    hi = ((row0 + block_q - 1) // block_k) if causal else n_kv - 1
-    lo = ((row0 - window + 1) // block_k) if window else 0
-    # bounds sanitization: a fully-dead geometry (every tile of this
-    # grid row pruned) may cross the bounds or push them out of range;
-    # the clamp must still emit an IN-RANGE index (any one — compute is
-    # skipped), never a negative or overflowing DMA offset
-    lo = jnp.clip(lo, 0, n_kv - 1)
-    hi = jnp.clip(hi, lo, n_kv - 1)
-    return jnp.clip(ki, lo, hi)
+def tile_classes(l_q: int, l_k: int, block_q: int, block_k: int,
+                 causal: bool, window: int = 0, q_offset: int = 0):
+    """(dead, interior, edge) tile counts of one (batch, head) row at
+    the given blocks — what the tile schedule skips, folds with no
+    mask, and folds masked."""
+    cls = _class_grid(-(-l_q // block_q), -(-l_k // block_k),
+                      block_q=block_q, block_k=block_k, causal=causal,
+                      window=window, seq_len=l_k, q_offset=q_offset)
+    return tuple(int(np.sum(cls == c)) for c in (0, _INTERIOR, _EDGE))
 
 
-def _q_clamp(qi, ki, *, block_q, block_k, causal, window, q_offset,
-             n_q):
-    """The dkv-kernel twin of _kv_clamp: clamp a q block index into kv
-    block ``ki``'s live range (q innermost there). Same liveness
-    algebra transposed: causal gives the LOWER bound (q blocks above
-    the diagonal are dead), the window gives the UPPER bound (q rows
-    too far past the kv block see nothing)."""
-    if not (causal or window):
-        return qi
-    lo = ((ki * block_k - q_offset) // block_q) if causal else 0
-    # strict inequality: row0 < ki·bk + bk - 1 + window - q_offset,
-    # so the last live block is (T - 1) // bq
-    hi = (((ki * block_k + block_k - 2 + window - q_offset) // block_q)
-          if window else n_q - 1)
-    # same bounds sanitization as _kv_clamp (hi can go NEGATIVE here
-    # when the kv block sits wholly behind the window — the banded
-    # ring's far hop): crossed bounds must still yield in-range indices
-    lo = jnp.clip(lo, 0, n_q - 1)
-    hi = jnp.clip(hi, lo, n_q - 1)
-    return jnp.clip(qi, lo, hi)
+# A step of the table is one int32, outer | inner | flags: the whole
+# table is copied to scalar memory (1 MB on a v5e) before the kernel
+# starts, and a long causal row has tens of thousands of live tiles.
+_FLAG_BITS, _INNER_BITS, _OUTER_BITS = 4, 14, 13
+_TABLE_STEPS = 200_000
+
+
+def _tile_table(cls, group: int = 1):
+    """The walk of a grid row over its LIVE tiles only, one int32 a
+    step (read back by _step): the outer block (cls's row, whose
+    scratch accumulates), the inner index (``g · n_inner + column``,
+    every live column once for each of ``group`` members: the dkv
+    kernel's walk over a kv head's q heads), and flags: the tile's
+    class, _FIRST and _LAST on an outer block's first and last step. An
+    outer block with no live tile keeps ONE step of class 0, which
+    folds nothing and writes the block's zeros."""
+    n_outer, n_inner = cls.shape
+    steps = []
+    for o, row in enumerate(cls):
+        cols = np.flatnonzero(row)
+        inner = (np.arange(group)[:, None] * n_inner + cols).ravel()
+        flags = np.tile(row[cols], group)
+        if not inner.size:
+            inner, flags = np.zeros(1, int), np.zeros(1, int)
+        flags[0] |= _FIRST
+        flags[-1] |= _LAST
+        steps.append(o << (_INNER_BITS + _FLAG_BITS)
+                     | inner << _FLAG_BITS | flags)
+    table = np.concatenate(steps).astype(np.int32)
+    if (n_outer > 1 << _OUTER_BITS or group * n_inner > 1 << _INNER_BITS
+            or table.size > _TABLE_STEPS):
+        raise ValueError(
+            f"flash attention: {n_outer} x {group * n_inner} blocks, "
+            f"{table.size} live tiles a row, are more than the tile "
+            f"table holds ({1 << _OUTER_BITS} x {1 << _INNER_BITS}, "
+            f"{_TABLE_STEPS}); pass larger block_q / block_k")
+    return table
+
+
+def _step(table_ref, s):
+    """(outer, inner, flags) of the table's step ``s``."""
+    word = table_ref[s]
+    return (word >> (_INNER_BITS + _FLAG_BITS),
+            (word >> _FLAG_BITS) & ((1 << _INNER_BITS) - 1),
+            word & ((1 << _FLAG_BITS) - 1))
+
+
+def _table_bits(table, interpret: bool):
+    """(the flag bits SOME step of the table has, the bits EVERY step
+    has) — what _on builds a step's bodies from. The interpreter gets
+    no bit of the second kind: it evaluates a kernel's top level op by
+    op, where shard_map's vma typing refuses an op that mixes a varying
+    block with a constant; inside a branch it does not look."""
+    flags = table & ((1 << _FLAG_BITS) - 1)
+    return (int(np.bitwise_or.reduce(flags)),
+            0 if interpret else int(np.bitwise_and.reduce(flags)))
+
+
+def _on(flag, bit: int, bits, body):
+    """Run ``body`` on the steps whose flags have ``bit``. A bit no step
+    of the table has builds no body at all; a bit every step has (a
+    row of one tile is _FIRST, _LAST and its class at once; a call
+    with no diagonal is all _INTERIOR) runs it with no branch, so the
+    step stays one straight block the compiler schedules whole."""
+    some, every = bits
+    if every & bit:
+        body()
+    elif some & bit:
+        pl.when(flag & bit != 0)(body)
+
+
+def _walk(flag, bits, init, fold, finish):
+    """One step of a kernel's walk: ``init`` on a run's first step,
+    ``fold(masked)`` by the tile's class, ``finish`` on its last."""
+    _on(flag, _FIRST, bits, init)
+    _on(flag, _INTERIOR, bits, functools.partial(fold, False))
+    _on(flag, _EDGE, bits, functools.partial(fold, True))
+    _on(flag, _LAST, bits, finish)
 
 
 def _attn_reference_xla(q, k, v, causal: bool, scale: float,
@@ -184,29 +244,37 @@ def _attn_reference_xla(q, k, v, causal: bool, scale: float,
     return out32, jnp.transpose(lse, (0, 2, 1))         # (B, L, H)
 
 
-def _flash_kernel_nolse(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
-                        acc_scr, **kw):
+def _tile_positions(qi, ki, block_q: int, block_k: int):
+    """Global (rows, cols) of a tile's entries — only a masked (edge)
+    body builds them."""
+    rows = qi * block_q + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 0)
+    cols = ki * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    return rows, cols
+
+
+def _flash_kernel_nolse(table_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
+                        l_scr, acc_scr, **kw):
     """Inference variant: no lse output allocated or written at all —
     the plain forward (return_lse=False, outside any vjp) should not
     pay HBM for softmax state nobody reads."""
-    _flash_kernel(q_ref, k_ref, v_ref, o_ref, None, m_scr, l_scr,
-                  acc_scr, **kw)
+    _flash_kernel(table_ref, q_ref, k_ref, v_ref, o_ref, None, m_scr,
+                  l_scr, acc_scr, **kw)
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                  acc_scr, *, scale: float, causal: bool, seq_len: int,
-                  block_q: int, block_k: int, n_kv: int,
+def _flash_kernel(table_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr,
+                  l_scr, acc_scr, *, scale: float, causal: bool,
+                  seq_len: int, block_q: int, block_k: int, bits,
                   window: int = 0, q_offset: int = 0):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qi, ki, flag = _step(table_ref, pl.program_id(1))
 
-    @pl.when(ki == 0)
-    def _():
+    def init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def fold():
+    def fold(masked):
         # dots take the INPUT dtype (bf16×bf16→f32 is the MXU's native
         # mode — upcasting operands to f32 first quarters matmul
         # throughput); only the softmax bookkeeping runs in f32
@@ -217,19 +285,20 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
 
-        # global positions: mask padded tail columns always, the upper
-        # triangle when causal (padded q rows give garbage, sliced off)
-        rows = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        cols = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        valid = _tile_mask(rows, cols, causal, window, seq_len,
-                           q_offset)
-        s = jnp.where(valid, s, _NEG_INF)
+        if masked:
+            # global positions: mask padded tail columns always, the
+            # upper triangle when causal (padded q rows give garbage,
+            # sliced off). An interior tile's mask is all true, so its
+            # body has no iota, compare or select: the same values.
+            valid = _tile_mask(*_tile_positions(qi, ki, block_q, block_k),
+                               causal, window, seq_len, q_offset)
+            s = jnp.where(valid, s, _NEG_INF)
 
         m_prev = jnp.max(m_scr[:], axis=-1, keepdims=True)  # (bq, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+        p = jnp.exp(s - m_new)
+        if masked:
+            p = jnp.where(valid, p, 0.0)
         alpha = jnp.exp(m_prev - m_new)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_prev = jnp.max(l_scr[:], axis=-1, keepdims=True)
@@ -242,18 +311,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    live = _tile_live(qi, ki, block_q, block_k, causal, window,
-                      q_offset)
-    if live is None:
-        fold()
-    else:
-        # skip kv blocks with no visible scores (above the causal
-        # diagonal / behind the sliding window) — folding them is pure
-        # wasted MXU time
-        pl.when(live)(fold)
-
-    @pl.when(ki == n_kv - 1)
-    def _():
+    def finish():
         l_fin = jnp.maximum(jnp.max(l_scr[:], axis=-1, keepdims=True),
                             1e-30)                      # (bq, 1)
         o_ref[0] = (acc_scr[:] / l_fin).astype(o_ref.dtype)
@@ -264,28 +322,33 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                    + jnp.log(l_fin))
             lse_ref[0] = jnp.broadcast_to(lse, lse_ref.shape[1:])
 
-
-# Tuned defaults from the on-chip sweep (benchmarks/flash_tune.py →
-# results/flash_tune.json, second-round sweep, v5e 2026-07-31
-# 11:32-11:38 UTC): (512, 512) is the decisive winner at every swept
-# shape — fwd 0.501 ms at L=2048 (vs 2.077 ms at the original
-# (128, 128), 0.778 at (256, 256)) and 1.80× the (256, 256) schedule
-# on the L=4096 training path (6.545 vs 11.756 ms fwdbwd). Bigger
-# tiles amortize the per-tile online-softmax state updates and halve
-# the number of VMEM-refill boundaries; the f32 score tile at 512² is
-# 1 MB, q/kv tiles 128 KB each at d=128 — comfortably inside VMEM
-# with double buffering. Short sequences clamp down in _clamp_blocks;
-# explicit callers (tiny windows, odd geometries) can still override.
-_DEFAULT_BLOCK_Q = 512
-_DEFAULT_BLOCK_K = 512
+    _walk(flag, bits, init, fold, finish)
 
 
-def _resolve_blocks(block_q, block_k):
+def _resolve_blocks(l: int, block_q, block_k, causal: bool, window: int,
+                    q_offset: int):
+    """The caller's blocks, else the ones the shape chooses (the sweep:
+    benchmarks/flash_tune.py → results/flash_tune.json, v5e, at the
+    train cells' shapes, forward + backward kernels, us a call).
+    (512, 512) wins wherever a row has a diagonal: its edge tiles are
+    masked and half empty, and twice as wide they are three quarters
+    empty (a ring's diagonal hop at L 2048: 5,766 against 6,429 at
+    (512, 1024); the one-chip cell's 4096: 16,155 against 15,906, level).
+    A call whose tiles are ALL interior (the banded ring's off-diagonal
+    hops, full attention over whole blocks) has nothing to mask and
+    nothing empty, and a (512, 1024) tile halves its steps: 8,735 ->
+    7,924. Smaller blocks lose everywhere, by 1.35x at (256, 512) to
+    11x at (64, 128). Short sequences clamp down in _clamp_blocks."""
     for nm, v in (("block_q", block_q), ("block_k", block_k)):
         if v is not None and v <= 0:  # match ops/matmul.py's validation
             raise ValueError(f"{nm} must be positive, got {v}")
-    return (_DEFAULT_BLOCK_Q if block_q is None else block_q,
-            _DEFAULT_BLOCK_K if block_k is None else block_k)
+    if block_k is None:
+        bq, bk = _clamp_blocks(l, block_q or 512, 1024)
+        wide = _class_grid(-(-l // bq), -(-l // bk), block_q=bq,
+                           block_k=bk, causal=causal, window=window,
+                           seq_len=l, q_offset=q_offset)
+        block_k = 1024 if (wide == _INTERIOR).all() else 512
+    return _clamp_blocks(l, block_q or 512, block_k)
 
 
 def _clamp_blocks(l: int, block_q: int, block_k: int):
@@ -326,8 +389,8 @@ def _flash_pallas(q, k, v, causal, block_q=None, block_k=None,
     hkv = k.shape[2]
     scale = 1.0 / float(d) ** 0.5
 
-    block_q, block_k = _resolve_blocks(block_q, block_k)
-    block_q, block_k = _clamp_blocks(l, block_q, block_k)
+    block_q, block_k = _resolve_blocks(l, block_q, block_k, causal,
+                                       window, q_offset)
     qb = _pad_seq(_to_bh(q), block_q)
     kb = _pad_seq(_to_bh(k), block_k)
     vb = _pad_seq(_to_bh(v), block_k)
@@ -335,14 +398,19 @@ def _flash_pallas(q, k, v, causal, block_q=None, block_k=None,
     n_kv = kb.shape[1] // block_k
 
     kern = _flash_kernel if with_lse else _flash_kernel_nolse
-    clamp = functools.partial(_kv_clamp, block_q=block_q,
-                              block_k=block_k, causal=causal,
-                              window=window, q_offset=q_offset,
-                              n_kv=n_kv)
-    spec_o = pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0),
+    geom = dict(causal=causal, seq_len=l, block_q=block_q,
+                block_k=block_k, window=window, q_offset=q_offset)
+    cls = _class_grid(n_q, n_kv, **geom)
+    table = _tile_table(cls)
+    spec_q = pl.BlockSpec((1, block_q, d),
+                          lambda bh, s, t: (bh, _step(t, s)[0], 0),
                           memory_space=pltpu.VMEM)
+    spec_kv = pl.BlockSpec(
+        (1, block_k, d),
+        lambda bh, s, t: (_kv_row(bh, h, hkv), _step(t, s)[1], 0),
+        memory_space=pltpu.VMEM)
     spec_lse = pl.BlockSpec((1, block_q, _LANES),
-                            lambda bh, qi, ki: (bh, qi, 0),
+                            lambda bh, s, t: (bh, _step(t, s)[0], 0),
                             memory_space=pltpu.VMEM)
     # the lse path serves partial-merge callers (ring folds): its out
     # stays f32 so P merged partials round ONCE at the caller's final
@@ -352,38 +420,29 @@ def _flash_pallas(q, k, v, causal, block_q=None, block_k=None,
     shape_lse = out_struct((b * h, qb.shape[1], _LANES), jnp.float32,
                            qb, kb, vb)
     res = pl.pallas_call(
-        functools.partial(kern, scale=scale, causal=causal,
-                          seq_len=l, block_q=block_q, block_k=block_k,
-                          n_kv=n_kv, window=window,
-                          q_offset=q_offset),
-        grid=(b * h, n_q, n_kv),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi, ki: (bh, qi, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d),
-                         lambda bh, qi, ki: (_kv_row(bh, h, hkv),
-                                             clamp(qi, ki), 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block_k, d),
-                         lambda bh, qi, ki: (_kv_row(bh, h, hkv),
-                                             clamp(qi, ki), 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[spec_o, spec_lse] if with_lse else [spec_o],
+        functools.partial(kern, scale=scale,
+                          bits=_table_bits(table, interpret), **geom),
+        # the inner axis walks the row's live (q block, kv block) pairs
+        # from the table, a q block's pairs in a run: the out tile
+        # stays put while its kv blocks fold into scratch
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * h, table.size),
+            in_specs=[spec_q, spec_kv, spec_kv],
+            out_specs=[spec_q, spec_lse] if with_lse else [spec_q],
+            scratch_shapes=[
+                pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
+                pltpu.VMEM((block_q, _LANES), jnp.float32),  # running denom
+                pltpu.VMEM((block_q, d), jnp.float32),       # running output
+            ]),
         out_shape=[shape_o, shape_lse] if with_lse else [shape_o],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running max
-            pltpu.VMEM((block_q, _LANES), jnp.float32),  # running denom
-            pltpu.VMEM((block_q, d), jnp.float32),       # running output
-        ],
-        # (bh, qi) carry no cross-iteration state (scratch re-inits at
-        # ki == 0); only the kv axis accumulates — telling Mosaic lets
-        # it parallelize/pipeline across the first two grid axes
+        # a (batch, head) row carries no state to the next (scratch
+        # re-inits on a q block's first step); only the walk accumulates
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="flash_pallas",
-    )(qb, kb, vb)
+    )(table, qb, kb, vb)
 
     out = res[0]
     out = jnp.transpose(out[:, :l, :].reshape(b, h, l, d), (0, 2, 1, 3))
@@ -392,8 +451,8 @@ def _flash_pallas(q, k, v, causal, block_q=None, block_k=None,
     return out, res[1][:, :, 0]        # collapse the replicated lanes
 
 
-def _bwd_tile(q, k, v, do, lse_ref, delta_ref, qi, ki, *, scale, causal,
-              seq_len, block_q, block_k, window=0, q_offset=0):
+def _bwd_tile(q, k, v, do, lse_ref, delta_ref, qi, ki, masked, *, scale,
+              causal, seq_len, block_q, block_k, window=0, q_offset=0):
     """Re-materialize one (block_q, block_k) tile's p and ds in VMEM —
     the shared core of both backward kernels. Returns (p, ds) in f32.
 
@@ -402,13 +461,12 @@ def _bwd_tile(q, k, v, do, lse_ref, delta_ref, qi, ki, *, scale, causal,
     jacobian term Σ_j p_ij dp_ij equals Δ_i because o = p·v)."""
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    rows = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    cols = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    valid = _tile_mask(rows, cols, causal, window, seq_len, q_offset)
     lse = _row_read(lse_ref)                            # (bq, 1)
-    p = jnp.where(valid, jnp.exp(s - lse), 0.0)
+    p = jnp.exp(s - lse)
+    if masked:          # an interior tile's mask is all true: no select
+        p = jnp.where(
+            _tile_mask(*_tile_positions(qi, ki, block_q, block_k),
+                       causal, window, seq_len, q_offset), p, 0.0)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     delta = _row_read(delta_ref)                        # (bq, 1)
@@ -416,64 +474,49 @@ def _bwd_tile(q, k, v, do, lse_ref, delta_ref, qi, ki, *, scale, causal,
     return p, ds
 
 
-def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, dq_scr, *, scale, causal, seq_len,
-                         block_q, block_k, n_kv, window=0, q_offset=0):
-    qi, ki = pl.program_id(1), pl.program_id(2)
+def _flash_bwd_dq_kernel(table_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                         delta_ref, dq_ref, dq_scr, *, bits, **kw):
+    """The forward's walk: a q block's live kv blocks in a run."""
+    qi, ki, flag = _step(table_ref, pl.program_id(1))
 
-    @pl.when(ki == 0)
-    def _():
+    def init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def fold():
+    def fold(masked):
         k = k_ref[0]
         _, ds = _bwd_tile(q_ref[0], k, v_ref[0], do_ref[0], lse_ref,
-                          delta_ref, qi, ki, scale=scale, causal=causal,
-                          seq_len=seq_len, block_q=block_q,
-                          block_k=block_k, window=window,
-                          q_offset=q_offset)
+                          delta_ref, qi, ki, masked, **kw)
         # dq_i += ds_ij · k_j  (scale already folded into ds)
         dq_scr[:] += jax.lax.dot_general(
             ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    live = _tile_live(qi, ki, block_q, block_k, causal, window,
-                      q_offset)
-    if live is None:
-        fold()
-    else:
-        pl.when(live)(fold)          # same tile pruning as the forward
-
-    @pl.when(ki == n_kv - 1)
-    def _():
+    def finish():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
+    _walk(flag, bits, init, fold, finish)
 
-def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dk_ref, dv_ref, dk_scr, dv_scr, *, scale,
-                          causal, seq_len, block_q, block_k, n_q,
-                          n_inner, window=0, q_offset=0):
-    """Grid: (b·h_kv, n_kv, n_inner) with n_inner = group·n_q — the
-    innermost axis walks every (q-head-in-group, q-block) pair whose
+
+def _flash_bwd_dkv_kernel(table_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                          delta_ref, dk_ref, dv_ref, dk_scr, dv_scr, *,
+                          n_q, bits, **kw):
+    """Grid: (b·h_kv, steps): a kv block's run walks every live
+    (q-head-in-group, q-block) pair, ``inner = g · n_q + qi``, whose
     gradients land in THIS kv head's (dk, dv) tile, so GQA's
     sum-over-group falls out of the same scratch accumulation that
     already summed over q blocks (group = 1 reduces to plain MHA)."""
-    ki, inner = pl.program_id(1), pl.program_id(2)
+    ki, inner, flag = _step(table_ref, pl.program_id(1))
     qi = inner % n_q
 
-    @pl.when(inner == 0)
-    def _():
+    def init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def fold():
+    def fold(masked):
         q = q_ref[0]
         do = do_ref[0]
         p, ds = _bwd_tile(q, k_ref[0], v_ref[0], do, lse_ref, delta_ref,
-                          qi, ki, scale=scale, causal=causal,
-                          seq_len=seq_len, block_q=block_q,
-                          block_k=block_k, window=window,
-                          q_offset=q_offset)
+                          qi, ki, masked, **kw)
         # dv_j += p_ijᵀ · do_i ; dk_j += ds_ijᵀ · q_i
         dv_scr[:] += jax.lax.dot_general(
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -482,17 +525,11 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
 
-    live = _tile_live(qi, ki, block_q, block_k, causal, window,
-                      q_offset)
-    if live is None:
-        fold()
-    else:
-        pl.when(live)(fold)
-
-    @pl.when(inner == n_inner - 1)
-    def _():
+    def finish():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    _walk(flag, bits, init, fold, finish)
 
 
 @functools.partial(
@@ -517,8 +554,8 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, block_q=None,
     group = h // hkv
     scale = 1.0 / float(d) ** 0.5
 
-    block_q, block_k = _resolve_blocks(block_q, block_k)
-    block_q, block_k = _clamp_blocks(l, block_q, block_k)
+    block_q, block_k = _resolve_blocks(l, block_q, block_k, causal,
+                                       window, q_offset)
     qb = _pad_seq(_to_bh(q), block_q)
     kb = _pad_seq(_to_bh(k), block_k)
     vb = _pad_seq(_to_bh(v), block_k)
@@ -538,77 +575,78 @@ def _flash_bwd_pallas(q, k, v, o, lse, g, causal, block_q=None,
         delta = delta - gl.astype(jnp.float32)
     n_q = qb.shape[1] // block_q
     n_kv = kb.shape[1] // block_k
-    kw = dict(scale=scale, causal=causal, seq_len=l,
-              block_q=block_q, block_k=block_k, window=window,
-              q_offset=q_offset)
+    geom = dict(causal=causal, seq_len=l, block_q=block_q,
+                block_k=block_k, window=window, q_offset=q_offset)
+    cls = _class_grid(n_q, n_kv, **geom)
+    kw = dict(scale=scale, **geom)
 
     # row operands (lse, Δ) ride lane-replicated — see _LANES
     lse_r = _lane_rep(lse)
     delta_r = _lane_rep(delta)
-    # dead-tile DMA elision (see _kv_clamp/_q_clamp): dq walks kv
-    # innermost, dkv walks q innermost — each clamps its innermost
-    # operand maps onto the live band
-    kvc = functools.partial(_kv_clamp, block_q=block_q, block_k=block_k,
-                            causal=causal, window=window,
-                            q_offset=q_offset, n_kv=n_kv)
-    qc = functools.partial(_q_clamp, block_q=block_q, block_k=block_k,
-                           causal=causal, window=window,
-                           q_offset=q_offset, n_q=n_q)
-    spec_q = pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0),
-                          memory_space=pltpu.VMEM)
-    spec_row = pl.BlockSpec((1, block_q, _LANES),
-                            lambda bh, i, j: (bh, i, 0),
-                            memory_space=pltpu.VMEM)
-    spec_kv = pl.BlockSpec(
-        (1, block_k, d),
-        lambda bh, i, j: (_kv_row(bh, h, hkv), kvc(i, j), 0),
-        memory_space=pltpu.VMEM)
 
+    def specs(q_at, kv_at):
+        """(q-side, kv-side, row-state) block specs of a walk whose
+        ``q_at`` / ``kv_at`` map (grid row, the step's outer, inner) to
+        the operand's (row, block)."""
+        def spec(at, block, lanes):
+            return pl.BlockSpec(
+                (1, block, lanes),
+                lambda row, s, t: (*at(row, *_step(t, s)[:2]), 0),
+                memory_space=pltpu.VMEM)
+        return (spec(q_at, block_q, d), spec(kv_at, block_k, d),
+                spec(q_at, block_q, _LANES))
+
+    params = pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"))
+    operands = (qb, kb, vb, dob, lse_r, delta_r)
+
+    # dq walks the forward's table: a q block's live kv blocks in a run
+    table = _tile_table(cls)
+    spec_q, spec_kv, spec_row = specs(
+        lambda bh, qi, ki: (bh, qi),
+        lambda bh, qi, ki: (_kv_row(bh, h, hkv), ki))
     dq = pl.pallas_call(
-        functools.partial(_flash_bwd_dq_kernel, n_kv=n_kv, **kw),
-        grid=(b * h, n_q, n_kv),
-        in_specs=[spec_q, spec_kv, spec_kv, spec_q, spec_row, spec_row],
-        out_specs=spec_q,
-        out_shape=out_struct(qb.shape, q.dtype, qb, kb, vb, dob),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        functools.partial(_flash_bwd_dq_kernel,
+                          bits=_table_bits(table, interpret), **kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * h, table.size),
+            in_specs=[spec_q, spec_kv, spec_kv, spec_q, spec_row,
+                      spec_row],
+            out_specs=spec_q,
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
+        out_shape=out_struct(qb.shape, q.dtype, *operands[:4]),
+        compiler_params=params,
         interpret=interpret,
         name="flash_bwd_pallas_dq",
-    )(qb, kb, vb, dob, lse_r, delta_r)
+    )(table, *operands)
 
-    # dkv grid: one row per KV head, kv-block outer, and the innermost
-    # axis walks (q-head-in-group × q-block) — the q-side index maps
-    # recover the q grid row from (bhkv, inner // n_q)
-    def q_row(bhkv, i):
-        return (bhkv // hkv) * h + (bhkv % hkv) * group + i // n_q
-
-    spec_q2 = pl.BlockSpec(
-        (1, block_q, d),
-        lambda bh, j, i: (q_row(bh, i), qc(i % n_q, j), 0),
-        memory_space=pltpu.VMEM)
-    spec_row2 = pl.BlockSpec(
-        (1, block_q, _LANES),
-        lambda bh, j, i: (q_row(bh, i), qc(i % n_q, j), 0),
-        memory_space=pltpu.VMEM)
-    spec_kv2 = pl.BlockSpec((1, block_k, d), lambda bh, j, i: (bh, j, 0),
-                            memory_space=pltpu.VMEM)
+    # dkv: one grid row per KV head; a kv block's run walks its live
+    # (q-head-in-group × q-block) pairs — the q-side index map recovers
+    # the q grid row from (bhkv, inner // n_q)
+    table = _tile_table(cls.T, group)
+    spec_q, spec_kv, spec_row = specs(
+        lambda bhkv, ki, inner: (
+            (bhkv // hkv) * h + (bhkv % hkv) * group + inner // n_q,
+            inner % n_q),
+        lambda bhkv, ki, inner: (bhkv, ki))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, n_q=n_q,
-                          n_inner=group * n_q, **kw),
-        grid=(b * hkv, n_kv, group * n_q),
-        in_specs=[spec_q2, spec_kv2, spec_kv2, spec_q2, spec_row2,
-                  spec_row2],
-        out_specs=[spec_kv2, spec_kv2],
-        out_shape=[out_struct(kb.shape, k.dtype, qb, kb, vb, dob),
-                   out_struct(vb.shape, v.dtype, qb, kb, vb, dob)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+                          bits=_table_bits(table, interpret), **kw),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b * hkv, table.size),
+            in_specs=[spec_q, spec_kv, spec_kv, spec_q, spec_row,
+                      spec_row],
+            out_specs=[spec_kv, spec_kv],
+            scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)]),
+        out_shape=[out_struct(kb.shape, k.dtype, *operands[:4]),
+                   out_struct(vb.shape, v.dtype, *operands[:4])],
+        compiler_params=params,
         interpret=interpret,
         name="flash_bwd_pallas_dkv",
-    )(qb, kb, vb, dob, lse_r, delta_r)
+    )(table, *operands)
 
     def from_bh(x, ln, heads):
         return jnp.transpose(x[:, :ln, :].reshape(b, heads, ln, d),

@@ -418,6 +418,36 @@ def test_the_decode_kernel_compiles_inside_its_vmem_limit(
 
 
 # --------------------------------------------------------------------------
+# the training flash kernels alone (PR 35), at what the two train cells hand
+# them: Mosaic compiles the tile table's walk (a scalar-prefetched int32 a
+# step, read by every index map) and both bodies of a step inside VMEM
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("shape", ["train-1chip", "ring-hop0", "ring-hop1"])
+def test_the_flash_kernels_compile_at_the_train_cells_shapes(topo, shape):
+    from jax.sharding import SingleDeviceSharding
+    from benchmarks import flash_tune as T
+    from lua_mapreduce_tpu.ops.attention import flash_attention
+    one = SingleDeviceSharding(topo.devices[0])
+    b, l, q_offset, lse = T.SHAPES[shape]
+    q, kv = (jax.ShapeDtypeStruct((b, l, h, T.HEAD_DIM), jnp.bfloat16,
+                                  sharding=one)
+             for h in (T.HEADS, T.KV_HEADS))
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, backend="pallas",
+                              window=T.WINDOW, q_offset=q_offset,
+                              return_lse=lse)
+        return sum(x.astype(jnp.float32).sum()
+                   for x in (out if lse else (out,)))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    for kernel in T.KERNELS:
+        assert f"%{kernel}" in text, kernel
+
+
+# --------------------------------------------------------------------------
 # latent attention over a whole cache (PR 32): the latent flash-decode
 # kernel at the sarvam-105b cell's shape, and the session entry around it
 # --------------------------------------------------------------------------

@@ -244,29 +244,46 @@ class TestQ8Lowering:
                    x, q, s)
 
 
-def test_every_tuner_candidate_lowers():
-    """The block-size sweeps (benchmarks/flash_tune.py, matmul_tune.py)
-    run on rare, short hardware windows — a Mosaic-illegal candidate
-    would burn the window on compile errors. Export every candidate
-    the tuners enumerate (shared module-level definitions, so the
-    tuners and this guard cannot drift), the flash ones through the
-    BACKWARD kernels too (the sweep times fwd+bwd)."""
-    from benchmarks.flash_tune import CANDIDATES as FLASH_CANDS
+def _flash_candidates():
+    from benchmarks.flash_tune import CANDIDATES
+    return [(None, None)] + CANDIDATES
+
+
+@pytest.mark.parametrize("blocks", _flash_candidates(), ids=str)
+def test_every_flash_candidate_lowers(blocks):
+    """The block-size sweep (benchmarks/flash_tune.py) runs on rare,
+    short hardware windows — a Mosaic-illegal candidate would burn the
+    window on compile errors. Export every candidate the tuner
+    enumerates (one module-level list, so the tuner and this guard
+    cannot drift) and the shape-chosen default, at the tuner's own
+    geometry (GQA, window, the ring's offset hop through the lse path),
+    the forward AND the gradient: all three kernels walk a
+    scalar-prefetched tile table whose index maps read it."""
+    from benchmarks import flash_tune as T
+    bq, bk = blocks
+    for b, l, q_offset, lse in T.SHAPES.values():
+        q = _q(b, l, T.HEADS, T.HEAD_DIM)
+        kv = _q(b, l, T.KV_HEADS, T.HEAD_DIM)
+
+        def attend(q_, k_, v_):
+            out = ops.flash_attention(
+                q_, k_, v_, causal=True, backend="pallas", block_q=bq,
+                block_k=bk, window=T.WINDOW, q_offset=q_offset,
+                return_lse=lse)
+            return out if lse else (out,)
+
+        def loss(q_, k_, v_):
+            return sum(x.astype(jnp.float32).sum()
+                       for x in attend(q_, k_, v_))
+
+        export_tpu(attend, q, kv, kv)
+        export_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv)
+
+
+def test_every_matmul_candidate_lowers():
+    """The same guard for benchmarks/matmul_tune.py's candidates."""
     from benchmarks.matmul_tune import candidates as matmul_cands
-    from lua_mapreduce_tpu.ops.attention import _flash_pallas
     from lua_mapreduce_tpu.ops.matmul import _matmul_pallas
-
-    q = jax.ShapeDtypeStruct((4, 2048, 8, 128), jnp.bfloat16)
-    for bq, bk in FLASH_CANDS:
-        export_tpu(lambda q_, k_, v_, bq=bq, bk=bk: _flash_pallas(
-            q_, k_, v_, True, block_q=bq, block_k=bk), q, q, q)
-
-        def loss(q_, k_, v_, bq=bq, bk=bk):
-            return ops.flash_attention(q_, k_, v_, causal=True,
-                                       backend="pallas", block_q=bq,
-                                       block_k=bk).sum()
-
-        export_tpu(jax.grad(loss, argnums=(0, 1, 2)), q, q, q)
 
     a = jax.ShapeDtypeStruct((4096, 4096), jnp.bfloat16)
     for bm, bn, bkk in matmul_cands():
