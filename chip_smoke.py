@@ -15,9 +15,11 @@ one process. There is no CPU mode and no flag that skips the device
 check. Any stage that raises or fails an assertion ends the run with a
 non-zero exit code and no result line. Each stage prints one JSON line;
 the times in them are smoke timings (one cold call, a few warm ones),
-not metrics. The line before the last sums the run up (stages, programs
-built, persistent-cache hits, ``"claim": null``), and the last line of
-standard output is the result, these keys and no other:
+not metrics, and ``builds`` are the rows of the process's build log
+(``utils/profiling.py``) for the programs the stage built. The line
+before the last sums the run up (stages, programs built,
+persistent-cache hits, ``"claim": null``), and the last line of standard
+output is the result, these keys and no other:
 
     {"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}
 
@@ -56,39 +58,21 @@ ENGINE_ITERATIONS = 5
 # what the run counts: programs built, and persistent-cache traffic
 # --------------------------------------------------------------------------
 
-class CompileLog:
-    """Counts JAX's own monitoring events: ``programs`` is every
-    executable built (compiled, or fetched from the persistent cache),
-    ``hits``/``misses`` are the persistent cache's."""
-
-    def __init__(self):
-        import jax
-        self.programs = self.hits = self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._timed)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _timed(self, event, duration, **kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.programs += 1
-
-    def _event(self, event, **kw):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def snapshot(self):
-        return (self.programs, self.hits, self.misses)
-
-    def since(self, snap) -> dict:
-        p, h, m = snap
-        return {"programs": self.programs - p,
-                "persistent_cache_hits": self.hits - h,
-                "persistent_cache_misses": self.misses - m}
+def _since(log, snap: dict) -> dict:
+    """The process's build log (``utils/profiling.build_log``) since
+    ``snap``, a ``log.counts()``: ``programs`` is every executable built
+    (compiled, or fetched from the persistent cache), the hits and
+    misses are the persistent cache's; ``builds`` are the log's rows
+    (trace, lower, build, and what ran after), in the order built."""
+    now = log.counts()
+    rows = [{k: round(v, 4) if isinstance(v, float) else v
+             for k, v in row.items() if k not in ("t0", "t1", "enclosed")}
+            for row in log.rows(snap["programs"])]
+    return {**{k: now[k] - snap[k] for k in now}, "builds": rows}
 
 
-def _report(stage: str, log: CompileLog, snap, **fields) -> dict:
-    line = {"stage": stage, **fields, **log.since(snap)}
+def _report(stage: str, log, snap, **fields) -> dict:
+    line = {"stage": stage, **fields, **_since(log, snap)}
     print(json.dumps(line), flush=True)
     return line
 
@@ -111,7 +95,7 @@ def _timed(fn):
 # a. the trainer
 # --------------------------------------------------------------------------
 
-def stage_trainer(log: CompileLog, devices, mesh_shape, *, lm, batch, seq,
+def stage_trainer(log, devices, mesh_shape, *, lm, batch, seq,
                   steps, n_kv_heads, moe_experts=0, expect_backend="pallas",
                   name="trainer") -> dict:
     """``steps`` separately dispatched LM train steps on one repeated
@@ -129,7 +113,7 @@ def stage_trainer(log: CompileLog, devices, mesh_shape, *, lm, batch, seq,
     from lua_mapreduce_tpu import ops
     from lua_mapreduce_tpu.models import transformer as tfm
 
-    snap = log.snapshot()
+    snap = log.counts()
     dp, sp = mesh_shape
     mesh = Mesh(np.array(devices[:dp * sp]).reshape(dp, sp), ("dp", "sp"))
     kw = dict(lm, n_kv_heads=n_kv_heads, max_seq=seq)
@@ -167,7 +151,7 @@ def stage_trainer(log: CompileLog, devices, mesh_shape, *, lm, batch, seq,
     losses, walls, later = [], [], None
     for i in range(steps):
         if i == 1:
-            later = log.snapshot()
+            later = log.counts()
         (params, opt_state, loss), wall = _timed(
             lambda: step(params, opt_state, tokens, targets))
         losses.append(float(loss))
@@ -177,7 +161,7 @@ def stage_trainer(log: CompileLog, devices, mesh_shape, *, lm, batch, seq,
     check(all(np.isfinite(losses)), losses)
     check(losses[-1] < losses[0],
           f"the loss did not fall over {steps} steps: {losses}")
-    built_later = log.since(later)["programs"]
+    built_later = log.programs - later["programs"]
     check(built_later == 0,
           f"{built_later} program(s) built after the first step")
 
@@ -226,7 +210,7 @@ def _memory_spread(mesh) -> dict:
 # b. the decoder
 # --------------------------------------------------------------------------
 
-def stage_decoder(log: CompileLog, *, lm, batch, prompt_len, n_new,
+def stage_decoder(log, *, lm, batch, prompt_len, n_new,
                   max_seq, requests, variant: str) -> dict:
     """``requests`` prompts through ``prefill`` + ``greedy_decode`` in
     one serving variant: ``mha`` (bf16), ``q8`` (int8 weights and int8
@@ -239,7 +223,7 @@ def stage_decoder(log: CompileLog, *, lm, batch, prompt_len, n_new,
 
     from lua_mapreduce_tpu.models import transformer as tfm
 
-    snap = log.snapshot()
+    snap = log.counts()
     cfg = tfm.TransformerConfig.llama_style(
         **lm, max_seq=max_seq,
         n_kv_heads=lm["n_heads"] // 4 if variant == "gqa" else 0)
@@ -268,7 +252,7 @@ def stage_decoder(log: CompileLog, *, lm, batch, prompt_len, n_new,
     walls, later = [], None
     for r in range(requests):
         if r == 1:
-            later = log.snapshot()
+            later = log.counts()
         p = prompt()
         out, wall = _timed(lambda: tfm.greedy_decode(
             params, p, n_new, cfg=cfg, use_prefill=True, kv_q8=kv_q8))
@@ -279,7 +263,7 @@ def stage_decoder(log: CompileLog, *, lm, batch, prompt_len, n_new,
               "the prompt is not the prefix of the output")
         check(out.min() >= 0 and out.max() < cfg.vocab,
               f"tokens out of range: {out.min()}..{out.max()}")
-    built_later = log.since(later)["programs"]
+    built_later = log.programs - later["programs"]
     check(built_later == 0,
           f"{built_later} program(s) built after the first request")
     return _report(
@@ -396,7 +380,7 @@ def _kernel_cases(*, lm, train, decode, pool_shape) -> dict:
     }
 
 
-def stage_kernels(log: CompileLog, *, lm, train, decode, pool_shape,
+def stage_kernels(log, *, lm, train, decode, pool_shape,
                   kernel_backend="pallas") -> dict:
     """For every op ``ops._TPU_AUTO_POLICY`` routes to Pallas: the
     kernel (``kernel_backend``) against ``backend="xla"`` within
@@ -405,7 +389,7 @@ def stage_kernels(log: CompileLog, *, lm, train, decode, pool_shape,
 
     from lua_mapreduce_tpu import ops
 
-    snap = log.snapshot()
+    snap = log.counts()
     cases = _kernel_cases(lm=lm, train=train, decode=decode,
                           pool_shape=pool_shape)
     routed = sorted(op for op, to in ops._TPU_AUTO_POLICY.items()
@@ -452,7 +436,7 @@ ENGINE_TASKS = {
 }
 
 
-def stage_engine(log: CompileLog, mod: str, *, iterations: int,
+def stage_engine(log, mod: str, *, iterations: int,
                  dp: int) -> dict:
     """One looping six-function task on the compiled plane: through the
     server launcher under ``--engine ingraph`` (the hard mode: a
@@ -469,7 +453,7 @@ def stage_engine(log: CompileLog, mod: str, *, iterations: int,
     from lua_mapreduce_tpu.engine.contract import TaskSpec
     from lua_mapreduce_tpu.engine.local import LocalExecutor
 
-    snap = log.snapshot()
+    snap = log.counts()
     init_args, want_mode, want_fold, read_state = ENGINE_TASKS[mod]
     args = init_args(iterations)
     short = mod.rsplit(".", 1)[-1]
@@ -577,7 +561,9 @@ def main() -> int:
                       "compile_cache_dir": cache_dir}), flush=True)
 
     import jax
-    log = CompileLog()
+
+    from lua_mapreduce_tpu.utils.profiling import build_log
+    log = build_log()
     devs = jax.devices()
     stages = []
 
@@ -598,12 +584,16 @@ def main() -> int:
             run(stage_trainer, devs, (2, 2), lm=LM, moe_experts=moe,
                 name="four_chips.trainer", **TRAIN)
 
+    first = log.rows()[:1]
+    first_build_s = (round(first[0]["t0"] - log.process_start, 3)
+                     if first and log.process_start is not None else None)
     print(json.dumps({
         "stages": [s["stage"] for s in stages],
         "four_chip_stages_ran": len(devs) >= 4,
         "programs_built": log.programs,
         "persistent_cache_hits": log.hits,
         "persistent_cache_misses": log.misses,
+        "process_start_to_first_build_s": first_build_s,
         "compile_cache_dir": cache_dir,
         "smoke_wall_s": round(time.perf_counter() - t0, 1),
         "claim": None}), flush=True)

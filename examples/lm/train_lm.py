@@ -326,6 +326,8 @@ def _run_inner(args, jax) -> dict:
     reached = target is None
     t0 = time.time()
     warm_t0 = None              # tokens/sec excludes the compile step
+    from lua_mapreduce_tpu.utils.profiling import build_log, build_table
+    builds, built = build_log(), None   # programs built by the last look
     i = start_step
     try:
         for i in range(start_step + 1, args.steps + 1):
@@ -382,6 +384,17 @@ def _run_inner(args, jax) -> dict:
                              {"params": params, "opt": opt_state,
                               "step": jnp.asarray(i, jnp.int32)})
                 print(f"  checkpoint @ step {i}", flush=True)
+            if built is None:
+                # where set-up went: every program built up to here
+                print(build_table(builds.rows(), builds.process_start),
+                      flush=True)
+                built = builds.programs
+            elif builds.programs != built:
+                for row in builds.rows(built):
+                    print(f"recompiled at step {i}: {row['program']} "
+                          f"(trace {row['trace_s']:.3f} s, build "
+                          f"{row['build_s']:.3f} s)", flush=True)
+                built = builds.programs
     finally:
         # an exception mid-loop (OOM, NaN guard, SIGTERM) must not
         # abandon the in-flight write: the 'checkpoint @ step' log

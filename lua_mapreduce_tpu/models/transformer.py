@@ -46,8 +46,11 @@ from lua_mapreduce_tpu.parallel.ring_attention import (
     _NEG_INF, _ring_shard, _ring_shard_zigzag, _ulysses_shard,
     _zigzag_check, _zigzag_perm, attention_reference)
 from lua_mapreduce_tpu.train.accum import accum_value_and_grad
-from lua_mapreduce_tpu.utils.profiling import annotate, scope
+from lua_mapreduce_tpu.utils.profiling import annotate, build_log, scope
 
+# every launcher imports this module before it builds a program: the
+# build log's listeners go in here, once a process
+build_log()
 
 
 @dataclasses.dataclass(frozen=True)

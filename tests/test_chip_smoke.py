@@ -23,7 +23,25 @@ DECODE = dict(batch=2, prompt_len=24, n_new=8, max_seq=32, requests=2)
 
 @pytest.fixture(scope="module")
 def log():
-    return chip_smoke.CompileLog()
+    """A build log of this module's own: the process's one, which
+    ``main`` takes, keeps its first rows only, and a test process has
+    built thousands of programs by the time it comes here."""
+    from lua_mapreduce_tpu.utils.profiling import BuildLog
+    log = BuildLog().register()
+    yield log
+    jax.monitoring.unregister_event_time_span_listener(log._span)
+    jax.monitoring.unregister_event_listener(log._event)
+
+
+def built_programs(line, program):
+    """The stage's line carries the build log's rows for what it built:
+    as many as it counts, the named program with its times among them."""
+    assert len(line["builds"]) == line["programs"] > 0
+    (row,) = [b for b in line["builds"] if b["program"] == program]
+    assert row["trace_s"] > 0 and row["lower_s"] > 0 and row["build_s"] > 0
+    assert row["cache"] in ("hit", "miss", "off")
+    assert set(row) == {"program", "trace_s", "lower_s", "build_s",
+                        "cache", "after_s"}
 
 
 def test_refuses_to_run_without_a_tpu():
@@ -53,6 +71,7 @@ def test_stage_trainer(log, mesh_shape, moe):
     assert line["losses"][-1] < line["losses"][0]
     assert line["programs_after_first_step"] == 0
     assert line["devices"] == mesh_shape[0] * mesh_shape[1]
+    built_programs(line, "lm_train_step")
     json.dumps(line)
 
 
@@ -67,6 +86,7 @@ def test_stage_decoder(log, variant):
     line = chip_smoke.stage_decoder(log, lm=LM, variant=variant, **DECODE)
     assert line["programs_after_first_request"] == 0
     assert len(line["smoke_later_calls_s"]) == DECODE["requests"] - 1
+    built_programs(line, "greedy_decode")
 
 
 def test_stage_kernels_walks_the_policy_table(log, monkeypatch):
@@ -118,7 +138,8 @@ def test_main_ends_with_the_result_line_and_nothing_else_in_it(
     for name in ("stage_trainer", "stage_decoder", "stage_kernels",
                  "stage_engine"):
         monkeypatch.setattr(chip_smoke, name, stub(name))
-    monkeypatch.setattr(chip_smoke, "CompileLog", lambda: log)
+    monkeypatch.setattr("lua_mapreduce_tpu.utils.profiling.build_log",
+                        lambda: log)
     monkeypatch.setattr(
         chip_smoke, "check_device",
         lambda: (chip_smoke.describe_device(), {"jax": jax.__version__}))
@@ -137,6 +158,8 @@ def test_main_ends_with_the_result_line_and_nothing_else_in_it(
     assert type(result["device"]["count"]) is int
     summary = json.loads(lines[-2])
     assert summary["claim"] is None and "ok" not in summary
+    assert summary["programs_built"] == log.programs
+    assert summary["process_start_to_first_build_s"] > 0
     assert summary["four_chip_stages_ran"] == (len(jax.devices()) >= 4)
     assert len(summary["stages"]) == (2 + 3 + 1 + 2
                                       + 2 * summary["four_chip_stages_ran"])
