@@ -338,6 +338,22 @@ def _kernel_cases(*, lm, train, decode, pool_shape) -> dict:
         return lambda backend: (ops.mla_decode_attention(
             q, rows, t, v_rank=512, scale=576 ** -0.5, backend=backend),)
 
+    def held_experts(count, touched):
+        # a decode step's tokens through the touched ones of `count`
+        # stacked SwiGLU experts (ops/moe_held.py)
+        from lua_mapreduce_tpu.ops.moe_held import moe_held
+        b, f = decode["batch"], lm["d_ff"] // 4
+        x = normal(11, (b, d))
+        wg, wu = (normal(i, (count, d, f)) * d ** -0.5 for i in (12, 13))
+        wd = normal(14, (count, f, d)) * f ** -0.5
+        chosen = jnp.arange(count) % (count // touched) == 0
+        combine = jnp.where(chosen[None, :], jax.random.uniform(
+            jax.random.fold_in(key, 15), (b, count), jnp.float32, 0.1, 0.5),
+            0.0)
+        load = jnp.where(chosen, b, 0).astype(jnp.int32)
+        return lambda backend: (moe_held(x, combine, load, wg, wu, wd,
+                                         backend=backend),)
+
     def q8(m, kdim, n):
         x = normal(6, (m, kdim))
         w, s = ops.quantize_q8(
@@ -367,6 +383,10 @@ def _kernel_cases(*, lm, train, decode, pool_shape) -> dict:
         ],
         "mla_decode_attention": [
             (f"h{h} bf16 cache {decode['max_seq']} x 576", latent_decode()),
+        ],
+        "moe_held": [
+            (f"{db} tokens, 4 of 8 experts {d}x{lm['d_ff'] // 4}",
+             held_experts(8, 4)),
         ],
         "q8_matmul": [
             (f"decode qkv {db}x{d}x{qkv}", q8(db, d, qkv)),

@@ -46,6 +46,10 @@ _TPU_AUTO_POLICY = {
     # value at once and crosses the bus once; XLA's composition reads
     # the cache for the scores and again for the values
     "mla_decode_attention": "pallas",
+    # the held experts of a decode step (ops/moe_held.py): one call
+    # streams the touched experts' weights back to back; XLA's
+    # composition is a conditional an expert, each a cold stream
+    "moe_held": "pallas",
 }
 
 

@@ -26,12 +26,14 @@ sharded over ``ep``) — identical routing, identical outputs.
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from lua_mapreduce_tpu.ops.moe_held import moe_held, swiglu as _swiglu
 from lua_mapreduce_tpu.utils.profiling import scope
 
 Params = Dict[str, jnp.ndarray]
@@ -423,16 +425,13 @@ def route_grouped(x, router_w, bias, *, top_k: int, n_groups: int,
     return expert.astype(jnp.int32), weight
 
 
-def _swiglu(x, wg, wu, wd):
-    return (jax.nn.silu(x @ wg) * (x @ wu)) @ wd
-
-
-# tokens one expert takes at a time. A tile no larger than this (a decode
-# step's B tokens) goes through a touched expert whole: gathering the few
-# rows that chose it would cost the step more small operations than the
-# rows it saves. A larger tile (a prefill chunk) is gathered, this many of
-# an expert's own tokens at a time, so that an expert works on the 1/32
-# of the tile that chose it and not on all of it.
+# tokens one expert takes at a time. A tile no larger than this goes
+# through a touched expert whole (`ops/moe_held.moe_held`: a decode step's
+# B tokens as one grouped call on the chip): gathering the few rows that
+# chose it would cost the step more small operations than the rows it
+# saves. A larger tile (a prefill chunk) is gathered, this many of an
+# expert's own tokens at a time, so that an expert works on the 1/32 of
+# the tile that chose it and not on all of it.
 _EXPERT_CHUNK = 512
 
 
@@ -448,7 +447,8 @@ def moe_ffn_held(params: Params, x, *, held: Tuple[int, int], top_k: int,
 
     An expert that no token of the tile chose is never computed, so its
     weights are not read: at decode, a step streams the experts it
-    touches. Tokens of a large tile go through their expert
+    touches (``ops/moe_held.moe_held``: on the chip one call walks
+    them). Tokens of a large tile go through their expert
     ``_EXPERT_CHUNK`` at a time, as many chunks as the routing needs.
 
     Returns (out (T, d), stats): ``held_assignments``, the routed
@@ -470,36 +470,32 @@ def moe_ffn_held(params: Params, x, *, held: Tuple[int, int], top_k: int,
         routed = jnp.sum(onehot, axis=1) > 0
         load = jnp.sum(routed, axis=0)                      # (count,)
     wg, wu, wd = (params[f"{prefix}_{n}"] for n in ("wg", "wu", "wd"))
-    chunk = min(t, _EXPERT_CHUNK)
 
-    def one_expert(e, acc):
-        def whole(acc):
-            y = _swiglu(x, wg[e], wu[e], wd[e]).astype(jnp.float32)
-            return acc + y * combine[:, e:e + 1]
+    def chunked(e, acc):
+        order = jnp.nonzero(routed[:, e], size=t, fill_value=0)[0]
+        order = jnp.pad(order, (0, _EXPERT_CHUNK))
 
-        def chunked(acc):
-            order = jnp.nonzero(routed[:, e], size=t, fill_value=0)[0]
-            order = jnp.pad(order, (0, chunk))
+        def body(state):
+            i, acc = state
+            idx = lax.dynamic_slice(order, (i * _EXPERT_CHUNK,),
+                                    (_EXPERT_CHUNK,))
+            live = (i * _EXPERT_CHUNK + jnp.arange(_EXPERT_CHUNK)) < load[e]
+            y = _swiglu(x[idx], wg[e], wu[e], wd[e])
+            g = jnp.where(live, combine[idx, e], 0.0)
+            return i + 1, acc.at[idx].add(
+                y.astype(jnp.float32) * g[:, None])
 
-            def body(state):
-                i, acc = state
-                idx = lax.dynamic_slice(order, (i * chunk,), (chunk,))
-                live = (i * chunk + jnp.arange(chunk)) < load[e]
-                y = _swiglu(x[idx], wg[e], wu[e], wd[e])
-                g = jnp.where(live, combine[idx, e], 0.0)
-                return i + 1, acc.at[idx].add(
-                    y.astype(jnp.float32) * g[:, None])
-
-            return lax.while_loop(lambda s: s[0] * chunk < load[e], body,
-                                  (jnp.int32(0), acc))[1]
-
-        return lax.cond(load[e] > 0, whole if t <= chunk else chunked,
-                        lambda acc: acc, acc)
+        return lax.while_loop(lambda s: s[0] * _EXPERT_CHUNK < load[e], body,
+                              (jnp.int32(0), acc))[1]
 
     with scope("lm.moe.experts"):
-        acc = jnp.zeros((t, d), jnp.float32)
-        for e in range(count):
-            acc = one_expert(e, acc)
+        if t <= _EXPERT_CHUNK:
+            acc = moe_held(x, combine, load, wg, wu, wd)
+        else:
+            acc = jnp.zeros((t, d), jnp.float32)
+            for e in range(count):
+                acc = lax.cond(load[e] > 0, functools.partial(chunked, e),
+                               lambda acc: acc, acc)
     if shared and f"{prefix}_sg" in params:
         with scope("lm.moe.shared"):
             acc = acc + _swiglu(x, params[f"{prefix}_sg"],

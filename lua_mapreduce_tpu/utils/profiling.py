@@ -62,10 +62,11 @@ LM_SCOPES = ("lm.loss", "lm.embed", "lm.attn", "lm.ffn", "lm.head",
              "lm.moe.route", "lm.moe.experts", "lm.moe.shared")
 LM_HOST_SPANS = ("lm.shard_batch",)
 # ``name=`` of the pallas_calls (ops/attention.py, ops/decode.py,
-# ops/mla_decode.py, ops/q8.py): the custom call's HLO result is ``%<name>.<n>`` whatever
-# scope or transformation encloses it.
+# ops/mla_decode.py, ops/moe_held.py, ops/q8.py): the custom call's HLO
+# result is ``%<name>.<n>`` whatever scope or transformation encloses it.
 LM_KERNELS = ("flash_pallas", "flash_bwd_pallas_dq", "flash_bwd_pallas_dkv",
-              "_decode_pallas", "q8_matmul_pallas", "_mla_decode_pallas")
+              "_decode_pallas", "q8_matmul_pallas", "_mla_decode_pallas",
+              "_moe_held_pallas")
 # the jitted functions, so the trace's programs are ``jit_<name>``
 LM_PROGRAMS = ("lm_train_step", "greedy_decode", "decode_from")
 

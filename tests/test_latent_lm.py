@@ -335,7 +335,8 @@ def test_the_bias_moves_the_choice_and_not_the_weight():
 
 
 def held_layer(tokens: int, held, shared=True, seed=0):
-    d, ff, experts = 16, 8, 16
+    # (widths of whole lanes: what the grouped kernel takes)
+    d, ff, experts = 128, 128, 16
     params = moe.init_moe_held(jax.random.PRNGKey(1), d, ff, experts,
                                (0, experts), n_shared=1)
     # initialisation leaves the selection bias at zero: work its path
@@ -349,8 +350,25 @@ def held_layer(tokens: int, held, shared=True, seed=0):
         params, x
 
 
-@pytest.mark.parametrize("tokens", [8, 700])   # one pass; chunks of 512
-def test_the_shares_add_up_to_the_uncut_layer(tokens):
+@pytest.fixture
+def path(request, monkeypatch):
+    """`kernel`: what the chip decides for a decode step's tile, the
+    grouped call of `ops/moe_held.py`, interpreted here."""
+    if request.param == "kernel":
+        from lua_mapreduce_tpu import ops
+        monkeypatch.setattr(ops, "default_backend",
+                            lambda op=None: "pallas_interpret")
+    return request.param
+
+
+# one pass; chunks of 512; one pass through the kernel
+HELD_CASES = pytest.mark.parametrize(
+    "tokens,path", [(8, "xla"), (700, "xla"), (8, "kernel")],
+    indirect=["path"])
+
+
+@HELD_CASES
+def test_the_shares_add_up_to_the_uncut_layer(tokens, path):
     """The guide's test: 4 shares of 4 of the 16 experts, the shared
     expert counted once, give what the whole layer gives."""
     (whole, stats), _, _ = held_layer(tokens, (0, 16))
@@ -363,8 +381,8 @@ def test_the_shares_add_up_to_the_uncut_layer(tokens):
     assert sum(int(s["held_assignments"]) for _, s in parts) == 3 * tokens
 
 
-@pytest.mark.parametrize("tokens", [8, 700])
-def test_the_held_layer_is_the_weighted_sum_of_its_experts(tokens):
+@HELD_CASES
+def test_the_held_layer_is_the_weighted_sum_of_its_experts(tokens, path):
     (out, stats), params, x = held_layer(tokens, (4, 8))
     expert, weight = moe.route_grouped(x, params["moe_router_W"],
                                        params["moe_router_b"], **ROUTER)

@@ -290,10 +290,12 @@ def test_every_scope_of_the_contract_is_used(lowered_train, lowered_decode,
 
 
 def test_the_indexed_layers_add_no_kernel():
-    """Latent attention over selected rows, the indexer and the expert
-    layer are XLA's: the session entry of that model holds no
-    pallas_call. The sixth pinned name is the kernel of latent attention
-    over a whole cache (`ops/mla_decode.py`)."""
+    """Latent attention over selected rows and the indexer are XLA's,
+    and off the chip the expert layer is too: the session entry of that
+    model holds no pallas_call here. The sixth pinned name is the kernel
+    of latent attention over a whole cache (`ops/mla_decode.py`), the
+    seventh the held experts of a decode step as one call on the chip
+    (`ops/moe_held.py`)."""
     params = tfm.init_transformer(jax.random.PRNGKey(0), LATENT_CFG)
     caches, _ = tfm.prefill(params, jnp.zeros((2, 8), jnp.int32),
                             cfg=LATENT_CFG, total=12)
@@ -301,7 +303,7 @@ def test_the_indexed_layers_add_no_kernel():
         p, c, jnp.zeros((2,), jnp.int32), 8, 4, cfg=LATENT_CFG))(
             params, caches)
     assert pallas_calls(jaxpr.jaxpr) == []
-    assert len(profiling.LM_KERNELS) == 6
+    assert len(profiling.LM_KERNELS) == 7
 
 
 @pytest.fixture(scope="module")
@@ -364,8 +366,15 @@ def kernel_sites():
                                         scale=0.1,
                                         backend="pallas_interpret")
 
+    def held(x, w):
+        from lua_mapreduce_tpu.ops.moe_held import moe_held
+        return moe_held(x, jnp.ones((8, 2)), jnp.ones((2,), jnp.int32), w, w,
+                        w, backend="pallas_interpret")
+
     cache = jnp.ones((1, 2, 128, 64), jnp.float32)
     return {
+        "_moe_held_pallas": (held, (jnp.ones((8, 128)),
+                                    jnp.ones((2, 128, 128)))),
         "_mla_decode_pallas": (mla, (jnp.ones((1, 4, 96)),
                                      jnp.ones((1, 128, 96)))),
         "flash_pallas": (flash, (q, q, q)),

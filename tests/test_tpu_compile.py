@@ -354,17 +354,45 @@ def latent_turn(topo):
     return turn
 
 
+def held_expert_calls(text: str, count: int, d: int, f: int) -> list:
+    """The operands of every `_moe_held_pallas` call in a compiled
+    program, after the assertion that each takes the three expert stacks
+    in the layout the parameters have (row-major, the chip's own tiling:
+    no transposed or re-laid copy is asked for)."""
+    calls = re.findall(
+        r"%_moe_held_pallas[.\d]* = f32\[\d+,\d+\][^ ]* custom-call\(([^)]*)\)"
+        r", custom_call_target=\"tpu_custom_call\", "
+        r"operand_layout_constraints=\{([^}]*(?:\}[^}]*)*?)\}, frontend", text)
+    up, down = f"bf16[{count},{d},{f}]{{2,1,0}}", f"bf16[{count},{f},{d}]{{2,1,0}}"
+    for _, layouts in calls:
+        assert layouts.endswith(f"{up}, {up}, {down}"), layouts
+    return [operands for operands, _ in calls]
+
+
+def no_copy_of_an_expert_stack(text: str, count: int, d: int, f: int):
+    assert not re.findall(
+        rf"= bf16\[{count},(?:{d},{f}|{f},{d})\][^ ]* "
+        r"(?:copy|transpose|fusion|copy-start)\(", text)
+
+
 def test_the_latent_session_entry_reads_an_expert_only_where_touched(
         chip_policy, latent_turn):
-    """Every held expert sits behind a conditional (no token, no read of
-    its weights), nothing sorts 32k scores, and the latent caches stay
-    in place."""
+    """The 16 held experts are ONE grouped call in the decode program
+    (`ops/moe_held.py`: it walks the list of touched experts, so an
+    untouched one costs no byte) and no conditional an expert; the
+    stacked weights go in as the parameters hold them, and the
+    compiler's temporaries are no larger than with the conditionals
+    (435,530,240 bytes at PR 36: no copy of a stack); nothing sorts 32k
+    scores, and the latent caches stay in place."""
     compiled = latent_turn()
     text = compiled.as_text()
-    assert len(re.findall(r" conditional\(", text)) == 16
+    assert len(held_expert_calls(text, 16, 7168, 2048)) == 1
+    assert not re.findall(r" conditional\(", text)
+    no_copy_of_an_expert_stack(text, 16, 7168, 2048)
     assert not re.findall(r"sort\([^)]*\[8,1,32832\]", text)
     assert not re.findall(r"= [^=]*\[8,1,32832\][^=]* sort\(", text)
     memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes <= 435530240
     # (the chip's tiling pads the rows a little)
     assert memory.alias_size_in_bytes >= 8 * 32832 * (576 + 128) * 2
 
@@ -478,7 +506,9 @@ def test_the_latent_decode_kernel_compiles_inside_its_vmem_limit(
 def test_the_whole_cache_session_entry_keeps_kernel_and_caches_in_place(
         topo, chip_policy):
     """One expert layer of sarvam-105b's share at its real widths: the
-    kernel is there, every held expert sits behind a conditional, and
+    attention kernel is there, the 32 held experts are one grouped call
+    and no conditional, their stacks go in as they are (temporaries no
+    larger than the conditionals' 122,104,320 bytes at PR 36), and
     the latent cache stays in place in the layout the chip keeps it in:
     the scan re-lays no (16, 32832, 576) array (a kernel over (B, S, R)
     blocks made XLA copy the whole cache into the scan and out of it,
@@ -491,13 +521,45 @@ def test_the_whole_cache_session_entry_keeps_kernel_and_caches_in_place(
     compiled = compiled_turn(topo, cfg, 16, 32768, 64)
     text = compiled.as_text()
     assert "_mla_decode_pallas" in text
-    assert len(re.findall(r" conditional\(", text)) == 32
+    assert len(held_expert_calls(text, 32, 4096, 2048)) == 1
+    assert not re.findall(r" conditional\(", text)
+    no_copy_of_an_expert_stack(text, 32, 4096, 2048)
     assert not re.findall(
         r"= bf16\[16,(?:32832,576|576,32832)\][^ ]* (?:copy|transpose)\(",
         text)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 16 * 32832 * 576 * 2
-    assert memory.temp_size_in_bytes < 256 * 2 ** 20
+    assert memory.temp_size_in_bytes <= 122104320
+
+
+# --------------------------------------------------------------------------
+# the held experts of a decode step as one call (PR 38): Mosaic compiles
+# the walk over the touched experts' tiles (copies from the stacks as they
+# are, two sets of three tiles in VMEM) at the two latent cells' shapes
+# --------------------------------------------------------------------------
+
+@pytest.mark.parametrize("t,count,d,f", [
+    (16, 32, 4096, 2048),           # the sarvam-105b cell
+    (8, 16, 7168, 2048),            # the DeepSeek-V3.2-Exp cell
+    (64, 32, 4096, 2048),           # the most tokens a call takes
+])
+def test_the_held_expert_kernel_compiles_inside_its_vmem_limit(
+        topo, chip_policy, t, count, d, f):
+    from jax.sharding import SingleDeviceSharding
+    from lua_mapreduce_tpu.ops import moe_held
+    one = SingleDeviceSharding(topo.devices[0])
+    arg = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one)
+    tile_f = moe_held._tiles(t, d, f, count, 2)
+    assert (moe_held._vmem_bytes(t, d, tile_f, count, 2)
+            <= moe_held._VMEM_BUDGET)
+    compiled = moe_held._moe_held_pallas.lower(
+        arg((t, d), jnp.bfloat16), arg((t, count), jnp.float32),
+        arg((count,), jnp.int32), arg((count, d, f), jnp.bfloat16),
+        arg((count, d, f), jnp.bfloat16),
+        arg((count, f, d), jnp.bfloat16)).compile()
+    assert len(held_expert_calls(compiled.as_text(), count, d, f)) == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 # --------------------------------------------------------------------------
