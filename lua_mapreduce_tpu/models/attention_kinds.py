@@ -96,9 +96,11 @@ def _mm(params: Params, key: str, y):
 
 
 def _layer_norm(x, g, b, eps=1e-5):
+    """LayerNorm with a gain, and a bias unless ``b`` is None."""
     mu = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.var(x, axis=-1, keepdims=True)
-    return (x - mu) * lax.rsqrt(var + eps) * g + b
+    out = (x - mu) * lax.rsqrt(var + eps) * g
+    return out if b is None else out + b
 
 
 def _rms_norm(x, g, eps):
@@ -107,10 +109,13 @@ def _rms_norm(x, g, eps):
 
 
 def _norm(params: Params, name: str, x, cfg):
-    """The block norm: pre-LN (scale+bias) or RMSNorm (scale only)."""
+    """The block norm: pre-LN (scale+bias), RMSNorm (scale only) or
+    ``ln_gain``, LayerNorm with a scale and no bias."""
     g = params[f"{name}_g"]
     if cfg.norm == "rms":
         return _rms_norm(x, g, cfg.norm_eps)
+    if cfg.norm == "ln_gain":
+        return _layer_norm(x, g, None, cfg.norm_eps)
     return _layer_norm(x, g, params[f"{name}_b"], cfg.norm_eps)
 
 
@@ -303,7 +308,12 @@ class GroupedQuery(_Kind):
     rolling buffer of ``window`` slots where the window is shorter than
     the decode.
 
-    ``window`` is the layer's own (0 = full causal). ``differential``
+    ``window`` is the layer's own (0 = full causal); ``nope``: q and k
+    are not rotated, whatever ``cfg.rope`` says (the full layers of
+    ``cfg.attn_pattern``). In a stack with a pattern the cache has ONE
+    form, (B, H_kv, S, D), rolling where windowed, as a differential
+    pair's has (below), and the two kinds run under ``lm.swa`` and
+    ``lm.full``. ``differential``
     (arXiv:2410.05258): heads pair up, ``(q1, q2)`` = heads ``(2 j, 2 j
     + 1)`` and ``(k1, k2)``, ``(v1, v2)`` = kv heads ``(2 m, 2 m + 1)``,
     query pair ``j`` on kv pair ``j // g``; the layer's output is
@@ -322,12 +332,13 @@ class GroupedQuery(_Kind):
     differential: bool = False
     depth: int = 0
     shares: bool = False
+    nope: bool = False
 
     leaves = ("k", "v")
 
     @property
     def one_form(self) -> bool:
-        return self.differential
+        return self.differential or bool(self.cfg.attn_pattern)
 
     def _shape(self) -> tuple:
         """(kv rows, query rows a kv row, a row's width, the scores'
@@ -362,7 +373,7 @@ class GroupedQuery(_Kind):
         q = qkv[..., :h * hd].reshape(b, l, h, hd)
         k = qkv[..., h * hd:(h + hkv) * hd].reshape(b, l, hkv, hd)
         v = qkv[..., (h + hkv) * hd:].reshape(b, l, hkv, hd)
-        if cfg.rope:
+        if cfg.rope and not self.nope:
             q = _rope(q, pos, cfg.rope_base)
             k = _rope(k, pos, cfg.rope_base)
         if self.differential:
@@ -382,20 +393,25 @@ class GroupedQuery(_Kind):
         return _biased(params, f"{p}_out", a)
 
     def _scope(self):
-        if not self.differential:
+        if not self.one_form:
             return contextlib.nullcontext()
         return scope("lm.swa" if self.window else "lm.full")
 
     def full(self, params: Params, p: str, y, pos, attn_fn, handed=None):
+        """Through the caller's ``attn_fn`` (one window for every layer),
+        or for a one-form cache attention of its own over its rows, at
+        the layer's window."""
         with self._scope():
             q, rows = self.project(params, p, y, pos)
-            if not self.differential:
+            if not self.one_form:
                 return self._out(params, p, attn_fn(q, *rows), y), rows
+            rows_kv, g, width, scale = self._shape()
             k, v = (jnp.transpose(row, (0, 2, 1, 3)) for row in rows)
             if self.shares:
                 handed["kv"] = (k, v)
-            a = cache_attention(q, k, v, pos, pos, window=self.window,
-                                scale=self._shape()[3])
+            a = cache_attention(q.reshape(*y.shape[:2], rows_kv, g, width),
+                                k, v, pos, pos, window=self.window,
+                                scale=scale)
             return self._out(params, p, a, y), rows
 
     def chunk(self, params: Params, p: str, y, pos, caches: Params, start,
@@ -471,7 +487,7 @@ class GroupedQuery(_Kind):
     def empty(self, p: str, b: int, total: int, dtype,
               kv_q8: bool = False) -> Params:
         rows_kv, _, width, _ = self._shape()
-        if self.differential:
+        if self.one_form:
             _no_int8(kv_q8)
         shape = (b, rows_kv, _cache_shape(self, total)[1])
         out = {n: jnp.zeros(shape + (width,),
@@ -483,7 +499,7 @@ class GroupedQuery(_Kind):
         return out
 
     def padded(self, p: str, rows, total: int, dtype) -> Params:
-        if not self.differential:
+        if not self.one_form:
             return super().padded(p, rows, total, dtype)
         # the one form: the scan's layout, rolled where it rolls
         roll, cache_len = _cache_shape(self, total)
@@ -499,10 +515,10 @@ class GroupedQuery(_Kind):
                 kv_q8: bool = False) -> Params:
         """One transpose at the boundary, not one per step; quantized
         under ``kv_q8``; folded into the rolling layout where the
-        window is shorter than ``total``. (A differential cache is
-        handed out so already.)"""
+        window is shorter than ``total``. (A one-form cache is handed
+        out so already.)"""
         roll, cache_len = _cache_shape(self, total)
-        if self.differential:
+        if self.one_form:
             _no_int8(kv_q8)
             out = {n: caches[n] for n in self.names(p)}
             return _with_snapshot(out, p) if roll else out
@@ -518,8 +534,8 @@ class GroupedQuery(_Kind):
         return {n: _rolled(c, p_len, cache_len) for n, c in out.items()}
 
     def turn_start(self, p: str, caches: Params, start) -> Params:
-        """A differential layer's rolling buffer keeps a snapshot (a
-        plain one is the caller's to copy, as ``decode_from`` says)."""
+        """A one-form layer's rolling buffer keeps a snapshot (a plain
+        one is the caller's to copy, as ``decode_from`` says)."""
         if f"{p}_at0" not in caches:
             return {}
         return _taken_back(self.names(p), p, caches, start)
@@ -536,8 +552,9 @@ def _differential_init(key, dtype, p: str, hd: int) -> Params:
 def _no_int8(kv_q8: bool) -> None:
     if kv_q8:
         raise ValueError("kv_q8 quantizes plain grouped-query caches; a "
-                         "latent cache, a differential pair's and a "
-                         "recurrent state have no int8 form")
+                         "latent cache, a differential pair's, an "
+                         "attention pattern's and a recurrent state have "
+                         "no int8 form")
 
 
 # query rows (batch x positions) of a latent-attention forward over a

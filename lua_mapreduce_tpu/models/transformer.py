@@ -171,7 +171,8 @@ class TransformerConfig:
     # encoding at all (a model whose state-space and window layers carry
     # the order)
     positions: Optional[str] = None
-    # "ln" (pre-LN with bias) or "rms" (RMSNorm, scale only)
+    # "ln" (pre-LN with bias), "rms" (RMSNorm, scale only) or "ln_gain"
+    # (LayerNorm with a gain and no bias: Cohere's)
     norm: str = "ln"
     norm_eps: float = 1e-5
     # the output head is ``tok_emb.T`` (tied) or a matrix of its own
@@ -190,6 +191,17 @@ class TransformerConfig:
     # (rolling O(window) cache), prefill, pipeline, and the BANDED
     # contiguous ring (attn="ring") speak it; zigzag/ulysses reject.
     window: int = 0
+    # () = every layer's attention as ``window`` says. Else ONE period of
+    # the layers' grouped-query attention, layer i having
+    # ``attn_pattern[i % len]``: "swa" (over the last ``window``
+    # positions, rope where ``rope``) or "full_nope" (every position,
+    # no positional encoding): Cohere2's three window layers to one
+    # global NoPE layer. Served only (prefill, decode_from,
+    # greedy_decode, transformer_apply)
+    attn_pattern: Tuple[str, ...] = ()
+    # the parallel block: x + attn(norm(x)) + ffn(norm(x)) from ONE norm
+    # a layer (GPT-J, Cohere), where the default is sequential
+    parallel_block: bool = False
     # mixture-of-experts: >0 replaces every block's dense FFN with a
     # switch-routed expert FFN (parallel/moe.py); 0 = dense. capacity is
     # REQUIRED with experts and is per routing group (the device tile in
@@ -206,7 +218,11 @@ class TransformerConfig:
     # choice (``moe_groups`` groups, the best ``moe_topk_groups`` stay),
     # weights normalised over the selected k and scaled by
     # ``moe_scale``, SwiGLU experts of width ``moe_d_ff`` (0 = d_ff),
-    # ``moe_shared`` shared experts, and NO dropped token (no capacity).
+    # ``moe_shared`` shared experts (summed: a model that averages them
+    # scales their down projections on load), and NO dropped token (no
+    # capacity).
+    # ``moe_router_bias`` False: no selection bias, the choice is on the
+    # scores alone.
     # ``moe_held = (first, count)`` is the range of experts this chip
     # holds (None = all): the router keeps its ``moe_experts`` outputs,
     # the layer computes its own experts' part of the result.
@@ -216,6 +232,7 @@ class TransformerConfig:
     moe_scale: float = 1.0
     moe_d_ff: int = 0
     moe_shared: int = 0
+    moe_router_bias: bool = True
     moe_held: Optional[Tuple[int, int]] = None
     # the first ``moe_first_dense`` layers keep the dense FFN
     moe_first_dense: int = 0
@@ -283,6 +300,10 @@ def attention_kind(cfg: TransformerConfig, i: int):
     reads the configuration for it."""
     if cfg.latent is not None:
         return Latent(cfg)
+    if cfg.attn_pattern:
+        full = cfg.attn_pattern[i % len(cfg.attn_pattern)] == "full_nope"
+        return GroupedQuery(cfg, window=0 if full else cfg.window,
+                            nope=full)
     hy = cfg.hybrid
     if hy is None:
         return GroupedQuery(cfg, window=cfg.window)
@@ -305,8 +326,9 @@ def _has_pos_table(cfg: TransformerConfig) -> bool:
 
 def _check_arch(cfg: TransformerConfig) -> None:
     """Architecture-knob validation shared by init and every factory."""
-    if cfg.norm not in ("ln", "rms"):
-        raise ValueError(f"unknown norm {cfg.norm!r} (want 'ln'|'rms')")
+    if cfg.norm not in ("ln", "rms", "ln_gain"):
+        raise ValueError(f"unknown norm {cfg.norm!r} "
+                         f"(want 'ln'|'rms'|'ln_gain')")
     if cfg.ffn not in ("gelu", "swiglu"):
         raise ValueError(f"unknown ffn {cfg.ffn!r} "
                          f"(want 'gelu'|'swiglu')")
@@ -330,6 +352,12 @@ def _check_arch(cfg: TransformerConfig) -> None:
                              "kept groups must hold moe_top_k experts")
         _moe._check_held(cfg.moe_held or (0, cfg.moe_experts),
                          cfg.moe_experts)
+    if not cfg.moe_router_bias and not (cfg.moe_experts
+                                        and cfg.moe_router == "grouped"):
+        raise ValueError("moe_router_bias is the grouped expert layer's "
+                         "(moe_router='grouped')")
+    if cfg.attn_pattern:
+        _check_pattern(cfg)
     if cfg.latent is not None:
         if not (cfg.rope and cfg.norm == "rms"):
             raise ValueError("latent attention is written for rope=True "
@@ -361,6 +389,20 @@ def _check_arch(cfg: TransformerConfig) -> None:
                          f"learned table) or 'none', which takes no rope")
     if cfg.hybrid is not None:
         _check_hybrid(cfg)
+
+
+def _check_pattern(cfg: TransformerConfig) -> None:
+    """What an attention pattern is written for: grouped-query layers of
+    the two kinds, a window for the "swa" ones, no other stack."""
+    known = ("swa", "full_nope")
+    if set(cfg.attn_pattern) - set(known):
+        raise ValueError(f"attn_pattern names each layer of a period as one "
+                         f"of {known}; got {cfg.attn_pattern}")
+    if cfg.latent is not None or cfg.hybrid is not None:
+        raise ValueError("attn_pattern is a period of grouped-query layers; "
+                         "latent attention and a hybrid stack take none")
+    if "swa" in cfg.attn_pattern and cfg.window < 1:
+        raise ValueError("attn_pattern's 'swa' layers need window >= 1")
 
 
 def _check_hybrid(cfg: TransformerConfig) -> None:
@@ -395,15 +437,25 @@ def _check_hybrid(cfg: TransformerConfig) -> None:
 
 
 def _check_sharded(cfg: TransformerConfig) -> None:
-    """What the sharded forward and the train steps cannot run yet."""
-    if (cfg.latent is not None or cfg.hybrid is not None
-            or (cfg.moe_experts and cfg.moe_router == "grouped")):
+    """What the sharded forward and the train steps cannot run yet, by
+    name: their attention is the caller's one window for every layer,
+    and their blocks (the tensor-parallel and the pipeline ones among
+    them) are sequential."""
+    served = [what for what, has in (
+        ("latent attention", cfg.latent is not None),
+        ("a hybrid stack", cfg.hybrid is not None),
+        ("the grouped expert layer",
+         cfg.moe_experts and cfg.moe_router == "grouped"),
+        (f"an attention pattern {cfg.attn_pattern}", cfg.attn_pattern),
+        ("a parallel block", cfg.parallel_block))
+        if has]
+    if served:
         raise ValueError(
-            "latent attention, a hybrid stack and the grouped expert "
-            "layer are served "
+            f"{', '.join(served)}: served only "
             "(prefill, decode_from, greedy_decode, transformer_apply); "
             "the sharded forward and the train steps run grouped-query "
-            "attention and the switch MoE")
+            "attention with one window, sequential blocks and the switch "
+            "MoE")
 
 
 def _check_moe(cfg: TransformerConfig, n_ep: Optional[int] = None) -> None:
@@ -452,7 +504,7 @@ def init_transformer(key, cfg: TransformerConfig = TransformerConfig(),
             params.update(_moe.init_moe_held(
                 next(keys), d, cfg.moe_d_ff or ff, cfg.moe_experts,
                 cfg.moe_held or (0, cfg.moe_experts), cfg.moe_shared,
-                dtype, prefix=f"{p}_moe"))
+                dtype, prefix=f"{p}_moe", router_bias=cfg.moe_router_bias))
         elif moe_layer(cfg, i):
             params.update(_moe.init_moe(
                 next(keys), d, ff, cfg.moe_experts, dtype,
@@ -466,7 +518,7 @@ def init_transformer(key, cfg: TransformerConfig = TransformerConfig(),
             params[f"{p}_ff1_b"] = jnp.zeros((ff,), dtype)
             params[f"{p}_ff2_W"] = dense((ff, d))
             params[f"{p}_ff2_b"] = jnp.zeros((d,), dtype)
-        for ln in ("ln1", "ln2"):
+        for ln in ("ln1",) if cfg.parallel_block else ("ln1", "ln2"):
             params[f"{p}_{ln}_g"] = jnp.ones((d,), dtype)
             if cfg.norm == "ln":
                 params[f"{p}_{ln}_b"] = jnp.zeros((d,), dtype)
@@ -573,13 +625,21 @@ def _layer(params: Params, i: int, x, cfg: TransformerConfig, attend,
            moe_axis: Optional[str] = None,
            stats_sink: Optional[list] = None):
     """One pre-norm decoder layer, written once: norm, attention,
-    residual; norm, FFN, residual. ``attend(kind, p, y) -> (out, kept)``
-    is the form of layer ``i``'s attention kind the caller runs on the
-    normed input (a full sequence, a chunk over a growing cache, one
-    position) with what that form hands back; it is called here, once,
-    so a caller's closure sees its loop's current caches. Returns (x,
-    moe aux, kept)."""
+    residual; norm, FFN, residual; or with ``cfg.parallel_block`` one
+    norm whose output both take, ``x + attn + ffn``. ``attend(kind, p,
+    y) -> (out, kept)`` is the form of layer ``i``'s attention kind the
+    caller runs on the normed input (a full sequence, a chunk over a
+    growing cache, one position) with what that form hands back; it is
+    called here, once, so a caller's closure sees its loop's current
+    caches. Returns (x, moe aux, kept)."""
     p = f"L{i}"
+    if cfg.parallel_block:
+        with scope("lm.attn"):
+            y = _norm(params, f"{p}_ln1", x, cfg)
+            a, kept = attend(attention_kind(cfg, i), p, y)
+        with scope("lm.ffn"):
+            out, aux = _ffn(params, i, y, cfg, moe_axis, stats_sink)
+            return x + a + out, aux, kept
     with scope("lm.attn"):
         out, kept = attend(attention_kind(cfg, i), p,
                            _norm(params, f"{p}_ln1", x, cfg))
@@ -683,9 +743,12 @@ def prefill(params: Params, prompt, *,
     positions, an attention layer ``L{i}_{k,v}`` as (B, H_kv / 2, S,
     2 Dh) pairs with S the window's slots where the layer has one, a
     cross layer and a memory unit nothing; the layers that cache
-    nothing run for the last position only. With ``chunk``
+    nothing run for the last position only. A stack with an
+    ``attn_pattern`` hands its (k, v) out as the scan carries them too,
+    (B, H_kv, S, Dh), rolling in its window layers. With ``chunk``
     (single-device, kinds whose caches have that one form: latent
-    attention, a hybrid stack; it must divide P) the prompt goes through
+    attention, a hybrid stack, an attention pattern; it must divide P)
+    the prompt goes through
     the layers that many positions at a time over the growing cache, so
     a long prompt's activations exist for one chunk only.
     Dense and MoE configs single-device; the
@@ -709,8 +772,8 @@ def prefill(params: Params, prompt, *,
             raise ValueError(
                 "chunk is for a single device and kinds whose caches "
                 "have one form, prefill's and the scan's (latent "
-                "attention, a hybrid stack's layers), and must divide "
-                f"the prompt ({p_len})")
+                "attention, a hybrid stack's layers, an attention "
+                f"pattern's), and must divide the prompt ({p_len})")
         return _prefill_chunked(params, tokens, cfg_fwd, total, chunk)
     if mesh is None:
         # backend="auto": the fused flash kernel on TPU — prefilling a

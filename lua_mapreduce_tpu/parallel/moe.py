@@ -359,12 +359,14 @@ def moe_ffn_shard(params: Params, x, *, capacity: int, ep_axis: str,
 
 def init_moe_held(key, d_model: int, d_ff: int, n_experts: int,
                   held: Tuple[int, int], n_shared: int = 0,
-                  dtype=jnp.float32, prefix: str = "moe") -> Params:
-    """The router over all ``n_experts`` (weights and the selection
-    bias, zero at initialisation: training's load balancing sets it) and
-    the SwiGLU weights of the ``held = (first, count)`` experts,
-    stacked; with ``n_shared`` a shared expert of that many times the
-    width."""
+                  dtype=jnp.float32, prefix: str = "moe",
+                  router_bias: bool = True) -> Params:
+    """The router over all ``n_experts`` (weights and, with
+    ``router_bias``, the selection bias, zero at initialisation:
+    training's load balancing sets it) and the SwiGLU weights of the
+    ``held = (first, count)`` experts, stacked; with ``n_shared`` the
+    shared experts side by side, one SwiGLU of that many times the
+    width (its down projection sums theirs)."""
     _check_held(held, n_experts)
     ks = jax.random.split(key, 8)
     count = held[1]
@@ -372,7 +374,6 @@ def init_moe_held(key, d_model: int, d_ff: int, n_experts: int,
     out = {
         f"{prefix}_router_W": s1 * jax.random.normal(
             ks[0], (d_model, n_experts), dtype),
-        f"{prefix}_router_b": jnp.zeros((n_experts,), jnp.float32),
         f"{prefix}_wg": s1 * jax.random.normal(
             ks[2], (count, d_model, d_ff), dtype),
         f"{prefix}_wu": s1 * jax.random.normal(
@@ -380,6 +381,8 @@ def init_moe_held(key, d_model: int, d_ff: int, n_experts: int,
         f"{prefix}_wd": s2 * jax.random.normal(
             ks[4], (count, d_ff, d_model), dtype),
     }
+    if router_bias:
+        out[f"{prefix}_router_b"] = jnp.zeros((n_experts,), jnp.float32)
     if n_shared:
         w = n_shared * d_ff
         out[f"{prefix}_sg"] = s1 * jax.random.normal(ks[5], (d_model, w),
@@ -401,7 +404,8 @@ def _check_held(held: Tuple[int, int], n_experts: int) -> None:
 def route_grouped(x, router_w, bias, *, top_k: int, n_groups: int,
                   topk_groups: int, scale: float):
     """Sigmoid scores with a group-limited choice (DeepSeek-V3's
-    `noaux_tc`): the choice is made on ``score + bias``: a group's
+    `noaux_tc`): the choice is made on ``score + bias`` (on the score
+    where ``bias`` is None): a group's
     score is the sum of its two highest, the ``topk_groups`` best groups
     stay, and the ``top_k`` highest of what they hold are selected. The
     weights are the scores themselves (no bias), normalised over the
@@ -410,7 +414,7 @@ def route_grouped(x, router_w, bias, *, top_k: int, n_groups: int,
     sc = jax.nn.sigmoid(x.astype(jnp.float32)
                         @ router_w.astype(jnp.float32))        # (T, E)
     t, e = sc.shape
-    choice = sc + bias.astype(jnp.float32)
+    choice = sc if bias is None else sc + bias.astype(jnp.float32)
     if n_groups > 1:
         grouped = choice.reshape(t, n_groups, e // n_groups)
         group_score = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)
@@ -441,9 +445,10 @@ def moe_ffn_held(params: Params, x, *, held: Tuple[int, int], top_k: int,
     """This chip's part of the expert layer for the flat (T, d) tile
     ``x``: the router runs over all experts, and of the result
     ``sum_i g_i E_i(x)`` the terms of the held experts are computed,
-    plus the shared expert (once on every chip; ``shared=False`` leaves
-    it to another share). No capacity and no dropped token; what the
-    absent experts would add is left out, and no exchange runs.
+    plus the shared experts' sum (once on every chip; ``shared=False``
+    leaves them to another share). No capacity and no dropped token; what the
+    absent experts would add is left out, and no exchange runs. A router
+    without ``router_b`` chooses on its scores alone.
 
     An expert that no token of the tile chose is never computed, so its
     weights are not read: at decode, a step streams the experts it
@@ -459,7 +464,7 @@ def moe_ffn_held(params: Params, x, *, held: Tuple[int, int], top_k: int,
     t, d = x.shape
     with scope("lm.moe.route"):
         expert, weight = route_grouped(
-            x, params[f"{prefix}_router_W"], params[f"{prefix}_router_b"],
+            x, params[f"{prefix}_router_W"], params.get(f"{prefix}_router_b"),
             top_k=top_k, n_groups=n_groups, topk_groups=topk_groups,
             scale=scale)
         local = expert - first                              # (T, k)

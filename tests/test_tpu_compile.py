@@ -607,3 +607,36 @@ def test_the_hybrid_session_entry_reads_one_shared_cache_in_place(
     # (and seventeen scalars: the positions the snapshots are of)
     assert held <= memory.alias_size_in_bytes < held + 2 ** 16
     assert memory.temp_size_in_bytes < 128 * 2 ** 20
+
+
+# --------------------------------------------------------------------------
+# an attention pattern with a parallel block (PR 39): the
+# command-a-plus-05-2026 cell's turn, its four layers whole
+# --------------------------------------------------------------------------
+
+def test_the_pattern_session_entry_keeps_its_caches_in_place(topo,
+                                                             chip_policy):
+    """One period at the cell's size (16 sessions, 32,768 cached
+    positions, 64 scanned): four calls of the decode kernel a step,
+    three over rolling windows of 4096 and one over the whole cache of
+    32,832; one grouped expert call a layer and no conditional; the
+    donated caches and the windows' snapshots written in place, and a
+    scan whose temporaries are a few megabytes."""
+    from perfbench.model_cmdaplus import program_config
+    cfg = program_config(serve_config("command-a-plus-05-2026.serve-ep8"))
+    compiled = compiled_turn(topo, cfg, 16, 32768, 64)
+    text = compiled.as_text()
+    lengths = re.findall(
+        r"= f32\[128,16,128\][^ ]* custom-call\([^)]*\), "
+        r"custom_call_target=\"tpu_custom_call\", operand_layout_constraints="
+        r"\{s32\[1\]\{0\}, bf16\[128,16,128\]\{[^}]*\}, bf16\[128,(\d+),128\]",
+        text)
+    assert sorted(lengths) == ["32832", "4096", "4096", "4096"]
+    assert len(held_expert_calls(text, 16, 4096, 4096)) == 4
+    assert not re.findall(r" conditional\(", text)
+    no_copy_of_an_expert_stack(text, 16, 4096, 4096)
+    memory = compiled.memory_analysis()
+    held = (2 * 16 * 8 * 32832 * 128 * 2             # the full layer's
+            + 2 * 3 * 2 * 16 * 8 * 4096 * 128 * 2)   # windows, snapshots
+    assert held <= memory.alias_size_in_bytes < held + 2 ** 16
+    assert memory.temp_size_in_bytes < 16 * 2 ** 20
