@@ -112,6 +112,7 @@ def stage_trainer(log, devices, mesh_shape, *, lm, batch, seq,
 
     from lua_mapreduce_tpu import ops
     from lua_mapreduce_tpu.models import transformer as tfm
+    from lua_mapreduce_tpu.parallel.mesh import opt_state_layout
 
     snap = log.counts()
     dp, sp = mesh_shape
@@ -127,7 +128,7 @@ def stage_trainer(log, devices, mesh_shape, *, lm, batch, seq,
     params = jax.tree.map(
         lambda x: x.astype(jnp.bfloat16),
         tfm.init_transformer(jax.random.PRNGKey(0), cfg))
-    params = tfm.shard_params_moe(params, mesh)     # dense: replicated
+    params = tfm.shard_params_moe(params, mesh)     # dense: split by rows
     # Adam: its step is ~lr per weight whatever the gradient's scale, so
     # it survives bfloat16 weights (lr * grad of plain SGD rounds away)
     opt = optax.adam(1e-3)
@@ -176,6 +177,8 @@ def stage_trainer(log, devices, mesh_shape, *, lm, batch, seq,
         attention_backend=expect_backend, losses=losses,
         smoke_first_call_s=walls[0], smoke_later_calls_s=walls[1:],
         programs_after_first_step=built_later,
+        opt_state=dict(zip(("bytes_a_device", "bytes", "leaves_split",
+                            "leaves_whole"), opt_state_layout(opt_state))),
         **_memory_spread(mesh))
 
 

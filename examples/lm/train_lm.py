@@ -326,6 +326,7 @@ def _run_inner(args, jax) -> dict:
     reached = target is None
     t0 = time.time()
     warm_t0 = None              # tokens/sec excludes the compile step
+    from lua_mapreduce_tpu.parallel.mesh import opt_state_layout
     from lua_mapreduce_tpu.utils.profiling import build_log, build_table
     builds, built = build_log(), None   # programs built by the last look
     i = start_step
@@ -388,6 +389,10 @@ def _run_inner(args, jax) -> dict:
                 # where set-up went: every program built up to here
                 print(build_table(builds.rows(), builds.process_start),
                       flush=True)
+                held, total, split, whole = opt_state_layout(opt_state)
+                print(f"optimizer state: {held / 1e9:.4g} of "
+                      f"{total / 1e9:.4g} GB a device, {split} leaves "
+                      f"split, {whole} whole", flush=True)
                 built = builds.programs
             elif builds.programs != built:
                 for row in builds.rows(built):

@@ -12,9 +12,11 @@ forms that must agree (golden-diff discipline, SURVEY.md §4):
   (all_to_all head reshard). No device ever holds a full sequence —
   context length scales with the sp axis.
 - :func:`make_train_step` — jitted SPMD LM training step over the mesh:
-  per-device loss on its (batch, seq) tile, gradient all-reduce over
+  per-device loss on its (batch, seq) tile, the gradients summed over
   BOTH axes behind the backward pass, in the same program (the
-  reference's reducefn-sum shape, common.lua:112-137).
+  reference's reducefn-sum shape, common.lua:112-137), each device
+  updating its rows of every leaf, the weights gathered where a step
+  takes them.
 
 Params are a flat name→array dict (the grad-shuffle key space, like every
 model in this zoo). Layout: activations (B, L, D); attention heads split
@@ -41,6 +43,7 @@ from lua_mapreduce_tpu.ops.attention import flash_attention
 from lua_mapreduce_tpu.ops.q8 import quantize_q8
 from lua_mapreduce_tpu.parallel import moe as _moe
 from lua_mapreduce_tpu.parallel import zero1 as _z1
+from lua_mapreduce_tpu.parallel.mesh import rows_layout, rows_spec
 from lua_mapreduce_tpu.parallel.pipeline import pipeline_apply
 from lua_mapreduce_tpu.parallel.ring_attention import (
     _NEG_INF, _ring_shard, _ring_shard_zigzag, _ulysses_shard,
@@ -1305,21 +1308,44 @@ def param_specs_moe(ep_axis: str = "dp") -> Dict[str, object]:
 
 def shard_params_moe(params: Params, mesh, *, ep_axis: str = "dp"
                      ) -> Params:
-    """device_put params with expert stacks sharded over ``ep_axis``
-    (every other leaf, so every leaf of a dense model, replicated)."""
+    """device_put params where :func:`make_train_step` keeps them between
+    steps: in an expert model the expert stacks sharded over ``ep_axis``
+    and every other leaf replicated; in a dense model on more than one
+    device every leaf split by rows (:func:`parallel.mesh.rows_layout`),
+    as the optimizer state is, and gathered inside the step."""
+    if _splits_rows(mesh, _has_experts(params)):
+        return jax.device_put(params, rows_layout(mesh, params))
     specs = param_specs_moe(ep_axis)
     return {k: jax.device_put(v, NamedSharding(mesh, _spec_for(k, specs)))
             for k, v in params.items()}
 
 
+def _has_experts(params: Params) -> bool:
+    return any(_spec_for(k, param_specs_moe()) != P() for k in params)
+
+
+def _splits_rows(mesh, moe: bool) -> bool:
+    """Whether :func:`make_train_step` reduce-scatters the gradients and
+    keeps weights and optimizer state split by rows: on a mesh of more
+    than one device, in a dense model (an expert model spends dp on
+    experts)."""
+    return mesh.size > 1 and not moe
+
+
 def init_opt_state(optimizer, params: Params, mesh):
     """``optimizer.init(params)`` laid out the way :func:`make_train_step`'s
     step returns it; ``params`` must already live on ``mesh``
-    (:func:`shard_params_moe`). The moments inherit their parameter's
+    (:func:`shard_params_moe`). Where the step splits the state (a dense
+    model on more than one device) every leaf is made in the layout of
+    :func:`parallel.mesh.rows_layout` by one program, so no device holds
+    more than its rows. Otherwise the moments inherit their parameter's
     layout, but what ``init`` makes from nothing (Adam's step count) is
     left uncommitted; the first step returns it committed to the mesh,
     and that one changed input type makes the SECOND step trace and
     compile the whole program again."""
+    if _splits_rows(mesh, _has_experts(params)):
+        layout = rows_layout(mesh, jax.eval_shape(optimizer.init, params))
+        return jax.jit(optimizer.init, out_shardings=layout)(params)
     replicated = NamedSharding(mesh, P())
 
     def place(x):
@@ -1336,14 +1362,30 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer, *,
                     zigzag_layout: bool = False, zero1: bool = False):
     """Jitted SPMD LM train step: ``step(params, opt_state, tokens,
     targets) -> (params, opt_state, loss)`` with tokens/targets sharded
-    P(dp, sp). The gradient all-reduce over dp AND sp is the transpose
-    of the loss's pmean, no call of this function's, and the one time a
-    gradient crosses the wire: it types each leaf as unvarying over the
-    axes it summed over, which is what shard_map's vma check (left ON)
-    asks of `out_specs`. XLA runs it in line behind the leaf's
-    weight-gradient matmul, hidden by nothing (21.8 ms a step on the
-    2x2, all exposed; my chip run, PR 29). Every gradient leaf is
-    written out in its own type before the optimizer reads it.
+    P(dp, sp). Every gradient crosses the wire once, summed over dp AND
+    sp, and XLA runs that in line behind the leaf's weight-gradient
+    matmul, hidden by nothing (PERF.md section 5). How, by what the mesh
+    and the configuration are:
+
+    - a dense model on more than one device: each device differentiates
+      its share of the mean (its tile's loss over the device count)
+      with respect to its own copy of the weights, and reduce-scatters
+      each leaf along its rows over every device (``rows_spec`` in
+      `parallel/mesh.py`), where the optimizer state lives
+      (:func:`init_opt_state` puts it there). The update runs outside
+      the shard_map, on each device's rows, so any optax transformation
+      stays right: one that reads across a leaf (``clip_by_global_norm``)
+      gets its sum over the devices from the partitioner. The weights
+      stay split by rows between steps too (:func:`shard_params_moe`
+      puts them there), and are all-gathered in their own dtype where
+      the next step's shard_map takes them. A leaf whose rows do not
+      divide is all-reduced, updated and kept whole;
+    - one device, or an expert model: the sum is the transpose of the
+      loss's pmean, no call of this function's, and the update is
+      replicated (an expert leaf is summed over sp alone).
+
+    shard_map's vma check stays ON. Every gradient leaf is written out
+    in its own type before the optimizer reads it.
 
     ``grad_accum`` > 1 folds that many microbatches (split along each
     device's batch rows) in a lax.scan before the single optimizer
@@ -1396,6 +1438,14 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer, *,
         moe_axis = dp_axis
     block = functools.partial(_block, moe_axis=moe_axis)
     suffix = param_specs_moe(dp_axis)
+    scatter = _splits_rows(mesh, bool(cfg.moe_experts))
+    devices, every_axis = mesh.size, tuple(mesh.axis_names)
+
+    def exchange(g):
+        if rows_spec(mesh, g.shape) == P():
+            return lax.psum(g, every_axis)
+        return lax.psum_scatter(g, every_axis, scatter_dimension=0,
+                                tiled=True)
 
     def shard_step(params, tokens, targets):
         l_loc = tokens.shape[1]
@@ -1405,14 +1455,25 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer, *,
         def global_loss(p, tok, tgt):
             local = lm_loss_local(p, tok, tgt, cfg, attn_shard,
                                   pos, block=block)
+            if scatter:
+                # this device's share of the mean (1/n is exact for a
+                # power of two, and scales a scalar, not a gradient);
+                # the transpose of the sum sums nothing
+                return lax.psum(local / devices, every_axis)
             return lax.pmean(lax.pmean(local, sp_axis), dp_axis)
 
+        if scatter:
+            # this device's own copy: the transpose of a replicated
+            # weight's use would be an all-reduce of its gradient
+            params = lax.pcast(params, every_axis, to="varying")
         if grad_accum == 1:
             loss, grads = jax.value_and_grad(global_loss)(
                 params, tokens, targets)
         else:
             loss, grads = accum_value_and_grad(
                 global_loss, params, (tokens, targets), grad_accum)
+        if scatter:
+            grads = {k: exchange(g) for k, g in grads.items()}
         # each leaf is written out in its own type before anything
         # reads it. Left alone on one device, XLA fuses optimizer.update
         # into the weight-gradient matmuls' output and they run at half
@@ -1420,9 +1481,10 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer, *,
         # f32[4096,14336], ...)` 0.1982 s and `(bf16[14336,4096],
         # f32[14336,4096], ...)` 0.0821 s over 3 steps, 16.5 and 13.7 ms
         # an instance for a 7.3 ms matmul (ledger, PR 26; ISSUE 27). On
-        # a mesh the all-reduce already stands between the two: there the
-        # barrier moves a few small values to another memory space, costs
-        # nothing (PERF.md section 6, PR 29) and changes no instruction.
+        # a mesh the exchange already stands between the two: there the
+        # barrier moves a few small values to another memory space and
+        # costs nothing (PERF.md section 6), and its only other effect is
+        # the placement `tests/test_tpu_compile.py` pins.
         # (An expert leaf was summed over sp alone and stays varying
         # over dp, as its spec says.)
         return loss, {k: lax.optimization_barrier(g)
@@ -1481,14 +1543,25 @@ def make_train_step(cfg: TransformerConfig, mesh, optimizer, *,
                           P(dp_axis, sp_axis)),
                 out_specs=(P(), st_specs, P()), check_vma=False)
             return mapped(params, opt_state, tokens, targets)
+        grad_specs = {k: rows_spec(mesh, v.shape)
+                      for k, v in params.items()} if scatter else specs
         mapped = shard_map(
             shard_step, mesh=mesh,
             in_specs=(specs, P(dp_axis, sp_axis), P(dp_axis, sp_axis)),
-            out_specs=(P(), specs))
+            out_specs=(P(), grad_specs))
         loss, grads = mapped(params, tokens, targets)
         with scope("lm.opt"):
             updates, opt_state = optimizer.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
+            if scatter:
+                # each device makes and keeps its rows of the new
+                # weights, beside those of the state: the next step
+                # gathers them where the shard_map needs them whole (as
+                # an output, a gathered weight costs a copy more)
+                params = lax.with_sharding_constraint(
+                    params, rows_layout(mesh, params))
+                opt_state = lax.with_sharding_constraint(
+                    opt_state, rows_layout(mesh, opt_state))
         return params, opt_state, loss
 
     return jax.jit(lm_train_step, donate_argnums=(0, 1))

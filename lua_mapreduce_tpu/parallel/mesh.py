@@ -60,3 +60,47 @@ def host_mesh(n: int = 8, dp: Optional[int] = None, mp: int = 1):
 
 def mesh_axis_size(mesh, name: str) -> int:
     return mesh.shape[name]
+
+
+def rows_spec(mesh, shape):
+    """Where the default train step keeps a leaf of its weights and of
+    its optimizer state, and the gradient that updates it: rows split
+    over every device of the mesh (``P(axis_names)`` on dim 0) where
+    their count divides evenly, else the leaf whole on every device (a
+    scalar, a one-device mesh, a leaf of ragged rows)."""
+    from jax.sharding import PartitionSpec as P
+
+    n = mesh.size
+    if n > 1 and len(shape) and shape[0] % n == 0:
+        return P(tuple(mesh.axis_names))
+    return P()
+
+
+def rows_layout(mesh, tree):
+    """:func:`rows_spec` of every leaf of ``tree`` (arrays, or anything
+    with a ``shape``) as a ``NamedSharding`` on ``mesh``."""
+    import jax
+    from jax.sharding import NamedSharding
+
+    return jax.tree.map(
+        lambda x: NamedSharding(mesh, rows_spec(mesh, x.shape)), tree)
+
+
+def opt_state_layout(opt_state) -> Tuple[int, int, int, int]:
+    """(bytes a device holds, bytes in all, leaves split, leaves whole) of
+    an optimizer state on the devices (arrays, or shapes with a
+    sharding): what :func:`rows_layout` saves. A leaf is an array of one
+    dimension or more; scalars (step counts) count in the bytes alone."""
+    import math
+
+    import jax
+
+    held = total = split = whole = 0
+    for x in jax.tree.leaves(opt_state):
+        shard = tuple(x.sharding.shard_shape(x.shape))
+        held += math.prod(shard) * x.dtype.itemsize
+        total += math.prod(x.shape) * x.dtype.itemsize
+        if x.ndim:
+            split += shard != tuple(x.shape)
+            whole += shard == tuple(x.shape)
+    return held, total, split, whole

@@ -71,6 +71,14 @@ def test_stage_trainer(log, mesh_shape, moe):
     assert line["losses"][-1] < line["losses"][0]
     assert line["programs_after_first_step"] == 0
     assert line["devices"] == mesh_shape[0] * mesh_shape[1]
+    # the state split by rows where the step splits it: dense, on four
+    state = line["opt_state"]
+    if mesh_shape == (2, 2) and not moe:
+        assert state["leaves_whole"] == 0
+        assert state["bytes_a_device"] < state["bytes"] / 3
+    elif not moe:
+        assert state["leaves_split"] == 0
+        assert state["bytes_a_device"] == state["bytes"]
     built_programs(line, "lm_train_step")
     json.dumps(line)
 

@@ -187,3 +187,8 @@ def test_lm_example_smoke():
         capture_output=True, text=True, timeout=300, cwd=REPO, env=env)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "done: final loss" in out.stdout
+    # the first step's table says where the optimizer state lives: split
+    # by rows over the four devices
+    (held,) = [line for line in out.stdout.splitlines()
+               if line.startswith("optimizer state: ")]
+    assert held.endswith(" whole") and not held.endswith(" 0 split, 0 whole")

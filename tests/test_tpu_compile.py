@@ -23,6 +23,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from lua_mapreduce_tpu import ops
 from lua_mapreduce_tpu.models import transformer as tfm
+from lua_mapreduce_tpu.parallel.mesh import rows_layout
 from lua_mapreduce_tpu.train.precision import with_f32_master
 
 CONFIG = os.path.join(os.path.dirname(os.path.dirname(
@@ -75,22 +76,27 @@ def chip_policy(monkeypatch):
 
 def compiled_step(topo, cfg, shape) -> str:
     """The train step as the cells build it (bfloat16 weights, float32
-    masters under Adam, state replicated), compiled for `shape` chips."""
+    masters under Adam, weights and state where `shard_params_moe` and
+    `init_opt_state` put them: split by rows over the chips,
+    `parallel.mesh.rows_layout`), compiled for `shape` chips."""
     dp, sp = shape
     mesh = Mesh(np.array(topo.devices[:dp * sp]).reshape(dp, sp),
                 ("dp", "sp"))
     placed = lambda tree, spec: jax.tree.map(  # noqa: E731
         lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=NamedSharding(mesh, spec)), tree)
+    by_rows = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x, at: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=at),
+        tree, rows_layout(mesh, tree))
     opt = with_f32_master(optax.adam(3e-4))
     params = jax.eval_shape(
         lambda: {k: v.astype(jnp.bfloat16) for k, v in tfm.init_transformer(
             jax.random.PRNGKey(0), cfg).items()})
-    state = placed(jax.eval_shape(opt.init, params), P())
+    state = by_rows(jax.eval_shape(opt.init, params))
     tokens = placed(jax.ShapeDtypeStruct((ROWS[shape], SEQ), jnp.int32),
                     P("dp", "sp"))
     step = tfm.make_train_step(cfg, mesh, opt, attn="ring")
-    return step.lower(placed(params, P()), state, tokens,
+    return step.lower(by_rows(params), state, tokens,
                       tokens).compile().as_text()
 
 
@@ -155,63 +161,56 @@ def written(line: str) -> str:
     return opcode + " " + re.sub(r"\{[^{}]*\}", "", out)
 
 
-# What PR 27's barrier changes in the one-layer 2x2 step since PR 29, and
-# all it changes. (Until then three all-reduces stood between a gradient
-# and the optimizer and the two programs were equal line for line; now
-# one does.) With the barrier the compiler keeps the [4096] norm scales,
-# their masters and moments, and two [4, 2048, 8, 128] blocks of the
-# attention's backward pass in its second memory space (`S(1)`) around
-# their use, copied in and out; without it, it prefetches one
-# [4096, 14336] and one [4096, 4096] weight there instead.
-# The instructions that read or write one of these are the same on both
-# sides but for that memory space and for the windows and cycles chosen
-# after it: five fusions (four of kind kOutput, and the [4096, 4096]
-# update) are tiled otherwise, and two all-reduces alias other operands.
-# What that costs on the chip: PERF.md section 6, PR 29.
+# What PR 27's barrier changes in the one-layer 2x2 step, and all it
+# changes, since each chip updates and keeps its own rows of weights and
+# state. With the barrier the compiler hands the updates of the
+# [1024, 4096] and [8000, 4096] rows and of the three norm gains' [1024]
+# their slice of the bfloat16 weight as an operand, cut by a fusion of
+# its own (the [8000, 4096] one fetched in four slices, joined and copied
+# back); without it each update fusion takes the whole weight and an
+# offset and slices inside. The instructions that read or write one of
+# these are the same on both sides but for that: five update fusions
+# take other operands. What the barrier costs on the chip: PERF.md
+# section 6.
 PLACED_OTHERWISE = {
-    "add f32[4,2048,8,128]": 2, "add f32[4096]": 12,
-    "all-reduce (bf16[4096,14336], bf16[4096], bf16[4096], bf16[4096])": 1,
-    "all-reduce (bf16[4096,4096], bf16[4096,6144])": 1,
-    "bitcast bf16[4,2048,1024]": 2,
-    "convert bf16[4,2048,8,128]": 2, "convert bf16[4096]": 3,
-    "convert f32[4096]": 3,
-    "fusion (bf16[4,2048], bf16[4096], bf16[4,2048,4096])": 1,
-    "fusion (bf16[4096,4096], f32[4096,4096], f32[4096,4096], "
-    "f32[4096,4096])": 1,
-    "fusion (bf16[4096], f32[4096], f32[4096], f32[4096])": 3,
-    "fusion (f32[4,2048], bf16[4,2048,4096])": 1,
-    "fusion bf16[4,2048,8,128]": 2, "fusion bf16[4096,6144]": 1,
-    "fusion f32[4,2048,4096]": 1,
-    "get-tuple-element bf16[4096]": 3, "get-tuple-element f32[4096]": 9,
-    "parameter bf16[4,2048,1024]": 4, "parameter bf16[4096,4096]": 5,
-    "parameter bf16[4096,6144]": 2, "parameter bf16[4096]": 6,
-    "parameter f32[4096]": 9,
-    "tuple (bf16[4096], f32[4096], f32[4096], f32[4096])": 3,
+    "add f32[8000,4096]": 1, "convert bf16[8000,4096]": 1,
+    "convert f32[8000,4096]": 1, "dynamic-slice bf16[1024,4096]": 1,
+    "dynamic-slice bf16[1024]": 3, "dynamic-slice bf16[8000,4096]": 1,
+    "fusion (bf16[1024,4096], f32[1024,4096], f32[1024,4096], "
+    "f32[1024,4096])": 1,
+    "fusion (bf16[1024], f32[1024], f32[1024], f32[1024])": 3,
+    "fusion (bf16[8000,4096], f32[8000,4096], f32[8000,4096], "
+    "f32[8000,4096])": 1,
+    "get-tuple-element bf16[8000,4096]": 1, "parameter bf16[1408,4096]": 1,
+    "parameter bf16[8000,4096]": 1, "parameter bf16[8256,4096]": 1,
+    "parameter f32[1024,4096]": 1, "parameter f32[1024]": 3,
+    "parameter f32[8000,4096]": 1, "parameter u32[]": 2,
+    "tuple (bf16[8000,4096], f32[8000,4096], f32[8000,4096], "
+    "f32[8000,4096])": 1,
 }
 MOVED_WITH_THE_BARRIER = {
-    "copy-start (bf16[4,2048,8,128], bf16[4,2048,8,128], u32[])": 2,
-    "copy-done bf16[4,2048,8,128]": 2,
-    "copy-start (bf16[4096], bf16[4096], u32[])": 8,
-    "copy-done bf16[4096]": 8,
-    "copy-start (f32[4096], f32[4096], u32[])": 18,
-    "copy-done f32[4096]": 18,
+    "fusion bf16[1024,4096]": 1, "fusion bf16[8000,4096]": 1,
+    "parameter bf16[1024,4096]": 1, "parameter bf16[8000,4096]": 1,
+    "parameter bf16[1024]": 3,
+    "slice-start ((bf16[8000,4096]), bf16[2000,4096], s32[])": 4,
+    "slice-done bf16[2000,4096]": 4,
+    "custom-call bf16[8000,4096]": 1,       # ConcatBitcast of the slices
+    "copy-start (bf16[8000,4096], bf16[8000,4096], u32[])": 1,
+    "copy-done bf16[8000,4096]": 1,
 }
 MOVED_WITHOUT_IT = {
-    "copy-start (bf16[4096,14336], bf16[4096,14336], u32[])": 1,
-    "copy-done bf16[4096,14336]": 1,
-    "slice-start ((bf16[4096,4096]), bf16[1024,4096], s32[])": 4,
-    "slice-done bf16[1024,4096]": 4,
-    "custom-call bf16[4096,4096]": 1,       # ConcatBitcast of the slices
+    "parameter bf16[4096]": 3,
+    "parameter u32[]": 3,                   # the offsets of the slices
 }
 
 
 def test_2x2_program_is_the_one_without_the_barrier(topo, cfg, chip_policy,
                                                     as_built, monkeypatch):
-    """Behind the all-reduce the barrier changes no instruction: the 2x2
-    step's are those of the same step built without it, line for line,
-    but for the placement pinned above."""
+    """Behind the reduce-scatter the barrier changes no instruction: the
+    2x2 step's are those of the same step built without it, line for
+    line, but for the placement pinned above."""
     with_barrier = as_built((2, 2))
-    assert "all-reduce" in with_barrier
+    assert "reduce-scatter" in with_barrier
     with monkeypatch.context() as m:
         m.setattr(jax.lax, "optimization_barrier", lambda x: x)
         without = compiled_step(topo, cfg, (2, 2))
@@ -222,7 +221,7 @@ def test_2x2_program_is_the_one_without_the_barrier(topo, cfg, chip_policy,
         PLACED_OTHERWISE) + collections.Counter(MOVED_WITH_THE_BARRIER)
     assert differs(without, with_barrier) == collections.Counter(
         PLACED_OTHERWISE) + collections.Counter(MOVED_WITHOUT_IT)
-    assert sum(with_barrier.values()) > 2500        # of which 133 and 88
+    assert sum(with_barrier.values()) > 3100        # of which 43 and 31
 
 
 def entry_instructions(text: str) -> dict:
@@ -246,44 +245,85 @@ def array_bytes(out: str) -> int:
                for kind, dims in re.findall(r"(\w+)\[([\d,]+)\]", out))
 
 
+def collectives(text: str) -> dict:
+    """(kind, bytes carried, output type, replica groups) of every
+    collective of a compiled program, by a key of its own. A
+    reduce-scatter carries its operand, an all-gather its result, an
+    all-reduce both. The chip runs some reduce-scatters as a fusion
+    whose computation, `all-reduce-scatter`, pads the operand, sums it
+    whole and slices: one reduce-scatter of the fusion's operand. An
+    asynchronous collective stands in the computations of its start,
+    its body and its end, on one channel: one collective. (Channels are
+    no key elsewhere: the synchronous reduce-scatters share one.)"""
+    found = {}
+    for entry, computation, operands, body in re.findall(
+            r"^(ENTRY )?%([\w.\-]+) \(([^\n]*)\) -> [^\n]*\{\n(.*?)^\}",
+            text, re.M | re.S):
+        for name, out, opcode, rest in re.findall(
+                r"%([\w.\-]+) = (.*?) (all-reduce|all-gather|reduce-scatter|"
+                r"collective-permute|all-to-all)(?:-start)?\((.*)", body):
+            groups = re.search(r"replica_groups=(\{\{[\d,]*\}\}|\S+<=\[\d+\])",
+                               rest)
+            groups = groups and groups.group(1).replace(
+                "[1,4]<=[4]", "{{0,1,2,3}}")
+            carried = array_bytes(out)
+            key = name
+            if opcode == "reduce-scatter":
+                carried *= len(groups.strip("{}").split(","))
+            elif computation.startswith("all-reduce-scatter"):
+                opcode, carried = "reduce-scatter", array_bytes(operands)
+            elif not entry:
+                key = re.search(r"channel_id=(\d+)", rest).group(1)
+            found.setdefault(key, (opcode, carried, out, groups))
+    return found
+
+
 def test_2x2_gradients_cross_the_wire_once(cfg, chip_policy, as_built):
     """ISSUE 29: until then every gradient went through three all-reduces
-    back to back, the sum over the four chips and two `pmean`s of a value
-    that was already the same on every chip. Every all-reduce that
-    carries an array is over all four chips, none reads what another
-    wrote (directly or through anything else), and together they carry
-    the parameters' bytes once."""
-    found = entry_instructions(as_built((2, 2)))
-    carrying = {name for name, (out, opcode, _, _) in found.items()
-                if opcode in ("all-reduce", "all-reduce-start")
-                and array_bytes(out)}
-    assert carrying
-    for name in carrying:
-        assert "replica_groups={{0,1,2,3}}" in found[name][3], name
-
-    def behind(name, seen):
-        for operand in found.get(name, ("", "", ()))[2]:
-            if operand not in seen:
-                seen.add(operand)
-                behind(operand, seen)
-        return seen
-
-    for name in carrying:
-        assert not carrying & behind(name, set()), name
+    back to back. Since weights and optimizer state are split by rows, a
+    gradient crosses the wire once as a reduce-scatter over all four
+    chips, and a bfloat16 weight once as an all-gather where the step
+    takes it: together the
+    reduce-scatters carry every matrix's bytes once and the all-gathers
+    every parameter's. The norms' gains, 8 KB a gradient, the compiler
+    sums whole, in one all-reduce with the loss, and each chip slices
+    its rows; no other all-reduce carries an array, and no collective
+    a float32 one (no master leaves its chip). Each chip updates a
+    quarter of the rows: the update of a [4096, 14336] leaf writes its
+    float32 streams at [1024, 14336]."""
+    text = as_built((2, 2))
+    found = collectives(text)
     params = jax.eval_shape(
         lambda: tfm.init_transformer(jax.random.PRNGKey(0), cfg))
-    assert sum(array_bytes(found[name][0]) for name in carrying) == sum(
-        2 * int(np.prod(v.shape)) for v in params.values())
+    held = lambda rank: sum(  # noqa: E731
+        2 * math.prod(v.shape) for v in params.values() if rank(v.ndim))
+    carried = lambda kind: [  # noqa: E731
+        (b, groups) for k, b, _, groups in found.values() if k == kind and b]
+    scatters = carried("reduce-scatter")
+    assert scatters and {groups for _, groups in scatters} == {"{{0,1,2,3}}"}
+    assert sum(b for b, _ in scatters) == held(lambda n: n > 1)
+    gathers = carried("all-gather")
+    assert {groups for _, groups in gathers} == {"{{0,1,2,3}}"}
+    assert sum(b for b, _ in gathers) == held(lambda n: n > 0)
+    assert sum(b for b, _ in carried("all-reduce")) == held(lambda n: n == 1)
+    for kind, _, out, _ in found.values():
+        assert not re.search(r"f32\[\d", out), (kind, out)
+    d, ff = cfg.d_model, cfg.d_ff
+    written = [out for out, _, _ in fusions(text)]
+    assert any(f"f32[{d // 4},{ff}]" in out for out in written)
+    assert not any(f"f32[{d},{ff}]" in out for out in written)
     # and nothing scales a gradient on its way (the old x 0.5 of a mean)
-    assert "broadcast_multiply_fusion" not in "".join(found)
+    assert "broadcast_multiply_fusion" not in "".join(entry_instructions(text))
 
 
 def test_one_chip_step_holds_no_all_reduce(chip_policy, as_built):
     """On a (1, 1) mesh the sums of the loss's mean compile to nothing,
-    and no other collective stands in the step."""
+    and no other collective stands in the step: the state stays whole,
+    so nothing is reduce-scattered or gathered either."""
     text = as_built((1, 1))
     assert text.startswith("HloModule jit_lm_train_step")
-    assert "all-reduce" not in text
+    for collective in ("all-reduce", "reduce-scatter", "all-gather"):
+        assert collective not in text
 
 
 # --------------------------------------------------------------------------

@@ -759,8 +759,13 @@ def _eqns_named(jaxpr, name):
 @pytest.mark.parametrize("shape,accum", BARRIER_CASES)
 def test_every_gradient_leaf_meets_one_barrier(shape, accum):
     """One `optimization_barrier` a gradient leaf, inside the shard_map,
-    behind the whole of `lm.loss` (behind the scan where microbatches
-    accumulate) and ahead of `lm.opt`; none anywhere else."""
+    on what the exchange returns (on a mesh of four, this device's
+    quarter of the rows), behind the whole of `lm.loss` (behind the scan
+    where microbatches accumulate) and ahead of `lm.opt`; none anywhere
+    else."""
+    from jax.sharding import PartitionSpec as P
+
+    from lua_mapreduce_tpu.parallel.mesh import rows_spec
     cfg, mesh, opt, params, state, batch = _barrier_setup(shape)
     step = tfm.make_train_step(cfg, mesh, opt, grad_accum=accum)
     (_, program), = _eqns_named(
@@ -778,7 +783,9 @@ def test_every_gradient_leaf_meets_one_barrier(shape, accum):
     assert all(len(e.invars) == 1 for _, e in barriers)
     shapes = sorted((e.invars[0].aval.shape, str(e.invars[0].aval.dtype))
                     for _, e in barriers)
-    assert shapes == sorted((v.shape, str(v.dtype))
+    rows = lambda s: s if rows_spec(mesh, s) == P() else (  # noqa: E731
+        s[0] // mesh.size, *s[1:])
+    assert shapes == sorted((rows(v.shape), str(v.dtype))
                             for v in params.values())
     loss_at = [i for i, e in enumerate(body.eqns)
                if "lm.loss" in str(e.source_info.name_stack)
@@ -821,17 +828,25 @@ def _stamped_accum(loss_of, p, arrays, accum, axes):
         lambda g, x: (g / accum).astype(x.dtype), g_s, p)
 
 
-def _by_hand_step(cfg, mesh, opt, accum, stamped=False):
-    """`make_train_step`'s replicated path without the barrier: the
-    optimizer applied by hand to `value_and_grad`'s result. Returns the
-    gradients too. `stamped` builds it the old way (see `_stamped`)."""
+def _by_hand_step(cfg, mesh, opt, accum, stamped=False, scatter=None):
+    """`make_train_step` without the barrier: the optimizer applied by
+    hand to `value_and_grad`'s result. Returns the gradients too.
+    `scatter` (by default what the package does: a dense model on more
+    than one device) reduce-scatters the gradients by rows and updates
+    each device's rows, else the sum is the transpose of the loss's mean
+    and the update replicated. `stamped` builds the replicated step the
+    old way (see `_stamped`)."""
     import functools
 
     from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
+    from lua_mapreduce_tpu.parallel.mesh import rows_layout, rows_spec
     from lua_mapreduce_tpu.train.accum import accum_value_and_grad
     axes, n_sp = ("dp", "sp"), mesh.shape["sp"]
+    if scatter is None:
+        scatter = not stamped and tfm._splits_rows(
+            mesh, bool(cfg.moe_experts))
     attn = tfm._attn_shard_fn("ring", "sp", n_sp, cfg)
     suffix = tfm.param_specs_moe("dp") if cfg.moe_experts else {}
     block = functools.partial(
@@ -841,12 +856,21 @@ def _by_hand_step(cfg, mesh, opt, accum, stamped=False):
         return tuple(a for a in axes
                      if a not in tuple(tfm._spec_for(k, suffix)))
 
+    def exchange(g):
+        if rows_spec(mesh, g.shape) == P():
+            return lax.psum(g, axes)
+        return lax.psum_scatter(g, axes, scatter_dimension=0, tiled=True)
+
     def shard(p, tok, tgt):
         pos = tfm._shard_pos("ring", "sp", n_sp, tok.shape[1])
+        if scatter:
+            p = lax.pcast(p, axes, to="varying")
 
         def loss_of(p, tok, tgt):
             local = tfm.lm_loss_local(p, tok, tgt, cfg, attn, pos,
                                       block=block)
+            if scatter:
+                return lax.psum(local / mesh.size, axes)
             return lax.pmean(lax.pmean(local, "sp"), "dp")
 
         if accum == 1:
@@ -860,16 +884,24 @@ def _by_hand_step(cfg, mesh, opt, accum, stamped=False):
         if stamped:
             grads = {k: _stamped(g, unsharded(k))
                      for k, g in grads.items()}
+        if scatter:
+            grads = {k: exchange(g) for k, g in grads.items()}
         return loss, grads
 
     @jax.jit
     def by_hand(p, s, tok, tgt):
         specs = {k: tfm._spec_for(k, suffix) for k in p}
+        grad_specs = {k: rows_spec(mesh, v.shape)
+                      for k, v in p.items()} if scatter else specs
         loss, grads = shard_map(
             shard, mesh=mesh, in_specs=(specs, P(*axes), P(*axes)),
-            out_specs=(P(), specs))(p, tok, tgt)
+            out_specs=(P(), grad_specs))(p, tok, tgt)
         updates, s = opt.update(grads, s, p)
-        return optax.apply_updates(p, updates), s, loss, grads
+        p = optax.apply_updates(p, updates)
+        if scatter:
+            p = lax.with_sharding_constraint(p, rows_layout(mesh, p))
+            s = lax.with_sharding_constraint(s, rows_layout(mesh, s))
+        return p, s, loss, grads
 
     return by_hand
 
@@ -902,7 +934,7 @@ def test_the_barrier_changes_no_bit_of_a_step(shape, accum):
 
     One case leaves one leaf out since PR 29: on the (1, 1) mesh at
     `grad_accum=1` nothing at all stands between the by-hand step's
-    gradients and its update (the old stamp did, and an all-reduce or
+    gradients and its update (the old stamp did, and the exchange or
     the scan does in the other cases), and the CPU's compiler adds the
     tied embedding's two gradients, the head's and the lookup's, into
     the float32 update without rounding their sum to bfloat16 first. Its
@@ -983,19 +1015,38 @@ def _sums(fn, *args):
     return mapped.params["check_vma"], found
 
 
+def _scatters(fn, *args):
+    """{axes: count} of the reduce-scatters in `fn`'s one shard_map."""
+    import collections
+    (_, program), = _eqns_named(jax.make_jaxpr(fn)(*args).jaxpr, "jit")
+    (_, mapped), = _eqns_named(program.params["jaxpr"].jaxpr, "shard_map")
+    return collections.Counter(
+        tuple(e.params["axis_name"])
+        for e in _eqns_within(mapped.params["jaxpr"])
+        if e.primitive.name == "reduce_scatter")
+
+
 @pytest.mark.parametrize("shape,accum,make_cfg", STAMP_CASES)
 def test_the_step_traces_checked_and_with_no_mean_of_a_gradient(
         shape, accum, make_cfg):
-    """The package's shard_map is traced with the vma check ON and
-    `out_specs` as they were, and it holds the stamped step's sums less
-    the stamps: one `pmean` a data axis that a leaf is not sharded over,
-    three times where microbatches accumulate (the carry's start, every
-    microbatch, the result), and there twice on the loss as well."""
+    """The package's shard_map is traced with the vma check ON. A dense
+    model on a mesh sums no gradient whole and means nothing: each leaf
+    is reduce-scattered once, over both axes at once, and the loss (this
+    device's share of the mean) is summed once. An expert model's holds
+    the stamped step's sums less the stamps, `out_specs` as they were:
+    one `pmean` a data axis that a leaf is not sharded over, three times
+    where microbatches accumulate (the carry's start, every microbatch,
+    the result), and there twice on the loss as well."""
     cfg, mesh, opt, old, step, args = _stamp_setup(shape, accum, make_cfg)
     checked, sums = _sums(step, *args)
     assert checked is True
     old_checked, old_sums = _sums(old, *args)
     assert old_checked is True
+    if not cfg.moe_experts:
+        assert sums == {(("dp", "sp"), False): 1}
+        assert _scatters(step, *args) == {("dp", "sp"): len(args[0])}
+        return
+    assert not _scatters(step, *args)
     suffix = tfm.param_specs_moe("dp") if cfg.moe_experts else {}
     over = lambda a: sum(  # noqa: E731
         a not in tuple(tfm._spec_for(k, suffix)) for k in args[0])
@@ -1008,11 +1059,6 @@ def test_the_step_traces_checked_and_with_no_mean_of_a_gradient(
                    for a in ("dp", "sp") if on_loss})
     assert dict(old_sums - sums) == stamps
     assert not sums - old_sums
-    if not cfg.moe_experts:
-        # what is left is the transpose of the loss's mean: every
-        # gradient summed once, over both axes at once
-        arrays = {axes for (axes, is_array) in sums if is_array}
-        assert arrays == {("dp", "sp")}
 
 
 @pytest.mark.parametrize("shape,accum,make_cfg", STAMP_CASES)
@@ -1020,7 +1066,10 @@ def test_the_step_without_the_stamp_is_the_stamped_one(
         shape, accum, make_cfg):
     """One step's gradients, parameters, optimizer state and loss against
     those of the step built the old way, a `pmean` over each data axis on
-    every gradient leaf: bit for bit at `grad_accum=1`.
+    every gradient leaf: bit for bit at `grad_accum=1`, the replicated
+    step by hand as the new way; and the package's step bit for bit
+    against its own form by hand (for a dense model, the reduce-scatter:
+    `tests/test_row_split.py` holds it to the one-device step).
 
     With microbatches the accumulated GRADIENTS are compared, to one
     bfloat16 ulp of the leaf's largest element. The old stamp's
@@ -1036,13 +1085,14 @@ def test_the_step_without_the_stamp_is_the_stamped_one(
     stops at the gradients there. Compiled to the types, every bit of
     the whole step agrees."""
     cfg, mesh, opt, old, step, args = _stamp_setup(shape, accum, make_cfg)
-    new = _by_hand_step(cfg, mesh, opt, accum)
+    new = _by_hand_step(cfg, mesh, opt, accum, scatter=False)
     want = old(*args)
     got = new(*args)
     if accum == 1:
         _assert_same_bits(got, want)
         _assert_same_bits(step(*jax.tree.map(jnp.copy, args[:2]),
-                               *args[2:]), want[:3])
+                               *args[2:]),
+                          _by_hand_step(cfg, mesh, opt, accum)(*args)[:3])
     else:
         for k, w in want[3].items():
             w = np.asarray(w.astype(jnp.float32))
