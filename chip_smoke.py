@@ -357,6 +357,20 @@ def _kernel_cases(*, lm, train, decode, pool_shape) -> dict:
         return lambda backend: (moe_held(x, combine, load, wg, wu, wd,
                                          backend=backend),)
 
+    def delta_step():
+        # a decode step of Kimi Delta Attention's rule (ops/kda.py): every
+        # session's float32 (heads, 128, 128) state decayed and corrected
+        from lua_mapreduce_tpu.ops.kda import kda_step
+        b, f32 = decode["batch"], jnp.float32
+        q, k = (x / jnp.linalg.norm(x, axis=-1, keepdims=True) for x in (
+            normal(i, (b, h, 128), f32) for i in (16, 17)))
+        v = normal(18, (b, h, 128), f32)
+        g = -jnp.exp(normal(19, (b, h, 128), f32))
+        beta = jax.nn.sigmoid(normal(20, (b, h), f32))
+        state = normal(21, (b, h, 128, 128), f32)
+        return lambda backend: kda_step(state, q, k, v, g, beta,
+                                        backend=backend)
+
     def q8(m, kdim, n):
         x = normal(6, (m, kdim))
         w, s = ops.quantize_q8(
@@ -390,6 +404,10 @@ def _kernel_cases(*, lm, train, decode, pool_shape) -> dict:
         "moe_held": [
             (f"{db} tokens, 4 of 8 experts {d}x{lm['d_ff'] // 4}",
              held_experts(8, 4)),
+        ],
+        "kda_step": [
+            (f"{db} sessions x {h} heads, float32 state 128 x 128",
+             delta_step()),
         ],
         "q8_matmul": [
             (f"decode qkv {db}x{d}x{qkv}", q8(db, d, qkv)),

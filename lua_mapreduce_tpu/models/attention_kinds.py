@@ -4,7 +4,9 @@ cache's format and the attention over it. :class:`GroupedQuery` and
 :class:`Latent` are attention over caches of their own;
 :class:`StateSpace`, :class:`Cross` and :class:`GatedMemory` are the
 further layers of a hybrid stack (a recurrent state; attention over
-ANOTHER layer's cache; a gate on another layer's activation).
+ANOTHER layer's cache; a gate on another layer's activation);
+:class:`KimiDelta` is linear attention with a recurrent state, beside
+latent layers in a stack with an attention pattern.
 ``models/transformer.attention_kind(cfg, i)`` says which one layer ``i``
 has, and nothing there knows more of a kind than the methods below.
 ``cfg`` is the model's ``TransformerConfig``; ``p`` is the layer's
@@ -52,6 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from lua_mapreduce_tpu.ops import kda as _kda
 from lua_mapreduce_tpu.ops import sparse_mla as _sparse
 from lua_mapreduce_tpu.ops import ssm as _ssm
 from lua_mapreduce_tpu.ops.decode import (cache_attention, decode_attention,
@@ -578,7 +581,9 @@ class Latent(_Kind):
     selects (``ops/sparse_mla.py``); without one the cache has the one
     leaf and a query reads every row up to its own
     (``ops/mla_decode.py``). ``qk_norm`` is an RMSNorm of every head's
-    query before the rope. The scan carries the caches as prefill hands
+    query before the rope. Without the config's ``rope`` (positions
+    "none") the ``rope_dim`` part of the queries and of the shared key
+    is carried unrotated. The scan carries the caches as prefill hands
     them out, in the model's own type: no latent cache has an int8 form
     (``kv_q8`` is refused), with or without the indexer."""
     one_form = True
@@ -616,8 +621,11 @@ class Latent(_Kind):
 
     def _rope_head(self, x, pos):
         """Rope (YaRN frequencies) on the first ``rope_dim`` values of
-        (B, L, H*, D) and none on the rest."""
+        (B, L, H*, D) and none on the rest (none at all without the
+        config's ``rope``)."""
         la, base = self.cfg.latent, self.cfg.rope_base
+        if not self.cfg.rope:
+            return x
         turned = _rope(x[..., :la.rope_dim], pos, base,
                        _yarn_freqs(la, base))
         return jnp.concatenate([turned, x[..., la.rope_dim:]], axis=-1)
@@ -669,7 +677,9 @@ class Latent(_Kind):
             q = q.reshape(b, q_len, h, la.nope_dim + la.rope_dim)
             if la.qk_norm:
                 q = _rms_norm(q, params[f"{p}_q_g"], cfg.norm_eps)
-            q_rope = _rope(q[..., la.nope_dim:], pos, cfg.rope_base, freqs)
+            q_rope = q[..., la.nope_dim:]
+            if cfg.rope:
+                q_rope = _rope(q_rope, pos, cfg.rope_base, freqs)
             q_lat = jnp.einsum("bqhn,chn->bqhc", q[..., :la.nope_dim],
                                w_kv[..., :la.nope_dim])
             q = jnp.concatenate([q_lat, q_rope], axis=-1)
@@ -863,6 +873,136 @@ class StateSpace(_Kind):
         cn, sn = self.names(p)
         return {cn: jnp.zeros((b, taps - 1, e), dtype),
                 sn: jnp.zeros((b, n, e), _ssm.STATE_DTYPE)}
+
+    def padded(self, p: str, rows, total: int, dtype) -> Params:
+        return dict(zip(self.names(p), rows))
+
+    def scanned(self, p: str, caches: Params, p_len: int, total: int,
+                kv_q8: bool = False) -> Params:
+        _no_int8(kv_q8)
+        return _with_snapshot({name: caches[name] for name in self.names(p)},
+                              p)
+
+    def turn_start(self, p: str, caches: Params, start) -> Params:
+        return _taken_back(self.names(p), p, caches, start)
+
+
+def _l2_heads(x, eps: float = 1e-6):
+    """Every head's vector (last axis) over its L2 norm, float32."""
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + eps)
+
+
+class KimiDelta(_Kind):
+    """Kimi Delta Attention (Kimi Linear, arXiv:2510.26692), the linear
+    layers of a stack whose ``attn_pattern`` says ``"kda"``;
+    ``cfg.linear`` has the sizes (H heads of D, P = H D, the
+    convolution's taps; the gates' low rank is D). With ``y`` the layer's
+    normed input:
+
+        q, k, v = silu(conv1d(y W_qkv))             (P each, depthwise, causal)
+        q = l2norm(q) D^-1/2, k = l2norm(k)         (per head)
+        g = -exp(A_log) softplus(y W_fa W_fb + dt_b)  (per key channel)
+        beta = sigmoid(y W_b)                       (per head)
+        S_t = (I - beta k k^T) diag(exp g) S_{t-1} + beta k v^T,  o = S_t^T q
+        out = (RMSNorm_D(o) o_g * sigmoid(y W_ga W_gb)) W_out
+
+    ``ops/kda.py`` runs the rule: chunked over a sequence, a Pallas step
+    at decode. What a session caches has no axis of positions: the
+    convolution's tail ``conv`` (B, taps - 1, 3 P), the last projected
+    inputs in the model's type, and the state ``state`` (B, H, D, D) in
+    float32, with ONE snapshot in the scan (:func:`_with_snapshot`).
+    Everything the layer does runs under ``lm.kda``."""
+    leaves = ("conv", "state")
+    one_form = True
+
+    def _sizes(self) -> tuple:
+        la = self.cfg.linear
+        return la.heads, la.head_dim, la.conv
+
+    def init(self, keys, dtype, p: str) -> Params:
+        d = self.cfg.d_model
+        h, dk, taps = self._sizes()
+        width = h * dk
+        k = iter(jax.random.split(next(keys), 9))
+        f32 = jnp.float32
+        # the published initialisation: A ~ U(1, 16) a head, the decay's
+        # bias the inverse softplus of a log-uniform draw in [1e-3, 0.1]
+        dt = jnp.exp(jax.random.uniform(next(k), (width,), f32,
+                                        np.log(1e-3), np.log(1e-1)))
+        return {
+            f"{p}_qkv_W": _dense(next(k), (d, 3 * width), dtype),
+            f"{p}_conv_W": _dense(next(k), (taps, 3 * width), dtype),
+            f"{p}_fa_W": _dense(next(k), (d, dk), dtype),
+            f"{p}_fb_W": _dense(next(k), (dk, width), dtype),
+            f"{p}_dt_b": (dt + jnp.log(-jnp.expm1(-dt))).astype(f32),
+            f"{p}_A_log": jnp.log(jax.random.uniform(next(k), (h,), f32,
+                                                     1.0, 16.0)),
+            f"{p}_b_W": _dense(next(k), (d, h), dtype),
+            f"{p}_ga_W": _dense(next(k), (d, dk), dtype),
+            f"{p}_gb_W": _dense(next(k), (dk, width), dtype),
+            f"{p}_o_g": jnp.ones((dk,), dtype),
+            f"{p}_out_W": _dense(next(keys), (width, d), dtype)}
+
+    def _low_rank(self, params: Params, p: str, name: str, y):
+        """``y W_{name}a W_{name}b`` in float32."""
+        return jnp.dot(_mm(params, f"{p}_{name}a_W", y),
+                       params[f"{p}_{name}b_W"],
+                       preferred_element_type=jnp.float32)
+
+    def _mix(self, params: Params, p: str, y, tail, state):
+        """The mixer over ``y`` (B, L, d) from the tail and state before
+        it. Returns (out (B, L, d), the tail and the state after)."""
+        h, dk, _ = self._sizes()
+        b, l, _ = y.shape
+        f32 = jnp.float32
+        with scope("lm.kda"):
+            qkv = _mm(params, f"{p}_qkv_W", y)
+            c, tail = _ssm.causal_conv(qkv, tail, params[f"{p}_conv_W"],
+                                       jnp.zeros((), f32))
+            q, k, v = (x.reshape(b, l, h, dk) for x in
+                       jnp.split(jax.nn.silu(c), 3, axis=-1))
+            q, k = _l2_heads(q) * dk ** -0.5, _l2_heads(k)
+            g = (-jnp.exp(params[f"{p}_A_log"].astype(f32))[:, None]
+                 * jax.nn.softplus(self._low_rank(params, p, "f", y)
+                                   + params[f"{p}_dt_b"]).reshape(
+                                       b, l, h, dk))
+            beta = jax.nn.sigmoid(_mm(params, f"{p}_b_W", y).astype(f32))
+            if l == 1:      # a decode step: the kernel
+                state, o = _kda.kda_step(state, q[:, 0], k[:, 0], v[:, 0],
+                                         g[:, 0], beta[:, 0])
+                o = o[:, None]
+            else:
+                o, state = _kda.kda_chunked(q, k, v, g, beta, state)
+            gate = jax.nn.sigmoid(self._low_rank(params, p, "g", y))
+            o = (_rms_norm(o, params[f"{p}_o_g"].astype(f32),
+                           self.cfg.norm_eps).reshape(b, l, h * dk) * gate)
+            return (_mm(params, f"{p}_out_W", o.astype(y.dtype)),
+                    tail.astype(y.dtype), state)
+
+    def full(self, params: Params, p: str, y, pos, attn_fn):
+        fresh = self.empty(p, y.shape[0], 0, y.dtype)
+        out, tail, state = self._mix(params, p, y, *fresh.values())
+        return out, (tail, state)
+
+    def chunk(self, params: Params, p: str, y, pos, caches: Params, start):
+        cn, sn = self.names(p)
+        out, tail, state = self._mix(params, p, y, caches[cn], caches[sn])
+        return out, {**caches, cn: tail, sn: state}
+
+    def step(self, params: Params, p: str, y, t, caches: Params,
+             kv_q8: bool):
+        """(``kv_q8`` was refused where the caches were made.)"""
+        out, caches = self.chunk(params, p, y, t[None], caches, t)
+        return out, (caches, None)
+
+    def empty(self, p: str, b: int, total: int, dtype,
+              kv_q8: bool = False) -> Params:
+        _no_int8(kv_q8)
+        h, dk, taps = self._sizes()
+        cn, sn = self.names(p)
+        return {cn: jnp.zeros((b, taps - 1, 3 * h * dk), dtype),
+                sn: jnp.zeros((b, h, dk, dk), _kda.STATE_DTYPE)}
 
     def padded(self, p: str, rows, total: int, dtype) -> Params:
         return dict(zip(self.names(p), rows))

@@ -37,8 +37,8 @@ from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from lua_mapreduce_tpu.models.attention_kinds import (
-    Cross, GatedMemory, GroupedQuery, Latent, Params, StateSpace, _dense,
-    _mm, _norm, _rope, head_dim, kv_heads)
+    Cross, GatedMemory, GroupedQuery, KimiDelta, Latent, Params, StateSpace,
+    _dense, _mm, _norm, _rope, head_dim, kv_heads)
 from lua_mapreduce_tpu.ops.attention import flash_attention
 from lua_mapreduce_tpu.ops.q8 import quantize_q8
 from lua_mapreduce_tpu.parallel import moe as _moe
@@ -72,7 +72,9 @@ class LatentAttention:
     ``q_rank``); 0: no indexer, a query reads every cached row up to
     its own. ``qk_norm``: an RMSNorm with a gain (``q_g``) of every
     head's query before the rope; the key side's norm is the latent's
-    own (``kv_g``), which every such model has."""
+    own (``kv_g``), which every such model has. The ``rope_dim`` part is
+    rotated where the config has ``rope``, and carried unrotated where
+    it has no positions (Kimi Linear's ``mla_use_nope``)."""
     q_rank: int
     kv_rank: int
     nope_dim: int
@@ -89,6 +91,18 @@ class LatentAttention:
     beta_slow: float = 1.0
     mscale_all_dim: float = 0.0
     qk_norm: bool = False
+
+
+@dataclasses.dataclass(frozen=True)
+class LinearAttention:
+    """The sizes of Kimi Delta Attention (``models/attention_kinds.
+    KimiDelta``), the layers an ``attn_pattern`` names ``"kda"``:
+    ``heads`` of ``head_dim`` (keys and values alike; the decay's and
+    the output gate's low rank is ``head_dim`` too) and the short
+    convolution's ``conv`` taps."""
+    heads: int
+    head_dim: int
+    conv: int = 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -183,6 +197,8 @@ class TransformerConfig:
     # None = grouped-query attention over (k, v) caches; else latent
     # attention over a latent cache, sparse where it has an indexer
     latent: Optional[LatentAttention] = None
+    # the sizes of the "kda" layers of an attention pattern (None: none)
+    linear: Optional[LinearAttention] = None
     # None = every layer has the one attention above; else the mixer is
     # chosen by layer
     hybrid: Optional[HybridStack] = None
@@ -194,13 +210,15 @@ class TransformerConfig:
     # (rolling O(window) cache), prefill, pipeline, and the BANDED
     # contiguous ring (attn="ring") speak it; zigzag/ulysses reject.
     window: int = 0
-    # () = every layer's attention as ``window`` says. Else ONE period of
-    # the layers' grouped-query attention, layer i having
-    # ``attn_pattern[i % len]``: "swa" (over the last ``window``
-    # positions, rope where ``rope``) or "full_nope" (every position,
-    # no positional encoding): Cohere2's three window layers to one
-    # global NoPE layer. Served only (prefill, decode_from,
-    # greedy_decode, transformer_apply)
+    # () = every layer's attention as ``window`` (or ``latent``) says.
+    # Else the layers' mixers, ONE period of them (or every layer's),
+    # layer i having ``attn_pattern[i % len]``: "swa" (grouped-query
+    # over the last ``window`` positions, rope where ``rope``) or
+    # "full_nope" (every position, no positional encoding): Cohere2's
+    # three window layers to one global NoPE layer; "latent" (``latent``
+    # attention) or "kda" (Kimi Delta Attention of ``linear``'s sizes):
+    # Kimi Linear's three linear layers to one latent one. Served only
+    # (prefill, decode_from, greedy_decode, transformer_apply)
     attn_pattern: Tuple[str, ...] = ()
     # the parallel block: x + attn(norm(x)) + ffn(norm(x)) from ONE norm
     # a layer (GPT-J, Cohere), where the default is sequential
@@ -301,12 +319,17 @@ def attention_kind(cfg: TransformerConfig, i: int):
     its weights, what a position caches, the cache's format and the
     attention over it (models/attention_kinds.py). The one place that
     reads the configuration for it."""
-    if cfg.latent is not None:
-        return Latent(cfg)
     if cfg.attn_pattern:
-        full = cfg.attn_pattern[i % len(cfg.attn_pattern)] == "full_nope"
+        kind = cfg.attn_pattern[i % len(cfg.attn_pattern)]
+        if kind == "kda":
+            return KimiDelta(cfg)
+        if kind == "latent":
+            return Latent(cfg)
+        full = kind == "full_nope"
         return GroupedQuery(cfg, window=0 if full else cfg.window,
                             nope=full)
+    if cfg.latent is not None:
+        return Latent(cfg)
     hy = cfg.hybrid
     if hy is None:
         return GroupedQuery(cfg, window=cfg.window)
@@ -361,10 +384,12 @@ def _check_arch(cfg: TransformerConfig) -> None:
                          "(moe_router='grouped')")
     if cfg.attn_pattern:
         _check_pattern(cfg)
+    elif cfg.linear is not None:
+        raise ValueError("linear sizes without an attn_pattern that names "
+                         "'kda' layers: no layer has them")
     if cfg.latent is not None:
-        if not (cfg.rope and cfg.norm == "rms"):
-            raise ValueError("latent attention is written for rope=True "
-                             "and norm='rms'")
+        if cfg.norm != "rms":
+            raise ValueError("latent attention is written for norm='rms'")
         if cfg.latent.rope_dim % 2 or cfg.window:
             raise ValueError("latent attention needs an even rope_dim and "
                              "takes no window")
@@ -396,14 +421,26 @@ def _check_arch(cfg: TransformerConfig) -> None:
 
 def _check_pattern(cfg: TransformerConfig) -> None:
     """What an attention pattern is written for: grouped-query layers of
-    the two kinds, a window for the "swa" ones, no other stack."""
-    known = ("swa", "full_nope")
-    if set(cfg.attn_pattern) - set(known):
+    the two kinds, a window for the "swa" ones; or latent layers; either
+    beside "kda" layers with ``linear``'s sizes; no other stack."""
+    known = ("swa", "full_nope", "latent", "kda")
+    kinds = set(cfg.attn_pattern)
+    if kinds - set(known):
         raise ValueError(f"attn_pattern names each layer of a period as one "
                          f"of {known}; got {cfg.attn_pattern}")
-    if cfg.latent is not None or cfg.hybrid is not None:
-        raise ValueError("attn_pattern is a period of grouped-query layers; "
-                         "latent attention and a hybrid stack take none")
+    if cfg.hybrid is not None:
+        raise ValueError("attn_pattern is a period of grouped-query, latent "
+                         "or 'kda' layers; a hybrid stack takes none")
+    if ("latent" in kinds) != (cfg.latent is not None) or (
+            cfg.latent is not None and kinds & {"swa", "full_nope"}):
+        raise ValueError("attn_pattern's 'latent' layers are latent's, and "
+                         "a stack with latent attention has no grouped-query "
+                         f"layers; got {cfg.attn_pattern}, latent "
+                         f"{cfg.latent is not None}")
+    if ("kda" in kinds) != (cfg.linear is not None):
+        raise ValueError("attn_pattern's 'kda' layers take linear's sizes, "
+                         f"and linear has no other use; got "
+                         f"{cfg.attn_pattern}, linear {cfg.linear}")
     if "swa" in cfg.attn_pattern and cfg.window < 1:
         raise ValueError("attn_pattern's 'swa' layers need window >= 1")
 
@@ -446,6 +483,7 @@ def _check_sharded(cfg: TransformerConfig) -> None:
     them) are sequential."""
     served = [what for what, has in (
         ("latent attention", cfg.latent is not None),
+        ("the KDA kind (Kimi Delta Attention)", "kda" in cfg.attn_pattern),
         ("a hybrid stack", cfg.hybrid is not None),
         ("the grouped expert layer",
          cfg.moe_experts and cfg.moe_router == "grouped"),

@@ -50,6 +50,10 @@ _TPU_AUTO_POLICY = {
     # streams the touched experts' weights back to back; XLA's
     # composition is a conditional an expert, each a cold stream
     "moe_held": "pallas",
+    # a KDA decode step (ops/kda.py): each (session, head) state read
+    # once and written once in place; XLA's composition reads it for the
+    # key's projection and again for the query's
+    "kda_step": "pallas",
 }
 
 

@@ -58,15 +58,19 @@ LM_SCOPES = ("lm.loss", "lm.embed", "lm.attn", "lm.ffn", "lm.head",
              # differential attention over a window, over the whole
              # cache, and over another layer's cache
              "lm.ssm", "lm.gmu", "lm.swa", "lm.full", "lm.cross",
+             # inside lm.attn, Kimi Delta Attention whole: projections,
+             # convolution, gates, the delta rule, norm, out projection
+             "lm.kda",
              # inside lm.ffn, the grouped expert layer
              "lm.moe.route", "lm.moe.experts", "lm.moe.shared")
 LM_HOST_SPANS = ("lm.shard_batch",)
 # ``name=`` of the pallas_calls (ops/attention.py, ops/decode.py,
-# ops/mla_decode.py, ops/moe_held.py, ops/q8.py): the custom call's HLO
-# result is ``%<name>.<n>`` whatever scope or transformation encloses it.
+# ops/mla_decode.py, ops/moe_held.py, ops/q8.py, ops/kda.py): the custom
+# call's HLO result is ``%<name>.<n>`` whatever scope or transformation
+# encloses it.
 LM_KERNELS = ("flash_pallas", "flash_bwd_pallas_dq", "flash_bwd_pallas_dkv",
               "_decode_pallas", "q8_matmul_pallas", "_mla_decode_pallas",
-              "_moe_held_pallas")
+              "_moe_held_pallas", "_kda_step_pallas")
 # the jitted functions, so the trace's programs are ``jit_<name>``
 LM_PROGRAMS = ("lm_train_step", "greedy_decode", "decode_from")
 

@@ -291,7 +291,7 @@ def test_the_decode_kernel_at_sixteen_query_rows_is_its_composition(t):
      "grouped expert layer's"),
     (dict(moe_experts=0, moe_held=None), "grouped expert layer's"),
     (dict(latent=tfm.LatentAttention(0, 64, 32, 32, 32)),
-     "latent attention and a hybrid"),
+     "a stack with latent attention has no grouped-query layers"),
 ])
 def test_check_arch_names_what_is_wrong(change, match):
     with pytest.raises(ValueError, match=match):

@@ -15,6 +15,7 @@ from jax.sharding import Mesh
 
 from lua_mapreduce_tpu import ops
 from lua_mapreduce_tpu.models import transformer as tfm
+from lua_mapreduce_tpu.ops import kda as kda_ops
 from lua_mapreduce_tpu.parallel import ring_attention
 from lua_mapreduce_tpu.utils import profiling
 
@@ -55,6 +56,18 @@ HYBRID_CFG = tfm.TransformerConfig(
     hybrid=tfm.HybridStack.sambay(8, 4, ssm_state=4, ssm_rank=3))
 HYBRID_SCOPES = {name: "lm.attn" for name in (
     "lm.ssm", "lm.gmu", "lm.swa", "lm.full", "lm.cross")}
+# Kimi Delta Attention beside latent layers with no positions: the whole
+# mixer under lm.kda, inside lm.attn
+KDA_CFG = tfm.TransformerConfig(
+    vocab=64, d_model=32, n_heads=4, n_layers=3, d_ff=48, max_seq=64,
+    norm="rms", ffn="swiglu", positions="none", tied_head=False,
+    latent=tfm.LatentAttention(q_rank=0, kv_rank=16, nope_dim=8,
+                               rope_dim=4, v_dim=8),
+    linear=tfm.LinearAttention(heads=2, head_dim=8),
+    attn_pattern=("kda", "kda", "latent"), moe_experts=16,
+    moe_router="grouped", moe_top_k=4, moe_scale=2.446, moe_d_ff=16,
+    moe_shared=1, moe_held=(4, 4), moe_first_dense=1)
+KDA_SCOPES = {"lm.kda": "lm.attn"}
 
 
 def train_setup(shape, make=tfm.make_train_step, **kw):
@@ -278,15 +291,16 @@ def test_the_session_entry_runs_no_prefill(lowered_session):
 
 def test_every_scope_of_the_contract_is_used(lowered_train, lowered_decode,
                                              lowered_session, lowered_whole,
-                                             lowered_hybrid):
+                                             lowered_hybrid, lowered_kda):
     found = (scope_paths(lowered_train[2, 2]) | scope_paths(lowered_decode)
              | scope_paths(lowered_session) | scope_paths(lowered_whole)
-             | scope_paths(lowered_hybrid[0]))
+             | scope_paths(lowered_hybrid[0]) | scope_paths(lowered_kda[0]))
     for name in profiling.LM_SCOPES:
         assert any(name in p for p in found), name
     assert set(TRAIN_SCOPES + DECODE_SCOPES + ("lm.ring",)
                + tuple(SESSION_SCOPES) + tuple(WHOLE_SCOPES)
-               + tuple(HYBRID_SCOPES)) == set(profiling.LM_SCOPES)
+               + tuple(HYBRID_SCOPES)
+               + tuple(KDA_SCOPES)) == set(profiling.LM_SCOPES)
 
 
 def test_the_indexed_layers_add_no_kernel():
@@ -295,7 +309,8 @@ def test_the_indexed_layers_add_no_kernel():
     model holds no pallas_call here. The sixth pinned name is the kernel
     of latent attention over a whole cache (`ops/mla_decode.py`), the
     seventh the held experts of a decode step as one call on the chip
-    (`ops/moe_held.py`)."""
+    (`ops/moe_held.py`), the eighth a KDA decode step
+    (`ops/kda.py`)."""
     params = tfm.init_transformer(jax.random.PRNGKey(0), LATENT_CFG)
     caches, _ = tfm.prefill(params, jnp.zeros((2, 8), jnp.int32),
                             cfg=LATENT_CFG, total=12)
@@ -303,7 +318,7 @@ def test_the_indexed_layers_add_no_kernel():
         p, c, jnp.zeros((2,), jnp.int32), 8, 4, cfg=LATENT_CFG))(
             params, caches)
     assert pallas_calls(jaxpr.jaxpr) == []
-    assert len(profiling.LM_KERNELS) == 7
+    assert len(profiling.LM_KERNELS) == 8
 
 
 @pytest.fixture(scope="module")
@@ -330,6 +345,63 @@ def lowered_hybrid():
     return (tfm.decode_from.lower(params, caches, jnp.zeros((2,), jnp.int32),
                                   8, 4, cfg=HYBRID_CFG),
             prefill.lower(params, ids))
+
+
+@pytest.fixture(scope="module")
+def lowered_kda():
+    """The session entry of a stack with KDA and latent layers, and its
+    chunked prefill."""
+    params = tfm.init_transformer(jax.random.PRNGKey(0), KDA_CFG)
+    ids = jnp.zeros((2, 8), jnp.int32)
+    prefill = jax.jit(lambda p, i: tfm.prefill(p, i, cfg=KDA_CFG, total=12,
+                                               chunk=4))
+    caches, _ = prefill(params, ids)
+    caches = tfm.decode_caches(caches, cfg=KDA_CFG, p_len=8, total=12)
+    return (tfm.decode_from.lower(params, caches, jnp.zeros((2,), jnp.int32),
+                                  8, 4, cfg=KDA_CFG),
+            prefill.lower(params, ids))
+
+
+@pytest.mark.parametrize("name", sorted(KDA_SCOPES))
+def test_the_kda_mixer_has_a_scope_of_its_own(lowered_kda, name):
+    """`lm.attn/lm.kda`: in the scan of the session entry and in the
+    chunked prefill; the latent layer beside it keeps its own."""
+    session, prefill = lowered_kda
+    nests(session, name, KDA_SCOPES[name])
+    nests(session, "lm.mla", "lm.attn")
+    under = [p for p in scope_paths(prefill) if name in p.split("/")]
+    assert under and all("lm.attn" in p.split("/") for p in under), name
+
+
+def test_the_kda_step_is_the_kernel_inside_its_scope():
+    """A decode step's KDA layers go through `ops/kda.kda_step`: on the
+    chip `_kda_step_pallas`, one call a KDA layer, under `lm.kda`."""
+    params = tfm.init_transformer(jax.random.PRNGKey(0), KDA_CFG)
+    caches, _ = tfm.prefill(params, jnp.zeros((2, 8), jnp.int32),
+                            cfg=KDA_CFG, total=12)
+    caches = tfm.decode_caches(caches, cfg=KDA_CFG, p_len=8, total=12)
+    real = kda_ops.kda_step
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kda_ops, "kda_step",
+                   lambda *a, **kw: real(*a, backend="pallas_interpret"))
+        jaxpr = jax.make_jaxpr(lambda p, c: tfm.decode_from.__wrapped__(
+            p, c, jnp.zeros((2,), jnp.int32), 8, 4, cfg=KDA_CFG))(
+                params, caches)
+    assert pallas_calls(jaxpr.jaxpr) == ["_kda_step_pallas"] * 2
+
+    def enclosing(jaxpr, path=""):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield path
+            for value in eqn.params.values():
+                for sub in (value if isinstance(value, (list, tuple))
+                            else [value]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        yield from enclosing(
+                            sub, f"{path}/{eqn.source_info.name_stack}")
+
+    assert list(enclosing(jaxpr.jaxpr)) == ["/lm.decode/lm.attn/lm.kda"] * 2
 
 
 def pallas_calls(jaxpr, out=None) -> list:
@@ -371,8 +443,15 @@ def kernel_sites():
         return moe_held(x, jnp.ones((8, 2)), jnp.ones((2,), jnp.int32), w, w,
                         w, backend="pallas_interpret")
 
+    def kda_step(s, q, v):
+        return kda_ops.kda_step(s, q, q, v, -q, q[..., 0],
+                                backend="pallas_interpret")
+
     cache = jnp.ones((1, 2, 128, 64), jnp.float32)
     return {
+        "_kda_step_pallas": (kda_step, (jnp.ones((2, 4, 16, 16)),
+                                        jnp.ones((2, 4, 16)),
+                                        jnp.ones((2, 4, 16)))),
         "_moe_held_pallas": (held, (jnp.ones((8, 128)),
                                     jnp.ones((2, 128, 128)))),
         "_mla_decode_pallas": (mla, (jnp.ones((1, 4, 96)),

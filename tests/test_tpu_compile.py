@@ -680,3 +680,61 @@ def test_the_pattern_session_entry_keeps_its_caches_in_place(topo,
             + 2 * 3 * 2 * 16 * 8 * 4096 * 128 * 2)   # windows, snapshots
     assert held <= memory.alias_size_in_bytes < held + 2 ** 16
     assert memory.temp_size_in_bytes < 16 * 2 ** 20
+
+
+# --------------------------------------------------------------------------
+# Kimi Delta Attention beside latent layers: the
+# Kimi-Linear-48B-A3B-Instruct cell's kernel and the first four layers of
+# its turn
+# --------------------------------------------------------------------------
+
+def test_the_kda_step_kernel_writes_the_state_in_place(topo, chip_policy):
+    """At the cell's 32 sessions of 32 heads of 128: Mosaic compiles the
+    kernel, and a donated state is its output (no copy of 64 MiB)."""
+    from jax.sharding import SingleDeviceSharding
+    from lua_mapreduce_tpu.ops import kda
+    one = SingleDeviceSharding(topo.devices[0])
+    arg = lambda shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.float32, sharding=one)
+    step = jax.jit(lambda s, q, k, v, g, b: kda._kda_step_pallas(
+        s, q, k, v, g, b), donate_argnums=0)
+    compiled = step.lower(arg((32, 32, 128, 128)), arg((32, 32, 128)),
+                          arg((32, 32, 128)), arg((32, 32, 128)),
+                          arg((32, 32, 128)), arg((32, 32))).compile()
+    text = compiled.as_text()
+    assert "_kda_step_pallas" in text and "tpu_custom_call" in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == 32 * 32 * 128 * 128 * 4
+    assert memory.temp_size_in_bytes < 2 ** 20
+
+
+def test_the_kda_session_entry_keeps_its_states_in_place(topo, chip_policy):
+    """Layers 1-4 at the cell's size (32 sessions, 4,096 cached positions,
+    64 scanned): three KDA layers and the latent one. A call of the KDA
+    kernel a KDA layer and step, the latent kernel and the grouped
+    expert call; the scan copies no state (the snapshots are copied
+    once, before it), and the donated states, tails, snapshots and
+    latent rows are written in place."""
+    from perfbench.model_kimilinear import program_config
+    import dataclasses
+    cfg = program_config(serve_config(
+        "kimi-linear-48b-a3b-instruct.serve-ep16"))
+    cfg = dataclasses.replace(cfg, n_layers=4,
+                              attn_pattern=cfg.attn_pattern[:4])
+    compiled = compiled_turn(topo, cfg, 32, 4096, 64)
+    text = compiled.as_text()
+    assert len(re.findall(r"%_kda_step_pallas[.\d]* = \(f32\[32,32,128,128\]",
+                          text)) == 3
+    assert "_mla_decode_pallas" in text
+    assert len(held_expert_calls(text, 16, 2304, 1024)) == 3
+    body = re.search(r"^%wide\.[^\n]*\{\n(.*?)^\}", text, re.M | re.S)
+    assert body and "_kda_step_pallas" in body.group(1)
+    assert not re.findall(r"= f32\[32,32,128,128\][^ ]* copy\(",
+                          body.group(1))
+    memory = compiled.memory_analysis()
+    states = 3 * 2 * 32 * (32 * 128 * 128 * 4 + 3 * 12288 * 2)
+    latent = 32 * 4160 * 576 * 2
+    assert states + latent <= memory.alias_size_in_bytes
+    # (the tails' 3 rows a session lie in the chip's tiles of more)
+    assert memory.alias_size_in_bytes < states + latent + 2 ** 23
+    assert memory.temp_size_in_bytes < 64 * 2 ** 20
