@@ -59,7 +59,8 @@ from lua_mapreduce_tpu.ops import sparse_mla as _sparse
 from lua_mapreduce_tpu.ops import ssm as _ssm
 from lua_mapreduce_tpu.ops.decode import (cache_attention, decode_attention,
                                           quantize_kv)
-from lua_mapreduce_tpu.ops.mla_decode import (mla_causal_attention,
+from lua_mapreduce_tpu.ops.mla_decode import (latent_row_put,
+                                              mla_causal_attention,
                                               mla_decode_attention)
 from lua_mapreduce_tpu.ops.q8 import q8_matmul
 from lua_mapreduce_tpu.utils.profiling import scope
@@ -750,8 +751,16 @@ class Latent(_Kind):
 
     def step(self, params: Params, p: str, y, t, caches: Params,
              kv_q8: bool):
-        """(``kv_q8`` was refused where the caches were made.)"""
-        caches, mine = self._written(params, p, y, t[None], caches, t)
+        """(``kv_q8`` was refused where the caches were made.) Without an
+        indexer the flash-decode kernel owns the cache's layout, and the
+        row goes in through its in-place put (``ops/mla_decode``)."""
+        if self.cfg.latent.index_top_k:
+            caches, mine = self._written(params, p, y, t[None], caches, t)
+        else:
+            (name,) = self.names(p)
+            (row,) = self.rows(params, p, y, t[None])
+            caches = {**caches, name: latent_row_put(caches[name], row, t)}
+            mine = [caches[name]]
         out, selected = self.attend(params, p, y, t[None], *mine)
         return out, (caches, selected)
 

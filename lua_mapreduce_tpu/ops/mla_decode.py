@@ -18,7 +18,10 @@ chunks past the scalar-prefetched position ``t`` onto the live range so
 that their copies are elided (``ops/decode.py``'s trick). Only the chunk
 that holds ``t`` is masked; the chunks before it are whole and pay for
 no mask. ``_mla_decode_xla`` is the composition for other platforms and
-the oracle.
+the oracle. :func:`latent_row_put` writes the step's row into the cache
+before the kernel reads it: on the kernel's backend in place, a 128-lane
+tile column a session, where XLA's dynamic-update-slice costs a tile
+write for each of the row's values.
 
 :func:`mla_causal_attention` is the prefill's, in XLA: a block of
 queries against the cache up to the block's last position and no
@@ -220,6 +223,91 @@ def _mla_decode_pallas(q, cache, t, v_rank: int, interpret: bool = False):
         interpret=interpret,
         name="_mla_decode_pallas",
     )(tarr, q, ct)
+
+
+# the chip's tile of positions: the put writes the 128-position column
+# that holds `t`
+_TILE = 128
+# a grid step of `_latent_row_put` carries at most this much of the cache
+# (its tile column of `r` rows), and as much again out. us a put on a
+# v5e, a loop of 10.2 us included, by rows a step
+# (`benchmarks/results/latent_put_bench.json`): (32, 4160, 576) 1 38.3,
+# 2 32.6, 4 28.8, 8 29.0, 16 27.2, 32 30.7 (XLA's dynamic-update-slice
+# 91.5); (16, 32832, 576) 1 22.5, 2 20.6, 4 19.3, 8 18.5, 16 20.1 (50.2)
+_PUT_BYTES = 3 << 19
+
+
+def _put_rows(b: int, width: int, itemsize: int) -> int:
+    """Rows a grid step of ``_latent_row_put`` carries: the largest
+    divisor of ``b`` whose (r, width, 128) tile column stays within
+    ``_PUT_BYTES``."""
+    return max(r for r in range(1, b + 1)
+               if b % r == 0 and (r == 1 or r * width * _TILE * itemsize
+                                  <= _PUT_BYTES))
+
+
+def _latent_row_put_kernel(t_ref, row_ref, c_ref, o_ref):
+    """The tile column that holds ``t``, with lane ``t % 128`` of each
+    row replaced by the new row (r, R, 1) and every other lane kept."""
+    lane = lax.broadcasted_iota(jnp.int32, c_ref.shape, 2)
+    o_ref[...] = jnp.where(lane == t_ref[0] % _TILE, row_ref[...],
+                           c_ref[...])
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "interpret"))
+def _latent_row_put(cache, row, t, rows: int | None = None,
+                    interpret: bool = False):
+    """``lax.dynamic_update_slice(cache, row, (0, t, 0))`` in place, for
+    a cache (B, S, R) that ``_mla_decode_pallas`` reads, row (B, 1, R).
+
+    On the chip such a cache is kept with the positions minor (the
+    kernel's (B, R, S) view is a bitcast of it), so one position is one
+    lane, at an unaligned index, in each of R / 16 tiles a session;
+    XLA's dynamic-update-slice writes them a tile at a time, 2.5 us a
+    session on a v5e. Here a grid step reads the one 128-lane tile
+    column that holds ``t`` for ``rows`` sessions, replaces the lane and
+    writes the column back over the same buffer (aliased in to out).
+    Where that column hangs over the cache's end the positions past S
+    are padding of the chip's tiles, dropped on the way out. ``t`` is
+    clamped into the cache as the dynamic-update-slice clamps it."""
+    b, s_len, width = cache.shape
+    r = rows or _put_rows(b, width, cache.dtype.itemsize)
+    ct = jnp.swapaxes(cache, 1, 2)
+    tarr = jnp.clip(jnp.asarray(t, jnp.int32), 0, s_len - 1).reshape(1)
+    column = pl.BlockSpec((r, width, _TILE),
+                          lambda i, t_ref: (i, 0, t_ref[0] // _TILE),
+                          memory_space=pltpu.VMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b // r,),
+        in_specs=[pl.BlockSpec((r, width, 1), lambda i, t_ref: (i, 0, 0),
+                               memory_space=pltpu.VMEM),
+                  column],
+        out_specs=column,
+    )
+    out = pl.pallas_call(
+        _latent_row_put_kernel,
+        grid_spec=grid_spec,
+        out_shape=out_struct(ct.shape, ct.dtype, ct, row),
+        input_output_aliases={2: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="_latent_row_put",
+    )(tarr, jnp.swapaxes(row, 1, 2), ct)
+    return jnp.swapaxes(out, 1, 2)
+
+
+def latent_row_put(cache, row, t, *, backend: str = "auto"):
+    """``cache`` (B, S, R) with the decode step's row (B, 1, R) written at
+    position ``t``. On the latent flash-decode kernel's backend the
+    in-place put of ``_latent_row_put``; elsewhere (and as its oracle)
+    ``lax.dynamic_update_slice``."""
+    backend = resolve_backend(backend, "mla_decode_attention")
+    if backend == "xla":
+        return lax.dynamic_update_slice(cache, row, (0, t, 0))
+    return _latent_row_put(cache, row, t,
+                           interpret=backend == "pallas_interpret")
 
 
 def _mla_decode_xla(q, cache, t, v_rank: int):

@@ -70,7 +70,7 @@ LM_HOST_SPANS = ("lm.shard_batch",)
 # encloses it.
 LM_KERNELS = ("flash_pallas", "flash_bwd_pallas_dq", "flash_bwd_pallas_dkv",
               "_decode_pallas", "q8_matmul_pallas", "_mla_decode_pallas",
-              "_moe_held_pallas", "_kda_step_pallas")
+              "_moe_held_pallas", "_kda_step_pallas", "_latent_row_put")
 # the jitted functions, so the trace's programs are ``jit_<name>``
 LM_PROGRAMS = ("lm_train_step", "greedy_decode", "decode_from")
 

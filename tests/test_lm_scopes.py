@@ -310,7 +310,8 @@ def test_the_indexed_layers_add_no_kernel():
     of latent attention over a whole cache (`ops/mla_decode.py`), the
     seventh the held experts of a decode step as one call on the chip
     (`ops/moe_held.py`), the eighth a KDA decode step
-    (`ops/kda.py`)."""
+    (`ops/kda.py`), the ninth the put of a decode step's row into a
+    whole cache that the sixth reads (`ops/mla_decode.py`)."""
     params = tfm.init_transformer(jax.random.PRNGKey(0), LATENT_CFG)
     caches, _ = tfm.prefill(params, jnp.zeros((2, 8), jnp.int32),
                             cfg=LATENT_CFG, total=12)
@@ -318,7 +319,7 @@ def test_the_indexed_layers_add_no_kernel():
         p, c, jnp.zeros((2,), jnp.int32), 8, 4, cfg=LATENT_CFG))(
             params, caches)
     assert pallas_calls(jaxpr.jaxpr) == []
-    assert len(profiling.LM_KERNELS) == 8
+    assert len(profiling.LM_KERNELS) == 9
 
 
 @pytest.fixture(scope="module")
@@ -438,6 +439,10 @@ def kernel_sites():
                                         scale=0.1,
                                         backend="pallas_interpret")
 
+    def put(rows, row):
+        return ops.mla_decode.latent_row_put(rows, row, jnp.int32(5),
+                                             backend="pallas_interpret")
+
     def held(x, w):
         from lua_mapreduce_tpu.ops.moe_held import moe_held
         return moe_held(x, jnp.ones((8, 2)), jnp.ones((2,), jnp.int32), w, w,
@@ -456,6 +461,8 @@ def kernel_sites():
                                     jnp.ones((2, 128, 128)))),
         "_mla_decode_pallas": (mla, (jnp.ones((1, 4, 96)),
                                      jnp.ones((1, 128, 96)))),
+        "_latent_row_put": (put, (jnp.ones((2, 130, 96)),
+                                  jnp.ones((2, 1, 96)))),
         "flash_pallas": (flash, (q, q, q)),
         "flash_bwd_pallas_dq": (jax.grad(flash, (0, 1, 2)), (q, q, q)),
         "flash_bwd_pallas_dkv": (jax.grad(flash, (0, 1, 2)), (q, q, q)),

@@ -6,6 +6,7 @@ un-absorbed, which shares no code with the program), and the latent
 flash-decode kernel against its XLA composition. CPU, tiny widths,
 seeded."""
 
+import functools
 import json
 import os
 
@@ -14,11 +15,14 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from lua_mapreduce_tpu import ops
+from lua_mapreduce_tpu.models import attention_kinds as kinds
 from lua_mapreduce_tpu.models import transformer as tfm
 from lua_mapreduce_tpu.ops import mla_decode
 from lua_mapreduce_tpu.parallel import moe
 from perfbench import reference_sarvam as ref
-from perfbench import weights, weights_sarvam
+from perfbench import weights, weights_kimilinear, weights_sarvam
+from perfbench.model_kimilinear import program_config as kimi_config
 from perfbench.model_sarvam import program_config
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -211,6 +215,108 @@ def test_the_latent_decode_kernel_reads_nothing_past_t():
         clean = mla_decode.mla_decode_attention(
             q, cache, jnp.int32(1300), v_rank=128, scale=0.3, backend=backend)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))
+
+
+@pytest.mark.parametrize("rows", [1, "chosen"])
+@pytest.mark.parametrize("b,s_len,ts", [
+    (16, 1000, (256, 257, 319, 383)),   # lanes 0, 1, 63, 127 of a tile
+    (2, 4160, range(4096, 4160)),       # the Kimi cell's last tile, all
+    (1, 32832, range(32768, 32832)),    # the sarvam cell's last tile, all
+    (3, 300, (299, 300, 10 ** 6)),      # past the end: clamped, as XLA's
+])
+def test_the_latent_row_put_is_the_dynamic_update_slice(b, s_len, ts, rows):
+    """The decode step's in-place put of a row into a (B, S, 576)
+    bfloat16 cache, a position after another as a scan makes them, is
+    `lax.dynamic_update_slice` bit for bit: every slot but the one
+    written (the other lanes of its tile column, and of a column that
+    hangs over the cache's end) is as it was, at one row a grid step
+    and at the rows `_put_rows` chooses."""
+    width = 576
+    if rows == "chosen":
+        rows = mla_decode._put_rows(b, width, 2)
+        assert rows == {16: 8, 2: 2, 1: 1, 3: 3}[b]
+    bits = lambda x: np.asarray(x).view(np.uint16)  # noqa: E731
+    kc, kr = jax.random.split(jax.random.PRNGKey(s_len))
+    put = want = jax.random.normal(kc, (b, s_len, width), jnp.bfloat16)
+    for i, t in enumerate(ts):
+        row = jax.random.normal(jax.random.fold_in(kr, i), (b, 1, width),
+                                jnp.bfloat16)
+        want = jax.lax.dynamic_update_slice(want, row, (0, t, 0))
+        put = mla_decode._latent_row_put(put, row, jnp.int32(t), rows=rows,
+                                         interpret=True)
+        # the written tile column at each step, the whole cache at the end
+        col = slice(min(t, s_len - 1) // 128 * 128, None)
+        np.testing.assert_array_equal(bits(put[:, col][:, :128]),
+                                      bits(want[:, col][:, :128]))
+    assert put.shape == want.shape and put.dtype == want.dtype
+    np.testing.assert_array_equal(bits(put), bits(want))
+
+
+def tiny_stack(model: str) -> tuple:
+    """(program config, bfloat16 weights, two contexts) of a tiny
+    sarvam-shaped stack (every layer latent) or Kimi-shaped one (three
+    KDA layers, then a latent one without positions)."""
+    if model == "sarvam":
+        return (program_config(TINY), params_of(TINY, jnp.bfloat16),
+                ids(2, CONTEXT, TINY["vocab_size"]))
+    with open(os.path.join(ROOT, "perfbench", "tests", "data",
+                           "tiny-kimilinear.json")) as f:
+        cfg = json.load(f)
+    return (kimi_config(cfg), weights_kimilinear.make_params(cfg, SEED),
+            weights.token_rows(SEED, 0, 2, CONTEXT, cfg["vocab_size"]))
+
+
+@pytest.fixture
+def fresh_traces():
+    """`decode_from` traced anew: what a test patches below it is not in
+    the jit's key."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("model", ["sarvam", "kimi"])
+def test_a_turn_is_the_same_with_the_row_put_as_with_the_update_slice(
+        model, monkeypatch, fresh_traces):
+    """The session entry on the latent kernel's backend (interpret mode
+    here), where a decode step's row goes in through the in-place put,
+    serves the tokens and leaves the caches of the same turn with the
+    row written by `lax.dynamic_update_slice`, bit for bit; the put is
+    traced once a latent layer."""
+    pcfg, params, context = tiny_stack(model)
+    monkeypatch.setattr(ops, "default_backend", lambda op=None: (
+        "pallas_interpret" if op == "mla_decode_attention" else "xla"))
+    traced = []
+    put = mla_decode._latent_row_put
+    monkeypatch.setattr(mla_decode, "_latent_row_put",
+                        lambda *a, **k: traced.append(1) or put(*a, **k))
+
+    caches, last = tfm.prefill(params, jnp.asarray(context), cfg=pcfg,
+                               total=TOTAL)
+    start = tfm.decode_caches(caches, cfg=pcfg, p_len=CONTEXT, total=TOTAL)
+    first = jnp.argmax(last, -1).astype(jnp.int32)
+
+    def turn():
+        jax.clear_caches()
+        # (the entry donates its caches)
+        tokens, caches = tfm.decode_from(
+            params, {k: jnp.copy(v) for k, v in start.items()}, first,
+            CONTEXT, N_NEW, cfg=pcfg)
+        return np.asarray(tokens), {k: np.asarray(v)
+                                    for k, v in caches.items()}
+
+    tokens, caches = turn()
+    latent = [k for k in caches if k.endswith("_ckv")]
+    assert len(traced) == len(latent) == {"sarvam": 3, "kimi": 1}[model]
+    assert caches[latent[0]].dtype == jnp.bfloat16
+    monkeypatch.setattr(kinds, "latent_row_put", functools.partial(
+        mla_decode.latent_row_put, backend="xla"))
+    want_tokens, want_caches = turn()
+    assert len(traced) == len(latent)
+    np.testing.assert_array_equal(tokens, want_tokens)
+    assert set(caches) == set(want_caches)
+    for k, v in caches.items():
+        np.testing.assert_array_equal(v, want_caches[k], err_msg=k)
 
 
 @pytest.mark.parametrize("s_len,first,q_len", [

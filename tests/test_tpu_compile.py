@@ -415,6 +415,23 @@ def no_copy_of_an_expert_stack(text: str, count: int, d: int, f: int):
         r"(?:copy|transpose|fusion|copy-start)\(", text)
 
 
+def latent_row_puts(text: str, b: int, s_len: int) -> int:
+    """How often the decode step's in-place row put engages: the
+    `_latent_row_put` calls on a (b, 576, s_len) latent cache that
+    write their result over the cache they were given, after the
+    assertion that no dynamic-update-slice, copy or transpose makes a
+    (b, s_len, 576) cache (XLA's dynamic-update-slice writes the row a
+    tile at a time, 2.5 us a session on a v5e)."""
+    assert not re.findall(
+        rf"= bf16\[{b},(?:{s_len},576|576,{s_len})\][^ ]* "
+        r"(?:dynamic-update-slice|copy|transpose)\(", text)
+    return len(re.findall(
+        rf"%_latent_row_put[.\d]* = bf16\[{b},576,{s_len}\][^ ]* "
+        r"custom-call\([^)]*\), custom_call_target=\"tpu_custom_call\", "
+        r"operand_layout_constraints=\{.*?\}, "
+        r"output_to_operand_aliasing=\{\{\}: \(2, \{\}\)\}", text))
+
+
 def test_the_latent_session_entry_reads_an_expert_only_where_touched(
         chip_policy, latent_turn):
     """The 16 held experts are ONE grouped call in the decode program
@@ -451,6 +468,18 @@ def test_the_latent_session_entry_gathers_the_selected_rows_and_no_more(
              if math.prod(map(int, re.findall(r"\d+", dims)))
              in (8 * 2048, 8 * 2048 * 576)]          # a scalar, a row a slot
     assert slots == [("bf16", "8,2048,576")]
+
+
+def test_the_indexed_latent_cache_keeps_its_dynamic_update_slice(
+        chip_policy, latent_turn):
+    """DeepSeek's indexed cache is laid out as XLA picks it for the row
+    gather, which the row put's transposed view could force into a
+    re-layout: its decode step writes the row with the one
+    dynamic-update-slice a layer, as before, and no put."""
+    text = latent_turn().as_text()
+    assert "_latent_row_put" not in text
+    assert len(re.findall(
+        r"= bf16\[8,32832,576\][^ ]* dynamic-update-slice\(", text)) == 1
 
 
 # --------------------------------------------------------------------------
@@ -552,7 +581,8 @@ def test_the_whole_cache_session_entry_keeps_kernel_and_caches_in_place(
     the latent cache stays in place in the layout the chip keeps it in:
     the scan re-lays no (16, 32832, 576) array (a kernel over (B, S, R)
     blocks made XLA copy the whole cache into the scan and out of it,
-    and hold a second one)."""
+    and hold a second one), and a step's row goes in through the one
+    in-place put."""
     from perfbench.model_sarvam import program_config as sarvam_config
     import dataclasses
     cfg = dataclasses.replace(
@@ -567,9 +597,13 @@ def test_the_whole_cache_session_entry_keeps_kernel_and_caches_in_place(
     assert not re.findall(
         r"= bf16\[16,(?:32832,576|576,32832)\][^ ]* (?:copy|transpose)\(",
         text)
+    # the decode step's row goes in through the in-place put
+    assert latent_row_puts(text, 16, 32832) == 1
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 16 * 32832 * 576 * 2
-    assert memory.temp_size_in_bytes <= 122104320
+    # (122,104,320 with a conditional an expert; 21,382,144 with the
+    # row's dynamic-update-slice)
+    assert memory.temp_size_in_bytes <= 21382144
 
 
 # --------------------------------------------------------------------------
@@ -713,8 +747,9 @@ def test_the_kda_session_entry_keeps_its_states_in_place(topo, chip_policy):
     64 scanned): three KDA layers and the latent one. A call of the KDA
     kernel a KDA layer and step, the latent kernel and the grouped
     expert call; the scan copies no state (the snapshots are copied
-    once, before it), and the donated states, tails, snapshots and
-    latent rows are written in place."""
+    once, before it), the latent layer's row goes in through the
+    in-place put, and the donated states, tails, snapshots and latent
+    rows are written in place."""
     from perfbench.model_kimilinear import program_config
     import dataclasses
     cfg = program_config(serve_config(
@@ -731,10 +766,14 @@ def test_the_kda_session_entry_keeps_its_states_in_place(topo, chip_policy):
     assert body and "_kda_step_pallas" in body.group(1)
     assert not re.findall(r"= f32\[32,32,128,128\][^ ]* copy\(",
                           body.group(1))
+    # the latent layer's row goes in through the in-place put, a step
+    assert latent_row_puts(body.group(1), 32, 4160) == 1
+    assert latent_row_puts(text, 32, 4160) == 1
     memory = compiled.memory_analysis()
     states = 3 * 2 * 32 * (32 * 128 * 128 * 4 + 3 * 12288 * 2)
     latent = 32 * 4160 * 576 * 2
     assert states + latent <= memory.alias_size_in_bytes
     # (the tails' 3 rows a session lie in the chip's tiles of more)
     assert memory.alias_size_in_bytes < states + latent + 2 ** 23
-    assert memory.temp_size_in_bytes < 64 * 2 ** 20
+    # (49,540,096 with the row's dynamic-update-slice)
+    assert memory.temp_size_in_bytes <= 49540096
